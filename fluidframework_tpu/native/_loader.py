@@ -19,6 +19,9 @@ _BUILD_DIR = Path(__file__).parent / "_build"
 _lock = threading.Lock()  # guards _cache and _name_locks only
 _name_locks: dict[str, threading.Lock] = {}
 _cache: dict[str, ctypes.CDLL | None] = {}
+#: name -> why the last build or load failed (callers that must not run
+#: without the native library report it; the rest use their twins).
+build_errors: dict[str, str] = {}
 
 
 def build_and_load(name: str, src: Path,
@@ -61,8 +64,10 @@ def build_and_load(name: str, src: Path,
                         except OSError:
                             pass
             lib = ctypes.CDLL(str(lib_path))
-        except (OSError, subprocess.SubprocessError):
+        except (OSError, subprocess.SubprocessError) as e:
             lib = None
+            stderr = getattr(e, "stderr", None) or b""
+            build_errors[name] = f"{e}: {stderr.decode(errors='replace')}"
         with _lock:
             _cache[name] = lib
         return lib

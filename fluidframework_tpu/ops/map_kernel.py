@@ -136,8 +136,8 @@ def apply_tick_words(state: MapState, words: jax.Array, counts: jax.Array,
                      base_seq: jax.Array) -> MapState:
     """Minimum-wire entry: 4 bytes/op. ``words`` is u32/i32[B, K] packing
     kind(2) | slot(10) | value(20); seq derives on device as base_seq + op
-    index. The host→device link is the op-storm bottleneck (a tunnel or
-    DCN hop runs at O(100MB/s)), so bytes-per-op is the throughput knob;
+    index. The host→device feed is what the op storm moves per op, so
+    bytes-per-op is the throughput knob;
     hosts whose interned value ids outgrow 20 bits (or key slots 10 bits)
     fall back to apply_tick_packed / apply_tick."""
     k = words.shape[1]

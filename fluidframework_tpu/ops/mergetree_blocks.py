@@ -140,6 +140,17 @@ class BlockPrims:
     def cumsum_excl(x: jax.Array, axis: int) -> jax.Array:
         return jnp.cumsum(x, axis=axis) - x
 
+    @staticmethod
+    def take_block(x: jax.Array, b: jax.Array) -> jax.Array:
+        """Row ``b`` ([1, 1]) of the block axis (-2) → [..., 1, Bk]."""
+        start = (0,) * (x.ndim - 2) + (b[0, 0], 0)
+        return lax.dynamic_slice(x, start, x.shape[:-2] + (1, x.shape[-1]))
+
+    @staticmethod
+    def put_block(x: jax.Array, blk: jax.Array, b: jax.Array) -> jax.Array:
+        start = (0,) * (x.ndim - 2) + (b[0, 0], 0)
+        return lax.dynamic_update_slice(x, blk, start)
+
 
 # -- per-doc frame math --------------------------------------------------------
 #
@@ -231,27 +242,14 @@ def _first_slot(mask):
     return f, b, f - b * bk, has
 
 
-def _block_update(arrs, b, edit):
+def _block_update(arrs, b, edit, prims):
     """Slice block ``b`` of every array in ``arrs`` ([NB, Bk] or
     [F, NB, Bk]), run ``edit`` on the [*, 1, Bk] slices, write back.
     The O(Bk) structural data movement of the table."""
-    bs = b[0, 0]
-
-    def slice_of(x):
-        if x.ndim == 3:
-            return lax.dynamic_slice(x, (0, bs, 0),
-                                     (x.shape[0], 1, x.shape[2]))
-        return lax.dynamic_slice(x, (bs, 0), (1, x.shape[1]))
-
-    blocks = jax.tree.map(slice_of, arrs)
+    blocks = jax.tree.map(lambda x: prims.take_block(x, b), arrs)
     blocks = edit(blocks)
-
-    def write(x, blk):
-        if x.ndim == 3:
-            return lax.dynamic_update_slice(x, blk, (0, bs, 0))
-        return lax.dynamic_update_slice(x, blk, (bs, 0))
-
-    return jax.tree.map(write, arrs, blocks)
+    return jax.tree.map(lambda x, blk: prims.put_block(x, blk, b),
+                        arrs, blocks)
 
 
 def _summ_add(col, b, delta):
@@ -295,7 +293,7 @@ def _split_at(p, prop, overlap, summ, count, pos, ref, client, act,
                                       out["pool_start"])
         return out, sh(bprop), sh(bover)
 
-    p, prop, overlap = _block_update((p, prop, overlap), b, edit)
+    p, prop, overlap = _block_update((p, prop, overlap), b, edit, prims)
     summ = dict(summ)
     summ["blk_count"] = _summ_add(summ["blk_count"], b, do.astype(I32))
     summ["blk_tomb"] = _summ_add(summ["blk_tomb"], b,
@@ -352,7 +350,7 @@ def _place(p, prop, overlap, summ, count, frame, op, act, prims):
         return (out, jnp.where(is_new[None], 0, sh(bprop)),
                 jnp.where(is_new[None], 0, sh(bover)))
 
-    p, prop, overlap = _block_update((p, prop, overlap), b, edit)
+    p, prop, overlap = _block_update((p, prop, overlap), b, edit, prims)
     summ = dict(summ)
     do_i = do.astype(I32)
     summ["blk_count"] = _summ_add(summ["blk_count"], b, do_i)

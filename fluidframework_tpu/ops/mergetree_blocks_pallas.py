@@ -21,10 +21,11 @@ and the twin stays bit-identical.
 Shapes (see /opt/skills/guides/pallas_guide.md): planes are i32 with
 (8, 128) tiles riding the trailing (NB, Bk) axes — size Bk to a lane
 multiple (the serving pools use Bk = 128) and NB to a sublane multiple
-for efficiency; summaries ride [NB, 1] columns (lane-padded like the
-flat kernel's count column). The per-doc block index is a scalar, so
-the block read/write is a real dynamic slice on the sublane-block axis,
-not a gather.
+for efficiency. Every block's trailing two dims equal its array's (the
+TPU tiling rule), so per-doc scalars ride a squeezed leading doc axis:
+summaries [B, NB, 4], count [B, 1, 1], ops [B, F, K]. Mosaic lowers no
+dynamic slice of a value, so the block read/write selects the block row
+with a mask over the short block axis (``PltPrims.take_block``).
 """
 
 from __future__ import annotations
@@ -69,40 +70,60 @@ class PltPrims:
             shift *= 2
         return total - x
 
+    # Mosaic has no dynamic value slices: the block row is selected and
+    # written back by a mask over the (short) block axis. One nonzero
+    # term per integer sum, so the result is exact.
+    @staticmethod
+    def take_block(x: jax.Array, b: jax.Array) -> jax.Array:
+        rows = lax.broadcasted_iota(I32, x.shape, x.ndim - 2)
+        return jnp.sum(jnp.where(rows == b, x, 0), axis=x.ndim - 2,
+                       keepdims=True)
+
+    @staticmethod
+    def put_block(x: jax.Array, blk: jax.Array, b: jax.Array) -> jax.Array:
+        rows = lax.broadcasted_iota(I32, x.shape, x.ndim - 2)
+        return jnp.where(rows == b, blk, x)
+
 
 def _tick_kernel(*refs, num_ops: int):
     plane_refs = refs[:6]
-    prop_ref, overlap_ref = refs[6], refs[7]
-    summ_refs = refs[8:12]
-    count_ref = refs[12]
-    op_refs = refs[13:24]
-    out_plane_refs = refs[24:30]
-    out_prop_ref, out_overlap_ref = refs[30], refs[31]
-    out_summ_refs = refs[32:36]
-    out_count_ref, out_ovf_ref = refs[36], refs[37]
+    prop_ref, overlap_ref, summ_ref, count_ref, op_ref = refs[6:11]
+    out_plane_refs = refs[11:17]
+    (out_prop_ref, out_overlap_ref, out_summ_ref, out_count_ref,
+     out_ovf_ref) = refs[17:22]
 
     planes = {name: ref[:] for name, ref in zip(_SLOT_PLANES, plane_refs)}
     prop = prop_ref[:]
     overlap = overlap_ref[:]
-    summ = {name: ref[:] for name, ref in zip(_SUMM, summ_refs)}
+    # Fields are picked out of the packs by masked reductions, never by
+    # unaligned slices: Mosaic cannot broadcast a [1, 1] value that sits
+    # at a nonzero sublane/lane offset, and needs 128-aligned dynamic
+    # lane slices. One nonzero term per integer sum keeps them exact.
+    summ_pack = summ_ref[:]                                  # [NB, 4]
+    summ_lane = lax.broadcasted_iota(I32, summ_pack.shape, 1)
+    summ = {name: jnp.sum(jnp.where(summ_lane == j, summ_pack, 0), axis=1,
+                          keepdims=True)
+            for j, name in enumerate(_SUMM)}
     count = count_ref[:]
-    # Mosaic requires 128-aligned dynamic lane slices, so column k of the
-    # op row is selected with a masked reduction instead of a load.
-    op_vals = {name: ref[:] for name, ref in zip(_OP_FIELDS, op_refs)}
-    op_lane = lax.broadcasted_iota(I32, op_vals["kind"].shape, 1)
+    op_pack = op_ref[:]                                      # [F, K]
+    op_lane = lax.broadcasted_iota(I32, op_pack.shape, 1)
+    op_row = lax.broadcasted_iota(I32, (op_pack.shape[0], 1), 0)
 
     def body(k, carry):
         planes, prop, overlap, summ, count, ovf = carry
-        op = {name: jnp.sum(jnp.where(op_lane == k, v, 0), axis=1,
+        col = jnp.sum(jnp.where(op_lane == k, op_pack, 0), axis=1,
+                      keepdims=True)                          # [F, 1]
+        op = {name: jnp.sum(jnp.where(op_row == j, col, 0), axis=0,
                             keepdims=True)
-              for name, v in op_vals.items()}
+              for j, name in enumerate(_OP_FIELDS)}
         idx = jnp.zeros((1, 1), I32) + k
         return block_apply_doc(planes, prop, overlap, summ, count, ovf,
                                op, idx, prims=PltPrims)
 
     # Serving flushes front-pack ops, so a dynamic trip count skips the
     # invalid tail at zero per-step cost.
-    last_valid = jnp.max(jnp.where(op_vals["valid"] != 0, op_lane + 1, 0))
+    is_valid = (op_row == _OP_FIELDS.index("valid")) & (op_pack != 0)
+    last_valid = jnp.max(jnp.where(is_valid, op_lane + 1, 0))
     ovf0 = jnp.full((1, 1), OVF_NONE, I32)
     planes, prop, overlap, summ, count, ovf = lax.fori_loop(
         0, jnp.minimum(last_valid, num_ops), body,
@@ -111,8 +132,10 @@ def _tick_kernel(*refs, num_ops: int):
         ref[:] = planes[name]
     out_prop_ref[:] = prop
     out_overlap_ref[:] = overlap
-    for name, ref in zip(_SUMM, out_summ_refs):
-        ref[:] = summ[name]
+    out_summ = jnp.zeros(summ_pack.shape, I32)
+    for j, name in enumerate(_SUMM):
+        out_summ = jnp.where(summ_lane == j, summ[name], out_summ)
+    out_summ_ref[:] = out_summ
     out_count_ref[:] = count
     out_ovf_ref[:] = ovf
 
@@ -132,50 +155,52 @@ def apply_tick_blocks_pallas(state: BlockMergeState,
     planes = [getattr(state, name) for name in _SLOT_PLANES]
     prop = jnp.transpose(state.prop_val, (3, 0, 1, 2))      # [P, B, NB, Bk]
     overlap = jnp.transpose(state.rem_overlap, (3, 0, 1, 2))
-    summs = [jnp.transpose(getattr(state, name)) for name in _SUMM]
-    count = state.count[:, None]
-    op_arrays = [getattr(ops, name).astype(I32) for name in _OP_FIELDS]
+    # Per-doc scalars ride a squeezed leading doc axis so every block's
+    # trailing two dims equal the array's own (the TPU tiling rule):
+    # summaries [B, NB, 4], count [B, 1, 1], ops [B, F, K].
+    summ = jnp.stack([getattr(state, name) for name in _SUMM], axis=2)
+    count = state.count[:, None, None]
+    op_pack = jnp.stack([getattr(ops, name).astype(I32)
+                         for name in _OP_FIELDS], axis=1)
+    n_summ, n_op = len(_SUMM), len(_OP_FIELDS)
 
-    grid = (b,)
-    plane_spec = pl.BlockSpec((None, nb, bk), lambda i: (i, 0, 0),
-                              memory_space=pltpu.VMEM)
+    def doc_spec(*shape):
+        return pl.BlockSpec((None, *shape),
+                            lambda i: (i,) + (0,) * len(shape),
+                            memory_space=pltpu.VMEM)
+
+    plane_spec = doc_spec(nb, bk)
     prop_spec = pl.BlockSpec((p, None, nb, bk), lambda i: (0, i, 0, 0),
                              memory_space=pltpu.VMEM)
     overlap_spec = pl.BlockSpec((w, None, nb, bk), lambda i: (0, i, 0, 0),
                                 memory_space=pltpu.VMEM)
-    summ_spec = pl.BlockSpec((nb, 1), lambda i: (0, i),
-                             memory_space=pltpu.VMEM)
-    scalar_spec = pl.BlockSpec((1, 1), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM)
-    op_spec = pl.BlockSpec((1, k), lambda i: (i, 0),
-                           memory_space=pltpu.VMEM)
+    summ_spec = doc_spec(nb, n_summ)
+    scalar_spec = doc_spec(1, 1)
 
     out = pl.pallas_call(
         functools.partial(_tick_kernel, num_ops=k),
-        grid=grid,
-        in_specs=[plane_spec] * 6 + [prop_spec, overlap_spec]
-        + [summ_spec] * 4 + [scalar_spec] + [op_spec] * 11,
-        out_specs=[plane_spec] * 6 + [prop_spec, overlap_spec]
-        + [summ_spec] * 4 + [scalar_spec, scalar_spec],
+        grid=(b,),
+        in_specs=[plane_spec] * 6 + [prop_spec, overlap_spec, summ_spec,
+                                     scalar_spec, doc_spec(n_op, k)],
+        out_specs=[plane_spec] * 6 + [prop_spec, overlap_spec, summ_spec,
+                                      scalar_spec, scalar_spec],
         out_shape=(
             [jax.ShapeDtypeStruct((b, nb, bk), I32)] * 6
             + [jax.ShapeDtypeStruct((p, b, nb, bk), I32),
-               jax.ShapeDtypeStruct((w, b, nb, bk), I32)]
-            + [jax.ShapeDtypeStruct((nb, b), I32)] * 4
-            + [jax.ShapeDtypeStruct((b, 1), I32),
-               jax.ShapeDtypeStruct((b, 1), I32)]),
-        input_output_aliases={i: i for i in range(13)},
+               jax.ShapeDtypeStruct((w, b, nb, bk), I32),
+               jax.ShapeDtypeStruct((b, nb, n_summ), I32)]
+            + [jax.ShapeDtypeStruct((b, 1, 1), I32)] * 2),
+        input_output_aliases={i: i for i in range(10)},
         interpret=interpret,
-    )(*planes, prop, overlap, *summs, count, *op_arrays)
+    )(*planes, prop, overlap, summ, count, op_pack)
 
     new = state._replace(
         **{name: arr for name, arr in zip(_SLOT_PLANES, out[:6])},
         prop_val=jnp.transpose(out[6], (1, 2, 3, 0)),
         rem_overlap=jnp.transpose(out[7], (1, 2, 3, 0)),
-        **{name: jnp.transpose(arr)
-           for name, arr in zip(_SUMM, out[8:12])},
-        count=out[12][:, 0])
-    return new, out[13][:, 0]
+        **{name: out[8][:, :, j] for j, name in enumerate(_SUMM)},
+        count=out[9][:, 0, 0])
+    return new, out[10][:, 0, 0]
 
 
 def default_interpret() -> bool:

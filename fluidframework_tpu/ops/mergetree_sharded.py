@@ -46,11 +46,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-try:  # jax >= 0.5 exports it at top level; 0.4.x keeps it experimental
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from .mergetree_kernel import MergeOpBatch, MergeState
 from .mergetree_pallas import _OPS, _PLANES, merge_apply_vec
 
@@ -184,8 +179,8 @@ def apply_tick_sharded(state: MergeState, ops: MergeOpBatch,
         state.count[:, None].astype(I32),
     ) + tuple(getattr(ops, name).astype(I32) for name in _OPS)
 
-    out = _shard_map(tick, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs)(*flat_in)
+    out = jax.shard_map(tick, mesh=mesh, in_specs=in_specs,
+                        out_specs=out_specs)(*flat_in)
 
     named = dict(zip(_PLANES, out[:7]))
     return MergeState(

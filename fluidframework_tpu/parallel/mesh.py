@@ -17,11 +17,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-try:  # jax >= 0.5 exports it at top level; 0.4.x keeps it experimental
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map
-
 DOCS_AXIS = "docs"
 
 
@@ -69,7 +64,7 @@ def aggregate_metrics(mesh: Mesh, tree):
         return tuple(
             jax.lax.psum(jnp.sum(x, axis=0), DOCS_AXIS) for x in xs)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_reduce, mesh=mesh,
         in_specs=tuple(PartitionSpec(DOCS_AXIS) for _ in leaves),
         out_specs=tuple(PartitionSpec() for _ in leaves))

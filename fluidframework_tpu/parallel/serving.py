@@ -530,25 +530,28 @@ class ShardedServing:
         kstats = None
         if not self._mixed:
             gather = np.arange(lo, hi, dtype=np.int32)
-            (self.seq_state, self.map_state, n_seq, first, last,
-             _msn, _bad, _kstats) = _storm_tick(
-                self.seq_state, self.map_state, put(slot), put(cseq0),
-                put(ref), put(np.full(b_local, now, np.int32)),
-                put(seq_counts), put(gather), put(map_words),
-                put(map_counts))
+            feed = (put(slot), put(cseq0), put(ref),
+                    put(np.full(b_local, now, np.int32)), put(seq_counts),
+                    put(gather), put(map_words), put(map_counts))
+            # The mesh context lets the tick shard_map its Pallas legs.
+            with jax.set_mesh(self.mesh):
+                (self.seq_state, self.map_state, n_seq, first, last,
+                 _msn, _bad, _kstats) = _storm_tick(
+                    self.seq_state, self.map_state, *feed)
         else:
             scalars = np.stack(
                 [slot, cseq0, ref, np.full(b_local, now, np.int32),
                  seq_counts, map_counts], axis=1)
-            (self.seq_state, self.map_state, self.merge_state,
-             self.matrix_state, self.tree_state, n_seq, first, last,
-             _msn, tree_overflow, text_overflow, kstats) = _mixed_tick(
-                self.seq_state, self.map_state, self.merge_state,
-                self.matrix_state, self.tree_state,
-                put(scalars), put(map_words),
-                put(fam_pack["text"]) if enabled["text"] else None,
-                put(fam_pack["matrix"]) if enabled["matrix"] else None,
-                put(fam_pack["tree"]) if enabled["tree"] else None)
+            feed = tuple(
+                put(a) if a is not None else None
+                for a in (scalars, map_words, fam_pack["text"],
+                          fam_pack["matrix"], fam_pack["tree"]))
+            with jax.set_mesh(self.mesh):
+                (self.seq_state, self.map_state, self.merge_state,
+                 self.matrix_state, self.tree_state, n_seq, first, last,
+                 _msn, tree_overflow, text_overflow, kstats) = _mixed_tick(
+                    self.seq_state, self.map_state, self.merge_state,
+                    self.matrix_state, self.tree_state, *feed)
         # The device program has the batch; only now may buffers drop
         # (at-least-once: an assembly failure above must keep them).
         for port in self.hosts:

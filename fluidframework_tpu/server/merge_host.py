@@ -471,7 +471,7 @@ class _BlockMergePool(_MergePool):
         min_seq = np.full(self.capacity, -1, np.int32)
         for r in self.members:
             if r is not None:
-                min_seq[r.row] = r.min_seq
+                min_seq[r.row] = r.applied_min_seq
         # Chaos kill class "mid-rebalance": the layout is about to move;
         # a crash here loses only volatile device state (the durable log
         # + snapshot replay rebuilds the row byte-identically).
@@ -1313,10 +1313,11 @@ class KernelMergeHost:
             vec_extra, cell_extra = self._matrix_vec_shortfall(rows)
         if vec_extra:
             # Zamboni the permutation vectors before paying for growth —
-            # tombstoned row/col segments below the window pack away.
+            # tombstoned row/col segments below the window pack away (at
+            # the applied frontier's MSN: the pending ops apply after).
             min_seq = np.full(self._matrix_capacity, -1, np.int32)
             for r in self._matrix_rows.values():
-                min_seq[r.row] = r.min_seq
+                min_seq[r.row] = r.applied_min_seq
             ms = jnp.asarray(min_seq)
             self._matrix_state = self._matrix_state._replace(
                 rows=mtk.compact(self._matrix_state.rows, ms),
@@ -1995,10 +1996,13 @@ class KernelMergeHost:
                 short = need > margins
                 if not short.any():
                     continue
+                # Compaction runs BEFORE the pending ops apply, so its
+                # floor is the MSN of the applied frontier: a pending op
+                # may reference a seq below the newest pending MSN.
                 min_seq = np.full(pool.capacity, -1, np.int32)
                 for r in pool.members:
                     if r is not None and short[r.row]:
-                        min_seq[r.row] = r.min_seq
+                        min_seq[r.row] = r.applied_min_seq
                 pool.state = pool.compact_state(jnp.asarray(min_seq))
                 self.stats["compactions"] += 1
                 still = need > pool.margins()

@@ -5,112 +5,44 @@ a new shape bucket (segment slots, op-batch k, prop planes, overlap
 words all double on demand). Within one process the in-memory jit cache
 dedups identical shapes; across restarts — a serving host rolling, a
 farm re-running, bench.py re-invoked — every bucket shape would pay its
-full XLA compile again (~1-3s each on CPU, 20-40s cold on TPU). The
-reference ships its lambdas warm for the same reason (a routerlicious
-pod restart does not re-JIT V8 code from scratch); here the equivalent
-is JAX's persistent compilation cache keyed by HLO fingerprint.
+full XLA compile again. The reference ships its lambdas warm for the
+same reason (a routerlicious pod restart does not re-JIT V8 code from
+scratch); here the equivalent is JAX's persistent compilation cache
+keyed by HLO fingerprint.
 
-Call :func:`enable` before first device use. Opt out with
-``FFTPU_COMPILE_CACHE=0``; override the location with
-``FFTPU_COMPILE_CACHE_DIR``.
+Call :func:`enable` before first device use. Where
+``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this module
+sets no directory; otherwise the cache sits at one fixed path inside the
+checkout (the path is part of the cache key, so it must not move).
 """
 
 from __future__ import annotations
 
-import contextlib
-import functools
 import os
+from pathlib import Path
 
-_DEFAULT_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "fluidframework_tpu", "xla")
+CHECKOUT_DIR = str(Path(__file__).resolve().parents[2] / ".jax_cache")
 
-_enabled = False
 _active_dir: str | None = None
 
 
-def enable(cache_dir: str | None = None) -> str | None:
-    """Idempotently turn on the persistent compilation cache.
-
-    Returns the cache directory, or None when disabled by env."""
-    global _enabled, _active_dir
-    if os.environ.get("FFTPU_COMPILE_CACHE", "1") == "0":
-        return None
-    if _enabled:
-        # Already configured: report the directory actually in effect —
-        # a different requested dir is NOT adopted mid-process.
+def enable() -> str:
+    """Idempotently turn on the persistent compilation cache; returns the
+    directory in effect."""
+    global _active_dir
+    if _active_dir is not None:
         return _active_dir
-    path = (cache_dir or os.environ.get("FFTPU_COMPILE_CACHE_DIR")
-            or _DEFAULT_DIR)
-    os.makedirs(path, exist_ok=True)
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", path)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = CHECKOUT_DIR
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
     # Serving-host programs include many sub-second helpers (row writes,
     # margin reads) that still dominate a farm's wall clock in aggregate;
     # cache everything non-trivial rather than only the big kernels.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    _enabled = True
     _active_dir = path
     return path
-
-
-@contextlib.contextmanager
-def bypass():
-    """Disable the persistent cache for the duration of the block.
-
-    Needed for DONATED serving ticks: jaxlib 0.4.37 mishandles buffer
-    donation on executables DESERIALIZED from the persistent cache —
-    the second execution of such an executable double-frees its donated
-    inputs (glibc "corrupted double-linked list" under the mixed
-    all-DDS tick; reproduced at /tmp with a two-process warm run of any
-    multi-tick mixed assembly, cold compiles unaffected). The donated
-    hot ticks therefore always compile in-process: they trade warm-start
-    seconds for correctness and keep in-place HBM donation."""
-    # The config dir is snapshotted into a singleton at first use, so a
-    # config context is a no-op once any jit compiled; the per-compile
-    # gate jax actually consults is the cached ``_cache_used`` verdict
-    # (compilation_cache.is_cache_used) — flip that for the block.
-    global _enabled, _active_dir
-    try:
-        from jax._src import compilation_cache as cc
-        with cc._cache_initialized_mutex:
-            prev = (cc._cache_checked, cc._cache_used)
-            cc._cache_checked, cc._cache_used = True, False
-    except Exception:
-        # jax internals moved: fail CLOSED. A silently inert guard would
-        # reintroduce the double-free on the next warm start, so turn
-        # the persistent cache off for the whole process (public config
-        # — effective as long as no jit compiled yet) and say so.
-        import warnings
-
-        import jax
-
-        warnings.warn(
-            "compile_cache.bypass: jax internals changed; disabling the "
-            "persistent compilation cache process-wide instead of "
-            "per-call (re-audit the donated-executable double-free "
-            "against this jax version)", RuntimeWarning, stacklevel=3)
-        jax.config.update("jax_compilation_cache_dir", None)
-        _enabled = False
-        _active_dir = None
-        yield
-        return
-    try:
-        yield
-    finally:
-        with cc._cache_initialized_mutex:
-            cc._cache_checked, cc._cache_used = prev
-
-
-def uncached(jitted):
-    """Wrap a donated jitted serving tick so its compile/lookup NEVER
-    touches the persistent cache (see :func:`bypass`). The traced
-    function stays reachable via ``__wrapped__`` (bench re-jits it
-    without donation, which the cache handles fine)."""
-    @functools.wraps(jitted)
-    def call(*args, **kwargs):
-        with bypass():
-            return jitted(*args, **kwargs)
-    call.__wrapped__ = getattr(jitted, "__wrapped__", jitted)
-    return call

@@ -1,112 +1,53 @@
-"""Donated-tick compile-cache bypass (utils/compile_cache.bypass):
-jaxlib 0.4.37 double-frees donated buffers on the SECOND run of an
-executable deserialized from the persistent cache, so every donated
-serving tick compiles through `uncached`. These tests pin the
-workaround so a jax upgrade cannot silently regress it (ADVICE item 1):
-the bypass must actually suppress persistent-cache use, the guarded
-donated tick must run repeatedly with identical results, and the
-fail-closed warning must NOT fire on this jax version — when jax's
-internals move, the warning test fails loudly and the double-free needs
-re-auditing."""
+"""Persistent compilation cache (utils/compile_cache): the directory is
+the one JAX_COMPILATION_CACHE_DIR names, else one fixed path inside the
+checkout; and a DONATED tick deserialized from that cache runs
+repeatedly with identical results (jaxlib 0.4.37 double-freed donated
+buffers on the second run of a cache-loaded executable — checked gone
+on jax 0.9.0, CPU and TPU)."""
 
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from fluidframework_tpu.utils import compile_cache
 
 
-def donated_tick():
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def tick(state, words):
-        return state + jnp.sum(words), state * 0 + words
-    return compile_cache.uncached(tick)
-
-
 def test_cache_round_tripped_donated_tick_runs_twice_identically():
-    """The serving shape: a donated jit executed repeatedly under the
-    bypass (cache enabled process-wide by conftest). Two executions of
-    the same executable — exactly the shape that double-freed — must
-    succeed with identical results."""
-    tick = donated_tick()
+    """The serving shape: a donated jit compiled once, dropped from the
+    in-memory caches, then loaded back from the persistent cache and
+    executed repeatedly (the shape that double-freed)."""
+    compile_cache.enable()
+
+    def make():
+        @functools.partial(jax.jit, donate_argnums=(0,))
+        def tick(state, words):
+            return state + jnp.sum(words), state * 0 + words
+        return tick
+
     w = jnp.arange(8, dtype=jnp.int32)
-    s1, out1 = tick(jnp.zeros(8, jnp.int32), w)
-    s2, out2 = tick(s1, w)  # second run of the SAME executable
-    s3, out3 = tick(s2, w)
+    was = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    try:
+        make()(jnp.zeros(8, jnp.int32), w)
+        jax.clear_caches()
+        tick = make()
+        s1, out1 = tick(jnp.zeros(8, jnp.int32), w)
+        s2, out2 = tick(s1, w)  # second run of the SAME executable
+        s3, out3 = tick(s2, w)
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", was)
     assert np.array_equal(np.asarray(out1), np.asarray(out3))
     assert np.asarray(s3)[0] == 3 * 28
-    # The undonated re-jit escape hatch (bench uses it) stays reachable.
-    assert callable(tick.__wrapped__)
 
 
-def test_bypass_actually_suppresses_persistent_cache():
-    """While inside bypass(), jax's per-compile gate must report the
-    persistent cache unused; outside, the process-wide enable() state is
-    restored untouched."""
-    cc = pytest.importorskip("jax._src.compilation_cache")
-    before = (cc._cache_checked, cc._cache_used)
-    with compile_cache.bypass():
-        assert (cc._cache_checked, cc._cache_used) == (True, False)
-    assert (cc._cache_checked, cc._cache_used) == before
+def test_cache_dir_is_env_or_checkout():
+    """conftest enabled the cache: the directory in effect is the env
+    var's where set (JAX reads it), else the fixed in-checkout path."""
+    import os
 
-
-def test_bypass_does_not_fail_closed_on_this_jax():
-    """The fail-closed path (jax internals moved → disable the cache
-    process-wide + warn) must NOT trigger today. When jax moves and this
-    fails, re-audit the donated-executable double-free before removing
-    the bypass (ADVICE item 1)."""
-    with warnings.catch_warnings():
-        warnings.simplefilter("error", RuntimeWarning)
-        with compile_cache.bypass():
-            pass
-
-
-def test_sequencer_and_storm_ticks_are_wrapped():
-    """The REAL donated serving ticks stay behind the bypass wrapper."""
-    from fluidframework_tpu.server import kernel_host, storm
-
-    for fn in (storm._storm_tick, storm._mixed_tick,
-               kernel_host._step_one):
-        assert getattr(fn, "__wrapped__", None) is not None, fn
-
-
-def test_donated_jit_registry_is_audited():
-    """ADVICE §1 re-audit guard, round-15 edition: the compile-cache
-    bypass must cover EVERY donated serving tick — including any new
-    sharded/combiner entry points a later round adds. The set of source
-    files declaring ``donate_argnums`` is pinned here; a new donated jit
-    in a new module fails this test until it is wrapped in
-    ``compile_cache.uncached`` and added to both lists (the double-free
-    was jaxlib-version-dependent — new tick functions must not silently
-    re-enter the persistent cache). The round-15 mega-doc tier
-    deliberately adds NO donated device entry points: the sequence-
-    parallel merge kernel (ops/mergetree_sharded.py) is undonated and
-    the doc combiner is host-side scalar work."""
-    import pathlib
-
-    import fluidframework_tpu
-
-    root = pathlib.Path(fluidframework_tpu.__file__).parent
-    files = {p.relative_to(root).as_posix()
-             for p in root.rglob("*.py")
-             if "donate_argnums" in p.read_text()}
-    assert files == {"server/kernel_host.py", "server/storm.py"}, (
-        "new donate_argnums site(s) — wrap them in "
-        f"compile_cache.uncached and pin them here: {sorted(files)}")
-    # And every known donated entry point IS wrapped (incl. the ones
-    # new round-15 code paths dispatch through).
-    from fluidframework_tpu.ops import mergetree_sharded as mts
-    from fluidframework_tpu.server import kernel_host, storm
-
-    for fn in (storm._storm_tick, storm._mixed_tick,
-               kernel_host._step_one):
-        assert getattr(fn, "__wrapped__", None) is not None, fn
-    # The sharded kernel's tick is jit WITHOUT donation — the cache is
-    # safe for it by the bypass docstring's own analysis; donation being
-    # added there later would flip the file-set assertion above.
-    assert "donate_argnums" not in pathlib.Path(
-        mts.__file__).read_text()
+    path = compile_cache.enable()
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    assert path == (env or compile_cache.CHECKOUT_DIR)
+    assert jax.config.jax_compilation_cache_dir == path

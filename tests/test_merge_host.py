@@ -554,3 +554,38 @@ def test_matrix_cell_run_fast_path_with_compaction():
     host.flush()
     assert grid_of(m1) == grid_of(m2)
     assert host.matrix_grid("doc", "default", "grid") == grid_of(m1)
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_precompaction_uses_applied_msn(seed):
+    """Compaction that runs BEFORE a flush's pending ops apply (the
+    capacity pass and the block pre-tick rebalance) drops tombstones at
+    the MSN of the applied frontier, never at the newest pending MSN: an
+    early pending op may reference a seq below it (ref lags 4, MSN lags
+    5 per message, 32-op ticks)."""
+    from fluidframework_tpu.dds.mergetree import MergeEngine
+
+    rng = random.Random(seed)
+    host = KernelMergeHost(merge_slots=64, flush_threshold=10**9)
+    oracle = MergeEngine()
+    for seq in range(1, 32 * 7 + 1):
+        writer = f"w{rng.randrange(6)}"
+        ref = rng.randrange(max(seq - 4, 0), seq)
+        length = len(oracle.get_text(ref, writer))
+        if length > 8 and rng.random() < 0.35:
+            start = rng.randrange(length - 4)
+            op = {"type": "remove", "start": start,
+                  "end": start + rng.randint(1, 4)}
+        else:
+            op = {"type": "insert", "pos": rng.randint(0, length),
+                  "text": "abcd"[:rng.randint(1, 4)]}
+        msn = max(0, seq - 5)
+        host.ingest("doc", _op_message(seq, ref, writer, op, msn=msn))
+        oracle.apply_remote(op, seq, ref, writer)
+        oracle.update_min_seq(msn)
+        if seq % 32 == 0:
+            host.flush()
+            assert host.text("doc", "default", "text") == \
+                oracle.get_text(), seq
+    assert host.stats["scalar_ops"] == 0
+    assert host.stats["compactions"] + host.stats["rebalances"] > 0

@@ -5,7 +5,6 @@ APIs a multi-host deployment uses."""
 from __future__ import annotations
 
 import numpy as np
-import pytest
 
 from fluidframework_tpu.ops import sequencer as seqk
 from fluidframework_tpu.parallel import multihost
@@ -85,14 +84,6 @@ def test_two_process_distributed_serving():
     for p in procs:
         out, _ = p.communicate(timeout=300)
         outs.append(out)
-    if any("Multiprocess computations aren't implemented" in out
-           for out in outs):
-        # This jaxlib's CPU backend cannot run cross-process collectives
-        # at all (0.4.x limitation) — an environment capability gap, not
-        # a serving regression; the multi-chip dryrun covers the SPMD
-        # path single-process.
-        pytest.skip("CPU backend lacks multiprocess collectives "
-                    "(jaxlib 0.4.x)")
     for pid, (p, out) in enumerate(zip(procs, outs)):
         assert p.returncode == 0, f"process {pid} failed:\n{out}"
         assert f"OK process {pid}" in out, out
