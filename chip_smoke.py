@@ -136,7 +136,6 @@ def phase_map_storm(dev, seed: int, num_docs: int = 10_240,
         compile_s = time.perf_counter() - t0
         _check(storm.stats["sequenced_ops"] == num_docs * k,
                f"warm-up sequenced {storm.stats['sequenced_ops']}")
-        storm.tick_seconds.clear()
         ticks_before = storm.stats["ticks"]
 
         per_conn = num_docs // n_conns
@@ -159,6 +158,7 @@ def phase_map_storm(dev, seed: int, num_docs: int = 10_240,
         for proc in procs:
             _check(proc.stdout.readline().strip() == "READY",
                    "storm client did not start")
+        storm.ledger.clear()  # tick walls count from the first frame
         t0 = time.perf_counter()
         for proc in procs:
             proc.stdin.write("GO\n")
@@ -213,7 +213,7 @@ def phase_map_storm(dev, seed: int, num_docs: int = 10_240,
                            vec_seq, vec_seq, vec_seq, vec_seq, vec_seq,
                            vec_map, np.zeros((b_map, k), np.uint32),
                            vec_map), "storm tick holds no Pallas kernel")
-        tick_ms = sorted(1000.0 * s for s in storm.tick_seconds)
+        tick_ms = sorted(r["wall"] / 1e6 for r in storm.ledger.records())
         _line("map_storm", dev, ok=True,
               shapes={"docs": num_docs, "clients_per_doc": clients_per_doc,
                       "k": k, "key_slots": num_slots, "conns": n_conns},

@@ -49,7 +49,7 @@ from ..ops import mergetree_kernel as mtk
 from ..ops import sequencer as seqk
 from ..ops import tree_kernel as tk
 from ..protocol.messages import MessageType
-from ..utils import faults
+from ..utils import StageLedger, faults
 from . import multihost
 from .mesh import aggregate_metrics
 
@@ -58,6 +58,15 @@ TEXT_FIELDS = ("kind", "pos", "end", "ref_seq", "client",
 MATRIX_FIELDS = ("target", "kind", "pos", "end", "count", "handle_base",
                  "row", "col", "value", "ref_seq", "client")
 TREE_FIELDS = ("kind", "node", "parent", "trait", "payload")
+
+#: Stage order of one sharded tick, the fixed shape of its ledger records:
+#: ``admit`` runs from the first submit after a tick to ``tick()``,
+#: ``assemble`` builds the host buffers and durable records, ``feed``
+#: places them on the mesh, ``dispatch`` calls the fused program and
+#: starts the readback copies, ``readback`` reads the addressable rows,
+#: ``record`` appends the durable records.
+SHARD_STAGES = ("admit", "assemble", "feed", "dispatch", "readback",
+                "record")
 
 
 def _plane_rows(arr, port: "HostPort") -> np.ndarray:
@@ -263,6 +272,13 @@ class ShardedServing:
         # harvest — what the failover tests rely on).
         self.pipeline_depth = max(0, pipeline_depth)
         self._inflight: list[dict] = []
+        # One stage-ledger record per harvested tick (ff.shard.stage.*
+        # spans on the profiler's clock); ``wall`` is the harvest-to-
+        # harvest cadence. The admit stage is held open from the first
+        # submit of a tick to tick() — no clock read per row.
+        self.ledger = StageLedger(SHARD_STAGES, prefix="shard.stage")
+        self._admit_stage = None
+        self._ticks = 0
 
     def route(self, row: int) -> HostPort:
         """The owning host of a document row (front-door routing)."""
@@ -295,6 +311,9 @@ class ShardedServing:
         """Common admission: ownership, one-sub-per-row-per-tick, family
         capacity bookkeeping, pool append. The replay path re-admits
         recorded subs through here so recovery is the ingest path."""
+        if self._admit_stage is None:
+            self._admit_stage = self.ledger.stage(
+                "admit", self._ticks).__enter__()
         port = self.route(row)
         pending = self._pending[port.host_id]
         if row in pending:
@@ -459,99 +478,109 @@ class ShardedServing:
         from ..server import storm as storm_mod
         from ..server.storm import _mixed_tick, _storm_tick
 
-        b = self.num_docs
-        # Host buffers build at LOCAL size (this process's doc rows) —
-        # never the global [B, ...] shape — exactly like the initial
-        # states: each process feeds only its multihost.local_docs slice.
-        lo, hi = self.local_lo, self.local_hi
-        b_local = hi - lo
-        slot = np.zeros(b_local, np.int32)
-        cseq0 = np.zeros(b_local, np.int32)
-        ref = np.zeros(b_local, np.int32)
-        seq_counts = np.zeros(b_local, np.int32)
-        map_words = np.zeros((b_local, self.k), np.uint32)
-        map_counts = np.zeros(b_local, np.int32)
-        # One packed i32[B_local, F, K] plane stack per configured family
-        # (the tick's one-transfer-per-family feed; field orders pinned
-        # by storm.TEXT_PACK/MATRIX_PACK/TREE_PACK, index 0 = valid).
-        pack_fields = {"text": storm_mod.TEXT_PACK,
-                       "matrix": storm_mod.MATRIX_PACK,
-                       "tree": storm_mod.TREE_PACK}
-        widths = {"text": self.text_k, "matrix": self.matrix_k,
-                  "tree": self.tree_k}
-        enabled = {"text": self.merge_state is not None,
-                   "matrix": self.matrix_state is not None,
-                   "tree": self.tree_state is not None}
-        fam_pack = {
-            name: (np.zeros((b_local, len(pack_fields[name]),
-                             widths[name]), np.int32)
-                   if enabled[name] else None)
-            for name in pack_fields}
+        tick = self._ticks
+        self._ticks += 1
+        if self._admit_stage is not None:
+            self._admit_stage.__exit__(None, None, None)
+            self._admit_stage = None
+        with self.ledger.stage("assemble", tick):
+            b = self.num_docs
+            # Host buffers build at LOCAL size (this process's doc rows) —
+            # never the global [B, ...] shape — exactly like the initial
+            # states: each process feeds only its multihost.local_docs slice.
+            lo, hi = self.local_lo, self.local_hi
+            b_local = hi - lo
+            slot = np.zeros(b_local, np.int32)
+            cseq0 = np.zeros(b_local, np.int32)
+            ref = np.zeros(b_local, np.int32)
+            seq_counts = np.zeros(b_local, np.int32)
+            map_words = np.zeros((b_local, self.k), np.uint32)
+            map_counts = np.zeros(b_local, np.int32)
+            # One packed i32[B_local, F, K] plane stack per configured family
+            # (the tick's one-transfer-per-family feed; field orders pinned
+            # by storm.TEXT_PACK/MATRIX_PACK/TREE_PACK, index 0 = valid).
+            pack_fields = {"text": storm_mod.TEXT_PACK,
+                           "matrix": storm_mod.MATRIX_PACK,
+                           "tree": storm_mod.TREE_PACK}
+            widths = {"text": self.text_k, "matrix": self.matrix_k,
+                      "tree": self.tree_k}
+            enabled = {"text": self.merge_state is not None,
+                       "matrix": self.matrix_state is not None,
+                       "tree": self.tree_state is not None}
+            fam_pack = {
+                name: (np.zeros((b_local, len(pack_fields[name]),
+                                 widths[name]), np.int32)
+                       if enabled[name] else None)
+                for name in pack_fields}
 
-        submitted: list[tuple[int, int]] = []  # (host, row)
-        records: dict[int, dict] = {}
-        for port in self.hosts:
-            for row, sub in self._pending[port.host_id].items():
-                if not lo <= row < hi:
-                    raise ValueError(
-                        f"row {row} outside this process's doc range "
-                        f"[{lo}, {hi}) cannot be fed from here")
-                r = row - lo
-                n = sub.count
-                seq_counts[r] = n
-                cseq0[r] = sub.cseq0
-                ref[r] = sub.ref
-                slot[r] = sub.client
-                if sub.family == "map":
-                    map_counts[r] = n
-                    map_words[r, :n] = sub.planes
-                else:
-                    pack = fam_pack[sub.family]
-                    pack[r, 0, :n] = 1
-                    for i, f in enumerate(pack_fields[sub.family][1:]):
-                        pack[r, i + 1, :n] = sub.planes[f]
-                submitted.append((port.host_id, row))
-                rec_planes = (np.array(sub.planes, np.uint32)
-                              if sub.family == "map"
-                              else {f: p.copy()
-                                    for f, p in sub.planes.items()})
-                records[row] = dict(
-                    family=sub.family, planes=rec_planes,
-                    count=n, cseq0=sub.cseq0, ref=sub.ref,
-                    client=sub.client, text=sub.text,
-                    pool_base=sub.pool_base,
-                    # Back-compat alias for the map-words record shape
-                    # (same object — not a second copy).
-                    words=(rec_planes if sub.family == "map" else None))
+            submitted: list[tuple[int, int]] = []  # (host, row)
+            records: dict[int, dict] = {}
+            for port in self.hosts:
+                for row, sub in self._pending[port.host_id].items():
+                    if not lo <= row < hi:
+                        raise ValueError(
+                            f"row {row} outside this process's doc range "
+                            f"[{lo}, {hi}) cannot be fed from here")
+                    r = row - lo
+                    n = sub.count
+                    seq_counts[r] = n
+                    cseq0[r] = sub.cseq0
+                    ref[r] = sub.ref
+                    slot[r] = sub.client
+                    if sub.family == "map":
+                        map_counts[r] = n
+                        map_words[r, :n] = sub.planes
+                    else:
+                        pack = fam_pack[sub.family]
+                        pack[r, 0, :n] = 1
+                        for i, f in enumerate(pack_fields[sub.family][1:]):
+                            pack[r, i + 1, :n] = sub.planes[f]
+                    submitted.append((port.host_id, row))
+                    rec_planes = (np.array(sub.planes, np.uint32)
+                                  if sub.family == "map"
+                                  else {f: p.copy()
+                                        for f, p in sub.planes.items()})
+                    records[row] = dict(
+                        family=sub.family, planes=rec_planes,
+                        count=n, cseq0=sub.cseq0, ref=sub.ref,
+                        client=sub.client, text=sub.text,
+                        pool_base=sub.pool_base,
+                        # Back-compat alias for the map-words record shape
+                        # (same object — not a second copy).
+                        words=(rec_planes if sub.family == "map" else None))
 
         put = lambda a: multihost.feed(self.mesh, a, global_batch=b)
         tree_overflow = None
         text_overflow = None
         kstats = None
         if not self._mixed:
-            gather = np.arange(lo, hi, dtype=np.int32)
-            feed = (put(slot), put(cseq0), put(ref),
-                    put(np.full(b_local, now, np.int32)), put(seq_counts),
-                    put(gather), put(map_words), put(map_counts))
-            # The mesh context lets the tick shard_map its Pallas legs.
-            with jax.set_mesh(self.mesh):
-                (self.seq_state, self.map_state, n_seq, first, last,
-                 _msn, _bad, _kstats) = _storm_tick(
-                    self.seq_state, self.map_state, *feed)
+            with self.ledger.stage("feed", tick):
+                gather = np.arange(lo, hi, dtype=np.int32)
+                feed = (put(slot), put(cseq0), put(ref),
+                        put(np.full(b_local, now, np.int32)), put(seq_counts),
+                        put(gather), put(map_words), put(map_counts))
+            with self.ledger.stage("dispatch", tick):
+                # The mesh context lets the tick shard_map its Pallas legs.
+                with jax.set_mesh(self.mesh):
+                    (self.seq_state, self.map_state, n_seq, first, last,
+                     _msn, _bad, _kstats) = _storm_tick(
+                        self.seq_state, self.map_state, *feed)
         else:
-            scalars = np.stack(
-                [slot, cseq0, ref, np.full(b_local, now, np.int32),
-                 seq_counts, map_counts], axis=1)
-            feed = tuple(
-                put(a) if a is not None else None
-                for a in (scalars, map_words, fam_pack["text"],
-                          fam_pack["matrix"], fam_pack["tree"]))
-            with jax.set_mesh(self.mesh):
-                (self.seq_state, self.map_state, self.merge_state,
-                 self.matrix_state, self.tree_state, n_seq, first, last,
-                 _msn, tree_overflow, text_overflow, kstats) = _mixed_tick(
-                    self.seq_state, self.map_state, self.merge_state,
-                    self.matrix_state, self.tree_state, *feed)
+            with self.ledger.stage("feed", tick):
+                scalars = np.stack(
+                    [slot, cseq0, ref, np.full(b_local, now, np.int32),
+                     seq_counts, map_counts], axis=1)
+                feed = tuple(
+                    put(a) if a is not None else None
+                    for a in (scalars, map_words, fam_pack["text"],
+                              fam_pack["matrix"], fam_pack["tree"]))
+            with self.ledger.stage("dispatch", tick):
+                with jax.set_mesh(self.mesh):
+                    (self.seq_state, self.map_state, self.merge_state,
+                     self.matrix_state, self.tree_state, n_seq, first, last,
+                     _msn, tree_overflow, text_overflow, kstats) = _mixed_tick(
+                        self.seq_state, self.map_state, self.merge_state,
+                        self.matrix_state, self.tree_state, *feed)
         # The device program has the batch; only now may buffers drop
         # (at-least-once: an assembly failure above must keep them).
         for port in self.hosts:
@@ -561,14 +590,16 @@ class ShardedServing:
         # in flight behind it (depth 0 = synchronous, the default).
         rec = dict(submitted=submitted, records=records,
                    out=(n_seq, first, last), tree_overflow=tree_overflow,
-                   text_overflow=text_overflow, kstats=kstats)
+                   text_overflow=text_overflow, kstats=kstats, tick=tick,
+                   ops=int(seq_counts.sum()))
         probes = rec["out"] + tuple(
             a for a in (tree_overflow, text_overflow, kstats)
             if a is not None)
-        for arr in probes:
-            copy_async = getattr(arr, "copy_to_host_async", None)
-            if copy_async is not None:
-                copy_async()
+        with self.ledger.stage("dispatch", tick):
+            for arr in probes:
+                copy_async = getattr(arr, "copy_to_host_async", None)
+                if copy_async is not None:
+                    copy_async()
         self._inflight.append(rec)
         if len(self._inflight) > self.pipeline_depth:
             return self._harvest_rec(self._inflight.pop(0))
@@ -588,29 +619,40 @@ class ShardedServing:
         # Shard-local harvest: each host reads ONLY the rows resident on
         # ITS addressable devices — a multi-process launch cannot (and
         # must not) materialize the global array.
+        tick = rec["tick"]
         n_seq, first, last = rec["out"]
         records = rec["records"]
-        n_seq_l = _addressable_rows(n_seq)
-        first_l = _addressable_rows(first)
-        last_l = _addressable_rows(last)
+        with self.ledger.stage("readback", tick):
+            n_seq_l = _addressable_rows(n_seq)
+            first_l = _addressable_rows(first)
+            last_l = _addressable_rows(last)
         harvest: dict[int, dict[int, tuple[int, int, int]]] = {
             port.host_id: {} for port in self.hosts}
-        for host_id, row in rec["submitted"]:
-            n_ok = n_seq_l[row]
-            harvest[host_id][row] = ((n_ok, first_l[row], last_l[row])
-                                     if n_ok > 0 else (0, 0, 0))
-            # scriptorium: the durable columnar record for this (row,
-            # tick) — the failover replay source.
-            row_rec = records[row]
-            row_rec.update(n_seq=n_ok, first=first_l[row],
-                           last=last_l[row])
-            log = self.durable.setdefault(row, [])
-            log.append(row_rec)
-            overflow = len(log) - self.durable_retention_ticks
-            if overflow > 0:
-                del log[:overflow]
-                self._durable_base[row] = (
-                    self._durable_base.get(row, 0) + overflow)
+        with self.ledger.stage("record", tick):
+            for host_id, row in rec["submitted"]:
+                n_ok = n_seq_l[row]
+                harvest[host_id][row] = ((n_ok, first_l[row], last_l[row])
+                                         if n_ok > 0 else (0, 0, 0))
+                # scriptorium: the durable columnar record for this (row,
+                # tick) — the failover replay source.
+                row_rec = records[row]
+                row_rec.update(n_seq=n_ok, first=first_l[row],
+                               last=last_l[row])
+                log = self.durable.setdefault(row, [])
+                log.append(row_rec)
+                overflow = len(log) - self.durable_retention_ticks
+                if overflow > 0:
+                    del log[:overflow]
+                    self._durable_base[row] = (
+                        self._durable_base.get(row, 0) + overflow)
+        with self.ledger.stage("readback", tick):
+            self._check_overflow(rec)
+        self.ledger.record(tick, 0, len(rec["submitted"]), rec["ops"],
+                           wall_ns=None, depth=self.pipeline_depth)
+        return harvest
+
+    def _check_overflow(self, rec: dict) -> None:
+        """The tree, text and kstats planes of a mixed tick's readback."""
         if rec["tree_overflow"] is not None:
             self.last_tree_overflow = {
                 row: n
@@ -645,7 +687,6 @@ class ShardedServing:
                 raise RuntimeError(
                     f"text block overflow on rows {sorted(overflowed)}; "
                     "size text blocks for the tick width")
-        return harvest
 
     # -- capacity maintenance --------------------------------------------------
 

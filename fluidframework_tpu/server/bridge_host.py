@@ -99,56 +99,68 @@ class BridgeFrontDoor:
     # -- event pump ------------------------------------------------------------
 
     def _pump_loop(self) -> None:
+        # With a storm controller attached, this thread is its serving
+        # thread: the wait for frames and the idle passes are timed as
+        # stages of the storm ledger (frame_wait, idle_service), so a
+        # tick's wall clock is accounted for between two harvests.
         while not self._stop.is_set():
+            storm = getattr(self.service, "storm", None)
+            ledger = storm.ledger if storm is not None else None
             # Blocking poll (cv in the C++ side): no busy-wait, and the
             # bounded timeout keeps close() responsive.
-            event = self._bridge.poll(wait_ms=50)
+            if ledger is None:
+                event = self._bridge.poll(wait_ms=50)
+            else:
+                with ledger.stage("frame_wait"):
+                    event = self._bridge.poll(wait_ms=50)
             if event is None:
-                # Idle: non-blocking storm service — drain acks whose
-                # group commit completed, run partial-cohort tails, and
-                # harvest ready in-flight ticks. Deliberately NOT a full
-                # flush(): a windowed (flow-controlled) sender goes
-                # quiet between frames while ticks are still in flight,
-                # and a forced settle on every quiet poll would collapse
-                # the dispatch/fsync overlap back into lockstep ticks.
-                storm = getattr(self.service, "storm", None)
-                if storm is not None and (storm._frames or storm._inflight
-                                          or storm._unacked):
-                    try:
-                        storm.idle_drain()
-                    except Exception as err:
-                        self.logger.send_error("BridgeStormFlushFailed", err)
-                # Idle residency sweep on the serving thread: docs idle
-                # past the timeout demote to the cold tier here (the
-                # bridge deployment never pumps the service's own idle
-                # pass — this IS its idle cadence), freeing pool slots
-                # for the next cold-doc hydration.
-                residency = getattr(getattr(self.service, "storm", None),
-                                    "residency", None)
-                if residency is not None:
-                    try:
-                        # Bounded per pass (each eviction is a flush +
-                        # fsync + upload on this serving thread); the
-                        # next idle poll continues the drain.
-                        residency.evict_idle(max_evictions=32)
-                    except Exception as err:
-                        self.logger.send_error("BridgeEvictIdleFailed",
-                                               err)
-                # Viewer-plane idle drain: flush queued broadcast frames
-                # to viewer transports between ticks (resumed viewers,
-                # per-op traffic on otherwise-quiet docs).
-                viewers = getattr(self.service, "viewers", None)
-                if viewers is not None and viewers.active_rooms:
-                    try:
-                        viewers.drain_all()
-                    except Exception as err:
-                        self.logger.send_error("BridgeViewerDrainFailed",
-                                               err)
+                if ledger is None:
+                    self._idle_pass(storm)
+                else:
+                    with ledger.stage("idle_service"):
+                        self._idle_pass(storm)
                 continue
             try:
                 self._dispatch(*event)
             except Exception as err:  # the pump must never die
                 self.logger.send_error("BridgePumpFailed", err)
+
+    def _idle_pass(self, storm) -> None:
+        # Idle: non-blocking storm service — drain acks whose group
+        # commit completed, run partial-cohort tails, and harvest ready
+        # in-flight ticks. Deliberately NOT a full flush(): a windowed
+        # (flow-controlled) sender goes quiet between frames while ticks
+        # are still in flight, and a forced settle on every quiet poll
+        # would collapse the dispatch/fsync overlap back into lockstep
+        # ticks.
+        if storm is not None and (storm._frames or storm._inflight
+                                  or storm._unacked):
+            try:
+                storm.idle_drain()
+            except Exception as err:
+                self.logger.send_error("BridgeStormFlushFailed", err)
+        # Idle residency sweep on the serving thread: docs idle past the
+        # timeout demote to the cold tier here (the bridge deployment
+        # never pumps the service's own idle pass — this IS its idle
+        # cadence), freeing pool slots for the next cold-doc hydration.
+        residency = getattr(storm, "residency", None)
+        if residency is not None:
+            try:
+                # Bounded per pass (each eviction is a flush + fsync +
+                # upload on this serving thread); the next idle poll
+                # continues the drain.
+                residency.evict_idle(max_evictions=32)
+            except Exception as err:
+                self.logger.send_error("BridgeEvictIdleFailed", err)
+        # Viewer-plane idle drain: flush queued broadcast frames to
+        # viewer transports between ticks (resumed viewers, per-op
+        # traffic on otherwise-quiet docs).
+        viewers = getattr(self.service, "viewers", None)
+        if viewers is not None and viewers.active_rooms:
+            try:
+                viewers.drain_all()
+            except Exception as err:
+                self.logger.send_error("BridgeViewerDrainFailed", err)
 
     def _dispatch(self, conn_id: int, kind: int, body: bytes) -> None:
         if kind == EV_OPEN:
@@ -167,19 +179,28 @@ class BridgeFrontDoor:
     def _handle_data(self, conn_id: int, body: bytes) -> None:
         # Bridge-ingress timestamp: stamped BEFORE the codec decode so a
         # sampled trace's first hop (and the ledger's ingress_decode
-        # split) covers the decode itself.
+        # stage) covers the decode itself.
         t_rx = time.monotonic_ns()
+        if is_storm_body(body):
+            storm = getattr(self.service, "storm", None)
+            if storm is None:
+                self._handle_storm(conn_id, body, t_rx)
+                return
+            # The stage opens here and the storm controller closes it once
+            # the frame is validated.
+            ingress = storm.ledger.stage("ingress_decode",
+                                         since_ns=t_rx).__enter__()
+            try:
+                self._handle_storm(conn_id, body, t_rx)
+            finally:
+                if ingress.open:
+                    # The controller never took the frame (no session, or
+                    # a body the codec refused): no tick serves it.
+                    ingress.__exit__(None, None, None)
+                    storm.ledger.refund(ingress)
+            return
         session = self._sessions.get(conn_id)
         if session is None:
-            return
-        if is_storm_body(body):
-            try:
-                resp = session.handle_binary(body, ingress_ns=t_rx)
-            except Exception as err:
-                self.logger.send_error("BridgeStormFailed", err)
-                resp = {"rid": None, "error": repr(err)}
-            if resp is not None:
-                session.push(resp)
             return
         try:
             req: Any = decode_body(body)
@@ -196,6 +217,18 @@ class BridgeFrontDoor:
                                    op=req.get("op"))
             resp = {"rid": req.get("rid"), "error": repr(err)}
         session.push(resp)
+
+    def _handle_storm(self, conn_id: int, body: bytes, t_rx: int) -> None:
+        session = self._sessions.get(conn_id)
+        if session is None:
+            return
+        try:
+            resp = session.handle_binary(body, ingress_ns=t_rx)
+        except Exception as err:
+            self.logger.send_error("BridgeStormFailed", err)
+            resp = {"rid": None, "error": repr(err)}
+        if resp is not None:
+            session.push(resp)
 
     def close(self) -> None:
         self._stop.set()

@@ -34,6 +34,7 @@ from ..native import OpLog
 from ..protocol.codec import from_wire, register_codec, to_wire
 from ..protocol.messages import MessageType
 from ..utils import faults
+from ..utils.metrics import ProgramSpan
 from .bus import BusMessage, MessageBus, Topic
 from .sequencer import RawOperation
 
@@ -247,6 +248,11 @@ class GroupCommitLog:
         #: exceptions are swallowed here and surfaced by the plane's own
         #: health gauges.
         self.on_batch_durable: Callable[[list], None] | None = None
+        #: Writer-thread counters: ns spent in fsync, and batches made
+        #: durable (each batch is an ``ff.wal.write`` then an
+        #: ``ff.wal.fsync`` span on the profiler's timeline).
+        self.fsync_ns = 0
+        self.batches = 0
         self._stop = False
         self._thread = threading.Thread(target=self._writer_loop,
                                         name="group-commit-wal", daemon=True)
@@ -356,21 +362,26 @@ class GroupCommitLog:
                 continue
             try:
                 with self._io:
-                    for idx in batch:
-                        if idx < self._appended_next:
-                            continue  # appended before a failed fsync
-                        data = b"".join(bytes(p) for p in parts_of[idx])
-                        got = self._log.append(data)
-                        # Advance BEFORE asserting: the record is on the
-                        # file either way, and a retry after the assert
-                        # must never append it twice.
-                        self._appended_next = max(self._appended_next,
-                                                  got + 1)
-                        assert got == idx, (got, idx)
+                    with ProgramSpan("wal.write", records=len(batch)):
+                        for idx in batch:
+                            if idx < self._appended_next:
+                                continue  # appended before a failed fsync
+                            data = b"".join(bytes(p)
+                                            for p in parts_of[idx])
+                            got = self._log.append(data)
+                            # Advance BEFORE asserting: the record is on
+                            # the file either way, and a retry after the
+                            # assert must never append it twice.
+                            self._appended_next = max(self._appended_next,
+                                                      got + 1)
+                            assert got == idx, (got, idx)
                     faults.crashpoint("wal.pre_fsync")
                     if self._fsync:
                         faults.failpoint("wal.fsync")
-                        self._log.sync()
+                        t_sync = time.monotonic_ns()
+                        with ProgramSpan("wal.fsync"):
+                            self._log.sync()
+                        self.fsync_ns += time.monotonic_ns() - t_sync
                 if self._fsync and self._commit_latency_s:
                     # Modeled commit round trip OUTSIDE the io lock:
                     # it delays the durable watermark (as a replicated
@@ -416,6 +427,7 @@ class GroupCommitLog:
                     self.breaker.record_failure()
                 return
             self.breaker.record_success()
+            self.batches += 1
             with self._lock:
                 self._error = None
                 for idx in batch:

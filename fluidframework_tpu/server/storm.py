@@ -37,6 +37,7 @@ mixed dict-path traffic on a storm channel is rejected.
 from __future__ import annotations
 
 import base64
+import contextlib
 import functools
 import time
 from typing import Any, Callable, NamedTuple
@@ -111,6 +112,10 @@ class _TrimGate:
         return True
 
 
+#: What ``StormController._stage`` times during WAL replay: nothing.
+_NO_STAGE = contextlib.nullcontext()
+
+
 class _Frame(NamedTuple):
     push: Callable[[dict], None] | None
     rid: Any
@@ -119,7 +124,7 @@ class _Frame(NamedTuple):
     counts: np.ndarray  # i32[n_docs] per-doc op counts
     meta: np.ndarray    # i32[n_docs, 3] (cseq0, ref, count) columns
     trace: Any = None   # (client tc, session scope) tracer key or None
-    staged_ns: tuple = (0, 0)  # (decode, admit) ns refunded on shed
+    staged: tuple = ()  # (decode, admit) ledger stages refunded on shed
     mega: Any = None    # per-entry mega-doc descriptors (megadoc.py)
     tenant: str = "default"  # session-validated tenant (QoS composition)
     t0: int = 0         # ingress monotonic ns (per-tenant ack latency)
@@ -613,18 +618,25 @@ class StormController:
         #: Ticks each doc participated in (telemetry: the zero-lost-ticks
         #: invariant for a quarantined doc's batch peers asserts on this).
         self.doc_tick_counts: dict[str, int] = {}
+        # wal_fsync_ns / wal_batches mirror the group-commit writer's
+        # counters (refreshed at harvest and flush): fsync time apart
+        # from the serving thread's late drains.
         self.stats = {"ticks": 0, "sequenced_ops": 0, "submitted_ops": 0,
                       "nacked_or_ignored_ops": 0,
                       "shed_frames": 0, "shed_ops": 0,
                       "quarantined_docs": 0, "readmitted_docs": 0,
-                      "degraded_rejects": 0, "quorum_rejects": 0}
-        self.tick_seconds: list[float] = []  # submit→harvest per round
-        self.harvest_intervals: list[float] = []  # completion cadence
+                      "degraded_rejects": 0, "quorum_rejects": 0,
+                      "wal_fsync_ns": 0, "wal_batches": 0}
         # Observability plane (the round-10 tentpole): one fixed-shape
         # stage record per tick into a ring buffer + per-stage Histograms
         # in the shared registry (alfred get_metrics exports them; the
         # monitor renders the attribution bar), and a per-op trace joiner
         # for frames that carry a sampled trace id ("tc" header field).
+        # Stages are timed through ledger.stage(), which also puts each
+        # on the profiler's clock as an ff.storm.stage.* span; a record
+        # is cut at each harvest and holds everything timed since the
+        # previous one (the bridge pump times its waits and idle passes
+        # into the same ledger).
         from ..utils import NullLogger, StageLedger, TraceSpans
         self.logger = logger if logger is not None else NullLogger()
         self.ledger = StageLedger(registry=merge_host.metrics,
@@ -638,9 +650,6 @@ class StormController:
         # trace ids are ignored (the frame still serves normally).
         self.max_traces_per_tick = 64
         self._traced_pending = 0
-        # ingress decode / admission ns spent on frames buffered toward
-        # the NEXT tick (consumed by its ledger record at flush).
-        self._staged_ns = {"ingress_decode": 0, "admission": 0}
         # Depth-N pipeline (SURVEY §7 hard part (c), round-14 tentpole):
         # up to N ticks stay in flight; each round HARVESTS the due tick
         # BEFORE staging the next one, so tick N's WAL append (and its
@@ -667,10 +676,6 @@ class StormController:
         self.pipeline_depth = 1 if self._auto_depth \
             else max(0, pipeline_depth)
         self._inflight: list[dict] = []
-        self._last_harvest: float | None = None
-        # Monotonic-ns completion of the last NON-replay harvest: the
-        # ledger's wall-clock slice per tick (cadence) derives from it.
-        self._last_harvest_done_ns: int | None = None
         # Host staging generations (double/N-buffered): the scatter
         # arrays a dispatched tick's device transfer may still alias are
         # never the ones the next round writes — see _staging_gen.
@@ -701,50 +706,32 @@ class StormController:
 
         ``ingress_ns`` is the transport's receive timestamp
         (``time.monotonic_ns``), stamped BEFORE the codec decode so the
-        ledger's ingress_decode split covers it (None = entry here); a
-        frame whose header carries a sampled trace id (``"tc"``) gets
-        its ingress/admit hops marked on the controller's tracer."""
-        if ingress_ns is None:
+        ledger's ingress_decode stage covers it (None = entry here). A
+        front door that already opened the ``ingress_decode`` stage at
+        receive (the bridge pump) has it closed here, once the frame is
+        validated. A frame whose header carries a sampled trace id
+        (``"tc"``) gets its ingress/admit hops marked on the controller's
+        tracer."""
+        ingress = None
+        if not self._replay:
+            ingress = self.ledger.open_stage()
+            if ingress is None or ingress.name != "ingress_decode":
+                ingress = self.ledger.stage("ingress_decode",
+                                            since_ns=ingress_ns).__enter__()
+            ingress_ns = ingress.t0
+        elif ingress_ns is None:
             ingress_ns = time.monotonic_ns()
-        entries = header.get("docs")
-        if not isinstance(entries, list) or not entries:
-            raise ValueError("storm frame without docs")
-        docs: list[tuple[str, str, int, int, int]] = []
-        seen: set[str] = set()
-        for entry in entries:
-            if not (isinstance(entry, (list, tuple)) and len(entry) == 5):
-                raise ValueError(f"bad storm doc entry: {entry!r}")
-            # NB the entry's writer id must NOT rebind the ``client_id``
-            # parameter — admission below keys on the SESSION identity,
-            # and a shadowing loop variable would hand the throttle a
-            # client-chosen string (fresh token bucket per frame).
-            doc_id, doc_client, cseq0, ref_seq, count = entry
-            count = int(count)
-            if not 0 < count <= self.MAX_COUNT:
-                raise ValueError(f"bad storm count {count} for {doc_id!r}")
-            if doc_id in seen:
-                # One sequencer row per doc per tick: the numpy scatter is
-                # last-writer-wins, so an in-frame repeat would silently
-                # drop the first batch while acking it as sequenced.
-                raise ValueError(f"doc {doc_id!r} repeats within one frame")
-            seen.add(doc_id)
-            docs.append((str(doc_id), str(doc_client), int(cseq0),
-                         int(ref_seq), count))
-        # Columnar from here down: ONE payload view + per-doc count/meta
-        # arrays — no per-doc np.frombuffer, no byte copy (the words view
-        # aliases the receive buffer all the way into the tick scatter).
-        meta = np.array([entry[2:] for entry in docs], np.int32)
-        counts = meta[:, 2]
-        offset = int(counts.sum())
-        if offset * 4 > len(payload):
-            raise ValueError("storm payload shorter than doc counts")
-        words = np.frombuffer(payload, np.uint32, offset)
-        max_slot = int((words & np.uint32(0xFFC)).max()) >> 2 \
-            if offset else 0
-        if max_slot >= self.max_key_slots:
-            raise ValueError(
-                f"storm key slot {max_slot} >= max_key_slots "
-                f"{self.max_key_slots}")
+        parsed = None
+        try:
+            parsed = self._parse_frame(header, payload)
+        finally:
+            if ingress is not None:
+                ingress.__exit__(None, None, None)
+                if parsed is None:
+                    # Never buffered: no tick inherits its decode time.
+                    self.ledger.refund(ingress)
+        docs, words, counts, meta = parsed
+        offset = len(words)
         # Admission gates run AFTER validation (a malformed frame is the
         # sender's error, not overload) and only on live traffic — replay
         # (recovery / readmit) re-runs already-admitted history.
@@ -761,31 +748,31 @@ class StormController:
             # keys a dict on it, so unhashable shapes are ignored — a
             # valid frame must never be nacked over its trace id.
         trace = None
-        staged = (0, 0)
-        t_validated = time.monotonic_ns()
+        staged = ()
         if not self._replay:
-            retry = self._admit(push, header, docs, offset,
-                                tenant_id, client_id)
-            t_admitted = time.monotonic_ns()
+            with self.ledger.stage("admission") as admission:
+                retry = self._admit(push, header, docs, offset,
+                                    tenant_id, client_id)
+            staged = (ingress, admission)
             if retry is not None:
-                return  # shed: its decode/admit ns never reaches a tick
-            # Charged only once the frame is BUFFERED — shed frames'
-            # time must not inflate a surviving tick's attribution (a
-            # frame shed LATER, at quarantine, refunds via staged_ns).
+                # Shed: its decode/admit ns never reaches a tick. Charged
+                # only once the frame is BUFFERED — shed frames' time
+                # must not inflate a surviving tick's attribution (a
+                # frame shed LATER, at quarantine, refunds via staged).
+                for stage in staged:
+                    self.ledger.refund(stage)
+                return
             # The trace slot likewise allocates only now: a traced-but-
             # shed frame must not consume the per-tick cap (tracing
             # would starve during exactly the overload it should
             # diagnose).
-            staged = (t_validated - ingress_ns, t_admitted - t_validated)
-            self._staged_ns["ingress_decode"] += staged[0]
-            self._staged_ns["admission"] += staged[1]
             if tc is not None \
                     and self._traced_pending < self.max_traces_per_tick:
                 trace = (tc, self._trace_seq)
                 self._trace_seq += 1
                 self._traced_pending += 1
                 self.tracer.mark(trace, "ingress", ingress_ns)
-                self.tracer.mark(trace, "admit", t_admitted)
+                self.tracer.mark(trace, "admit", admission.t1)
         # Mega-doc ingress: promoted-doc entries are rewritten to their
         # writers' LANE sub-doc ids (stateless hash — up to L writer
         # frames of one doc become DISJOINT cohort members and serve in
@@ -814,6 +801,49 @@ class StormController:
             # (next tick's early frames) waits for its cohort instead of
             # fragmenting into tiny device ticks.
             self.flush(force=False)
+
+    def _parse_frame(self, header: dict, payload: memoryview) -> tuple:
+        """Validate one storm frame: (docs, words, counts, meta), or
+        ValueError before anything is buffered."""
+        entries = header.get("docs")
+        if not isinstance(entries, list) or not entries:
+            raise ValueError("storm frame without docs")
+        docs: list[tuple[str, str, int, int, int]] = []
+        seen: set[str] = set()
+        for entry in entries:
+            if not (isinstance(entry, (list, tuple)) and len(entry) == 5):
+                raise ValueError(f"bad storm doc entry: {entry!r}")
+            # NB the entry's writer id is not the SESSION identity that
+            # admission keys on (submit_frame's ``client_id``): the frame
+            # is client-controlled.
+            doc_id, doc_client, cseq0, ref_seq, count = entry
+            count = int(count)
+            if not 0 < count <= self.MAX_COUNT:
+                raise ValueError(f"bad storm count {count} for {doc_id!r}")
+            if doc_id in seen:
+                # One sequencer row per doc per tick: the numpy scatter is
+                # last-writer-wins, so an in-frame repeat would silently
+                # drop the first batch while acking it as sequenced.
+                raise ValueError(f"doc {doc_id!r} repeats within one frame")
+            seen.add(doc_id)
+            docs.append((str(doc_id), str(doc_client), int(cseq0),
+                         int(ref_seq), count))
+        # Columnar from here down: ONE payload view + per-doc count/meta
+        # arrays — no per-doc np.frombuffer, no byte copy (the words view
+        # aliases the receive buffer all the way into the tick scatter).
+        meta = np.array([entry[2:] for entry in docs], np.int32)
+        counts = meta[:, 2]
+        offset = int(counts.sum())
+        if offset * 4 > len(payload):
+            raise ValueError("storm payload shorter than doc counts")
+        words = np.frombuffer(payload, np.uint32, offset)
+        max_slot = int((words & np.uint32(0xFFC)).max()) >> 2 \
+            if offset else 0
+        if max_slot >= self.max_key_slots:
+            raise ValueError(
+                f"storm key slot {max_slot} >= max_key_slots "
+                f"{self.max_key_slots}")
+        return docs, words, counts, meta
 
     def _admit(self, push, header: dict, docs: list, n_ops: int,
                tenant_id: str, client_id: str | None) -> float | None:
@@ -1003,6 +1033,7 @@ class StormController:
                         "storm.degraded_flushes").inc()
                 else:
                     self._drain_durable_acks()
+            self._sync_wal_stats()
         if (self.snapshot_interval_ticks is not None
                 and self.snapshots is not None
                 and not self._replay and not self._in_checkpoint
@@ -1077,6 +1108,21 @@ class StormController:
             dw = min(dw, self.replication.replicated_len)
         return dw
 
+    @property
+    def _staged_ns(self) -> dict:
+        """Ingress decode / admission ns spent on buffered frames since
+        the last ledger record (the next record holds them; a frame shed
+        before it is cut takes its share back)."""
+        pending = self.ledger.pending()
+        return {s: pending[s] for s in ("ingress_decode", "admission")}
+
+    def _stage(self, name: str, tick: int | None = None):
+        """The ledger stage ``name`` on live traffic; nothing during WAL
+        replay (reconstruction is not serving)."""
+        if self._replay:
+            return _NO_STAGE
+        return self.ledger.stage(name, tick)
+
     def _drain_durable_acks(self) -> None:
         """Push withheld acks whose tick the WAL has fsynced (and the
         follower quorum journaled, when replication is attached) —
@@ -1085,34 +1131,45 @@ class StormController:
         dw = self._group_wal.durable_len
         if self.replication is not None:
             dw = min(dw, self.replication.replicated_len)
-        if self._inflight and self._unacked and self._unacked[0][0] < dw:
+        if not self._unacked or self._unacked[0][0] >= dw:
+            return
+        if self._inflight:
             # Chaos kill class "fsync-complete-before-readback": tick N
             # is durable and about to ack while a later tick's device
             # work is still in flight (its readback not yet taken).
             # Recovery must replay N byte-identically and must never
             # treat the in-flight tick as acked or durable.
             faults.crashpoint("storm.overlap_fsynced")
-        while self._unacked and self._unacked[0][0] < dw:
-            _tick, acks, t_harvested, led = self._unacked.pop(0)
-            t_drain = time.monotonic_ns()
-            if led is not None:
-                # The tick's commit-wait: harvest done → fsync watermark
-                # passed (the acked-durable latency the ledger attributes).
-                self.ledger.amend(led, "wal_commit_wait",
-                                  t_drain - t_harvested)
-            faults.crashpoint("storm.pre_ack")
-            for frame, payload in acks:
-                payload["dw"] = dw
-                if frame.trace is not None:
-                    self.tracer.mark(frame.trace, "durable", t_drain)
-                    self._stamp_trace_ack(frame, payload)
-                if frame.t0:
-                    # Per-tenant SLO surface: submit→durable-ack latency
-                    # into the tenant's ack histogram (get_metrics
-                    # exports p50/p99; render_tenants renders them).
-                    self.qos.observe_ack(frame.tenant,
-                                         (t_drain - frame.t0) / 1e9)
-                frame.push(payload)
+        with self._stage("ack_send"):
+            while self._unacked and self._unacked[0][0] < dw:
+                _tick, acks, t_harvested, led = self._unacked.pop(0)
+                t_drain = time.monotonic_ns()
+                if led is not None:
+                    # The tick's commit-wait: harvest done → fsync
+                    # watermark passed (the acked-durable latency the
+                    # ledger attributes).
+                    self.ledger.amend(led, "wal_commit_wait",
+                                      t_drain - t_harvested)
+                faults.crashpoint("storm.pre_ack")
+                for frame, payload in acks:
+                    payload["dw"] = dw
+                    if frame.trace is not None:
+                        self.tracer.mark(frame.trace, "durable", t_drain)
+                        self._stamp_trace_ack(frame, payload)
+                    if frame.t0:
+                        # Per-tenant SLO surface: submit→durable-ack
+                        # latency into the tenant's ack histogram
+                        # (get_metrics exports p50/p99; render_tenants
+                        # renders them).
+                        self.qos.observe_ack(frame.tenant,
+                                             (t_drain - frame.t0) / 1e9)
+                    frame.push(payload)
+
+    def _sync_wal_stats(self) -> None:
+        wal = self._group_wal
+        if wal is not None:
+            self.stats["wal_fsync_ns"] = wal.fsync_ns
+            self.stats["wal_batches"] = wal.batches
 
     def _push_synth_acks(self, acks: list, mega_plans: dict) -> None:
         """Deliver acks for a cohort that collapsed to zero descs (every
@@ -1124,31 +1181,32 @@ class StormController:
         WAL withholds these acks exactly like tick acks (the client
         retries; live and recovered decisions are deterministic either
         way)."""
-        from ..protocol.codec import StormAck
-        if self._group_wal is not None and not self._replay:
-            from .durable_store import WalDegradedError
-            try:
-                self._group_wal.sync()
-            except WalDegradedError:
-                return  # not durable: withhold (clients resend)
-            if self.replication is not None \
-                    and self.replication.replicated_len \
-                    < self._group_wal.durable_len:
-                # Durable locally but not on the follower quorum: the
-                # same withhold discipline, one tier out.
-                return
-        dw = self.acked_watermark
-        for ack_i, (frame, _i0, _i1) in enumerate(acks):
-            if frame.push is None:
-                continue
-            plan = mega_plans.get(ack_i) or []
-            rows = np.asarray([v for kind, v in plan if kind == "s"],
-                              np.int32).reshape(-1, 4)
-            payload = StormAck(frame.rid, rows)
-            payload["dw"] = dw
-            if frame.trace is not None:
-                self._stamp_trace_ack(frame, payload)
-            frame.push(payload)
+        with self._stage("ack_send"):
+            from ..protocol.codec import StormAck
+            if self._group_wal is not None and not self._replay:
+                from .durable_store import WalDegradedError
+                try:
+                    self._group_wal.sync()
+                except WalDegradedError:
+                    return  # not durable: withhold (clients resend)
+                if self.replication is not None \
+                        and self.replication.replicated_len \
+                        < self._group_wal.durable_len:
+                    # Durable locally but not on the follower quorum: the
+                    # same withhold discipline, one tier out.
+                    return
+            dw = self.acked_watermark
+            for ack_i, (frame, _i0, _i1) in enumerate(acks):
+                if frame.push is None:
+                    continue
+                plan = mega_plans.get(ack_i) or []
+                rows = np.asarray([v for kind, v in plan if kind == "s"],
+                                  np.int32).reshape(-1, 4)
+                payload = StormAck(frame.rid, rows)
+                payload["dw"] = dw
+                if frame.trace is not None:
+                    self._stamp_trace_ack(frame, payload)
+                frame.push(payload)
 
     def _stamp_trace_ack(self, frame: _Frame, payload: dict) -> None:
         """Finish a sampled frame's span at ack transmit: the joined hop
@@ -1173,8 +1231,6 @@ class StormController:
         DISJOINT doc set falls short of the tick threshold declines
         (returns False) — pipelined senders whose later ticks arrive
         early must not fragment the cohort into undersized device ticks."""
-        import time as _time
-
         if self.wal_degraded and not self._replay:
             # Breaker open: do NOT advance device state ahead of a WAL
             # that cannot journal it — frames stay queued (new ones are
@@ -1195,7 +1251,6 @@ class StormController:
             self.merge_host.metrics.gauge("repl.parked_docs").set(
                 self._pending_docs)
             return False
-        round_start = _time.perf_counter()
         queue_depth = self._pending_docs
         frames, self._frames, self._pending_docs = self._frames, [], 0
         # Bus-path ops already admitted must sequence first (per-doc total
@@ -1203,12 +1258,13 @@ class StormController:
         # in-round flag keeps the pump's idle pass from evicting docs out
         # from under the cohort being assembled (residency.evict refuses
         # while it is set).
-        self._in_round = True
-        try:
-            self.service.pump()
-            self.seq_host._flush_pending()
-        finally:
-            self._in_round = False
+        with self._stage("service_pump"):
+            self._in_round = True
+            try:
+                self.service.pump()
+                self.seq_host._flush_pending()
+            finally:
+                self._in_round = False
 
         # Tick composition is the QoS seam (server/qos.py): the deficit
         # round robin drains per-tenant queues by weight into the tick's
@@ -1222,51 +1278,47 @@ class StormController:
         # doc's total order relative to the single-lane path). A
         # single-tenant compose with no slot budget reduces exactly to
         # the legacy first-come scan.
-        if self._replay:
-            # Replay never re-composes: the recorded cohort IS the
-            # composition (one frame per replayed tick), and scheduler
-            # state comes from the tick headers — a synthetic replay
-            # frame must not register phantom tenants.
-            qplan = {"selected": frames, "kept": [], "charge": {},
-                     "slices": {}, "quantum": None}
-        else:
-            qplan = self.qos.compose(frames, self.tick_slot_budget)
-        selected: list[_Frame] = qplan["selected"]
-        kept: list[_Frame] = qplan["kept"]
-        # A slot budget below the flush threshold caps every cohort
-        # under it — a full-budget tick IS a full round then, or
-        # threshold-triggered flushing would decline forever.
-        full_bar = self.flush_threshold_docs \
-            if self.tick_slot_budget is None \
-            else min(self.flush_threshold_docs, self.tick_slot_budget)
-        if require_full and sum(len(f.docs) for f in selected) \
-                < full_bar:
-            # Undersized cohort: put everything back; the idle drain (or
-            # the cohort completing) will run it. No mega decision has
-            # run yet and the scheduler plan was NOT committed, so
-            # re-buffering is side-effect free.
-            self._frames = frames + self._frames
-            self._pending_docs += sum(len(f.docs) for f in frames)
-            return False
-        self.qos.commit(qplan)
-        if not self._replay:
-            # Chaos kill class "mid-composition": scheduler state moved
-            # (deficits charged, rotation advanced) but the tick neither
-            # dispatched nor journaled. Recovery restores the scheduler
-            # from the last durable tick's header; the selected frames
-            # come back via client resend and recompose deterministically
-            # against that state — the single-tenant twin diff proves
-            # fairness never changes converged replica state.
-            faults.crashpoint("storm.qos_mid_compose")
-        # A kept frame's staged decode/admit ns is consumed by THIS
-        # round's record (it was already pooled) — zero it on the frame
-        # so a later quarantine shed refunds exactly what is still
-        # staged, never double-subtracting.
-        self._frames.extend(f._replace(staged_ns=(0, 0))
-                            for f in kept)
-        self._pending_docs += sum(len(f.docs) for f in kept)
-        if not self._replay:
-            self.qos.reset_pending(self._frames)
+        with self._stage("cohort"):
+            if self._replay:
+                # Replay never re-composes: the recorded cohort IS the
+                # composition (one frame per replayed tick), and scheduler
+                # state comes from the tick headers — a synthetic replay
+                # frame must not register phantom tenants.
+                qplan = {"selected": frames, "kept": [], "charge": {},
+                         "slices": {}, "quantum": None}
+            else:
+                qplan = self.qos.compose(frames, self.tick_slot_budget)
+            selected: list[_Frame] = qplan["selected"]
+            kept: list[_Frame] = qplan["kept"]
+            # A slot budget below the flush threshold caps every cohort
+            # under it — a full-budget tick IS a full round then, or
+            # threshold-triggered flushing would decline forever.
+            full_bar = self.flush_threshold_docs \
+                if self.tick_slot_budget is None \
+                else min(self.flush_threshold_docs, self.tick_slot_budget)
+            if require_full and sum(len(f.docs) for f in selected) \
+                    < full_bar:
+                # Undersized cohort: put everything back; the idle drain (or
+                # the cohort completing) will run it. No mega decision has
+                # run yet and the scheduler plan was NOT committed, so
+                # re-buffering is side-effect free.
+                self._frames = frames + self._frames
+                self._pending_docs += sum(len(f.docs) for f in frames)
+                return False
+            self.qos.commit(qplan)
+            if not self._replay:
+                # Chaos kill class "mid-composition": scheduler state moved
+                # (deficits charged, rotation advanced) but the tick neither
+                # dispatched nor journaled. Recovery restores the scheduler
+                # from the last durable tick's header; the selected frames
+                # come back via client resend and recompose deterministically
+                # against that state — the single-tenant twin diff proves
+                # fairness never changes converged replica state.
+                faults.crashpoint("storm.qos_mid_compose")
+            self._frames.extend(kept)
+            self._pending_docs += sum(len(f.docs) for f in kept)
+            if not self._replay:
+                self.qos.reset_pending(self._frames)
         # HARVEST-FIRST (the round-14 pipelining order): settle the due
         # tick BEFORE staging this one, so its readback is taken the
         # moment it matters and its WAL append reaches the writer thread
@@ -1280,214 +1332,200 @@ class StormController:
         # replay re-applies mirror updates in live order.
         while len(self._inflight) >= max(1, self.pipeline_depth):
             self._harvest_one(self._inflight.pop(0))
-        # WAL replay re-runs the tick with its RECORDED timestamp so the
-        # sequencer planes (client last_update) rebuild byte-identically.
-        # Computed before cohort assembly: the mega combiner stamps the
-        # same clock the device ts plane carries.
-        now = (self._replay_ts if self._replay_ts is not None
-               else self.service._clock())
-        descs: list[tuple[str, str, int, int, int]] = []
-        frame_words: list[np.ndarray] = []   # one payload view per frame
-        frame_counts: list[np.ndarray] = []
-        metas: list[np.ndarray] = []
-        acks: list[tuple[_Frame, int, int]] = []  # frame -> desc [i0, i1)
-        mega_rows: dict[int, tuple] = {}   # desc idx -> doc-space quad
-        mega_plans: dict[int, list] = {}   # ack idx -> per-entry plan
-        for frame in selected:
-            i0 = len(descs)
-            if frame.mega is not None and not self._replay:
-                # The combiner: doc-space tickets in cohort admission
-                # order (== the single-lane interleaving), dup prefixes
-                # trimmed out of the words, zero-op entries dropped with
-                # synthesized ack rows.
-                (fdesc, fwords, fcounts, fmeta, plan,
-                 desc_rows) = self.megadoc.decide_frame(frame, now)
-                descs.extend(fdesc)
-                frame_words.append(fwords)
-                frame_counts.append(fcounts)
-                metas.append(fmeta)
-                for rel, row in enumerate(desc_rows):
-                    if row is not None:
-                        mega_rows[i0 + rel] = row
-                if len(fdesc) != len(frame.docs):
-                    # Dropped entries: the ack is rebuilt positionally
-                    # from this plan (synth row or kept-desc index).
-                    mega_plans[len(acks)] = [
-                        ("s", item.synth) if item.synth is not None
-                        else ("l", i0 + item.desc_rel)
-                        for item in plan]
-            else:
-                descs.extend(frame.docs)
-                frame_words.append(frame.words)
-                frame_counts.append(frame.counts)
-                metas.append(frame.meta)
-            acks.append((frame, i0, len(descs)))
-        if not descs:
-            # Every selected entry resolved to a zero-op outcome: no
-            # tick to ride — deliver the synthesized acks now (nothing
-            # was sequenced, so there is no durability to wait on; the
-            # one state-bearing zero-op outcome journaled its own
-            # control record in decide_frame).
-            self._push_synth_acks(acks, mega_plans)
-            return True
-        if self._replay and self.megadoc is not None:
-            # Replayed lane entries are already cleaned: rebuild the
-            # combiner's mirrors + combine logs in desc order (== the
-            # order live decisions ran).
-            self.megadoc.replay_decide(descs, now)
-        if self.megadoc is not None and not self._replay:
-            self.megadoc.finish_cohort(descs)
-        # Stage ledger: the tick that runs consumes the decode/admission
-        # ns staged by its frames' submit_frame calls (a frame DEFERRED
-        # to the next round charges the round it was decoded in —
-        # attribution, not exact accounting); scatter starts now. Replay
-        # rounds record nothing and must not steal ns staged by live
-        # frames (readmit replays interleave with serving).
-        if self._replay:
-            stage_ns = {}
-        else:
-            stage_ns = dict(self._staged_ns)
-            self._staged_ns = {"ingress_decode": 0, "admission": 0}
+        with self._stage("cohort"):
+            # WAL replay re-runs the tick with its RECORDED timestamp so the
+            # sequencer planes (client last_update) rebuild byte-identically.
+            # Computed before cohort assembly: the mega combiner stamps the
+            # same clock the device ts plane carries.
+            now = (self._replay_ts if self._replay_ts is not None
+                   else self.service._clock())
+            descs: list[tuple[str, str, int, int, int]] = []
+            frame_words: list[np.ndarray] = []   # one payload view per frame
+            frame_counts: list[np.ndarray] = []
+            metas: list[np.ndarray] = []
+            acks: list[tuple[_Frame, int, int]] = []  # frame -> desc [i0, i1)
+            mega_rows: dict[int, tuple] = {}   # desc idx -> doc-space quad
+            mega_plans: dict[int, list] = {}   # ack idx -> per-entry plan
+            for frame in selected:
+                i0 = len(descs)
+                if frame.mega is not None and not self._replay:
+                    # The combiner: doc-space tickets in cohort admission
+                    # order (== the single-lane interleaving), dup prefixes
+                    # trimmed out of the words, zero-op entries dropped with
+                    # synthesized ack rows.
+                    (fdesc, fwords, fcounts, fmeta, plan,
+                     desc_rows) = self.megadoc.decide_frame(frame, now)
+                    descs.extend(fdesc)
+                    frame_words.append(fwords)
+                    frame_counts.append(fcounts)
+                    metas.append(fmeta)
+                    for rel, row in enumerate(desc_rows):
+                        if row is not None:
+                            mega_rows[i0 + rel] = row
+                    if len(fdesc) != len(frame.docs):
+                        # Dropped entries: the ack is rebuilt positionally
+                        # from this plan (synth row or kept-desc index).
+                        mega_plans[len(acks)] = [
+                            ("s", item.synth) if item.synth is not None
+                            else ("l", i0 + item.desc_rel)
+                            for item in plan]
+                else:
+                    descs.extend(frame.docs)
+                    frame_words.append(frame.words)
+                    frame_counts.append(frame.counts)
+                    metas.append(frame.meta)
+                acks.append((frame, i0, len(descs)))
+            if not descs:
+                # Every selected entry resolved to a zero-op outcome: no
+                # tick to ride — deliver the synthesized acks now (nothing
+                # was sequenced, so there is no durability to wait on; the
+                # one state-bearing zero-op outcome journaled its own
+                # control record in decide_frame).
+                self._push_synth_acks(acks, mega_plans)
+                return True
+            if self._replay and self.megadoc is not None:
+                # Replayed lane entries are already cleaned: rebuild the
+                # combiner's mirrors + combine logs in desc order (== the
+                # order live decisions ran).
+                self.megadoc.replay_decide(descs, now)
+            if self.megadoc is not None and not self._replay:
+                self.megadoc.finish_cohort(descs)
+        if not self._replay:
             self._traced_pending = 0  # next round gets a fresh cap
-        t_scatter0 = _time.monotonic_ns()
+        with self._stage("scatter"):
+            seq_host, merge_host = self.seq_host, self.merge_host
+            desc_arr = metas[0] if len(metas) == 1 else np.concatenate(metas)
+            counts_col = desc_arr[:, 2]
+            k = _next_pow2(int(counts_col.max()))
 
-        seq_host, merge_host = self.seq_host, self.merge_host
-        desc_arr = metas[0] if len(metas) == 1 else np.concatenate(metas)
-        counts_col = desc_arr[:, 2]
-        k = _next_pow2(int(counts_col.max()))
+            # Rows + slots (the only per-doc Python work on the hot path).
+            # Storm cohorts repeat tick after tick (the same docs stream
+            # frames continuously), so the resolved arrays are cached keyed
+            # on the exact (doc, client) sequence and the sequencer's
+            # membership generation (any join/leave/restore invalidates).
+            cohort_key = (seq_host.membership_gen,
+                          tuple((d, c) for d, c, *_ in descs))
+            cached = self._cohort_cache.get(cohort_key)
+            if cached is not None:
+                seq_rows, slots, map_rows, mrows, lane_rows = cached
+            else:
+                seq_rows = np.empty(len(descs), np.int32)
+                slots = np.empty(len(descs), np.int32)
+                map_rows = np.empty(len(descs), np.int32)
+                mrows = []
+                for i, (doc, client, _cseq0, _ref, _count) in enumerate(descs):
+                    row = seq_host._row(doc)
+                    seq_rows[i] = row
+                    slots[i] = seq_host._slots[row].get(client,
+                                                        seq_host._ghost)
+                    mrow = self._storm_mrow(doc)
+                    map_rows[i] = mrow.row
+                    mrows.append(mrow)
+                # Lane sub-sequencer rows keep their cref planes pinned at 0
+                # (the doc-space refseq/MSN law lives in the mega combiner);
+                # cached alongside the cohort so the per-round forcing below
+                # is one vectorized store, not a per-desc string scan.
+                lane_rows = (self.megadoc.lane_seq_rows(descs, seq_rows)
+                             if self.megadoc is not None
+                             else np.empty(0, np.int32))
+                self._cohort_cache.put(cohort_key,
+                                       (seq_rows, slots, map_rows, mrows,
+                                        lane_rows))
 
-        # Rows + slots (the only per-doc Python work on the hot path).
-        # Storm cohorts repeat tick after tick (the same docs stream
-        # frames continuously), so the resolved arrays are cached keyed
-        # on the exact (doc, client) sequence and the sequencer's
-        # membership generation (any join/leave/restore invalidates).
-        cohort_key = (seq_host.membership_gen,
-                      tuple((d, c) for d, c, *_ in descs))
-        cached = self._cohort_cache.get(cohort_key)
-        if cached is not None:
-            seq_rows, slots, map_rows, mrows, lane_rows = cached
-        else:
-            seq_rows = np.empty(len(descs), np.int32)
-            slots = np.empty(len(descs), np.int32)
-            map_rows = np.empty(len(descs), np.int32)
-            mrows = []
-            for i, (doc, client, _cseq0, _ref, _count) in enumerate(descs):
-                row = seq_host._row(doc)
-                seq_rows[i] = row
-                slots[i] = seq_host._slots[row].get(client,
-                                                    seq_host._ghost)
-                mrow = self._storm_mrow(doc)
-                map_rows[i] = mrow.row
-                mrows.append(mrow)
-            # Lane sub-sequencer rows keep their cref planes pinned at 0
-            # (the doc-space refseq/MSN law lives in the mega combiner);
-            # cached alongside the cohort so the per-round forcing below
-            # is one vectorized store, not a per-desc string scan.
-            lane_rows = (self.megadoc.lane_seq_rows(descs, seq_rows)
-                         if self.megadoc is not None
-                         else np.empty(0, np.int32))
-            self._cohort_cache.put(cohort_key,
-                                   (seq_rows, slots, map_rows, mrows,
-                                    lane_rows))
+            b_seq = seq_host._capacity
+            b_map = merge_host._map_capacity
+            # Double-buffered staging generations: this round scatters into
+            # the IDLE generation while the one a still-in-flight tick's
+            # device transfer may alias stays untouched (pipeline_depth + 1
+            # generations rotate round-robin; the harvest-first loop above
+            # guarantees the generation coming up for reuse was harvested
+            # ≥ one round ago). The per-doc vectors re-zero (cheap memsets);
+            # the [B, K] words plane deliberately does NOT: every window the
+            # tick consumes lies inside the [0, count) prefix freshly
+            # scattered for its row this round (rows without a batch have
+            # count 0 and an empty ticket window), so stale words from the
+            # generation's previous tick are unreachable by construction and
+            # the ~MB-scale memset stays off the hot path.
+            gen = self._staging_gen(b_seq, b_map, k)
+            slot_full = gen["slot"]
+            cseq0_full = gen["cseq0"]
+            ref_full = gen["ref"]
+            seq_counts = gen["seq_counts"]
+            ts_full = gen["ts"]
+            ts_full.fill(now)
+            words_full = gen["words"]
+            map_counts = gen["map_counts"]
+            gather = gen["gather"]
+            slot_full[seq_rows] = slots
+            cseq0_full[seq_rows] = desc_arr[:, 0]
+            ref_full[seq_rows] = desc_arr[:, 1]
+            if lane_rows.size:
+                # Live metas already carry 0 here (megadoc._meta_for); the
+                # REPLAY path rebuilds metas from WAL entries, whose ref
+                # column is the doc-space ref the records need — force the
+                # device feed back to the lane contract either way.
+                ref_full[lane_rows] = 0
+            seq_counts[seq_rows] = desc_arr[:, 2]
+            map_counts[map_rows] = desc_arr[:, 2]
+            gather[map_rows] = seq_rows
+            if counts_col.min() == counts_col.max() == k:
+                # Uniform storm (the common shape): one fancy-index scatter
+                # PER FRAME, reading straight from each frame's receive
+                # buffer (a reshape view) — no np.stack copy, no per-doc
+                # Python loop between the socket and the device staging.
+                pos = 0
+                for fw, fc in zip(frame_words, frame_counts):
+                    n = len(fc)
+                    words_full[map_rows[pos:pos + n]] = fw.reshape(n, k)
+                    pos += n
+            else:
+                pos = 0
+                for fw, fc in zip(frame_words, frame_counts):
+                    off = 0
+                    for n in fc.tolist():
+                        words_full[map_rows[pos], :n] = fw[off:off + n]
+                        off += n
+                        pos += 1
 
-        b_seq = seq_host._capacity
-        b_map = merge_host._map_capacity
-        # Double-buffered staging generations: this round scatters into
-        # the IDLE generation while the one a still-in-flight tick's
-        # device transfer may alias stays untouched (pipeline_depth + 1
-        # generations rotate round-robin; the harvest-first loop above
-        # guarantees the generation coming up for reuse was harvested
-        # ≥ one round ago). The per-doc vectors re-zero (cheap memsets);
-        # the [B, K] words plane deliberately does NOT: every window the
-        # tick consumes lies inside the [0, count) prefix freshly
-        # scattered for its row this round (rows without a batch have
-        # count 0 and an empty ticket window), so stale words from the
-        # generation's previous tick are unreachable by construction and
-        # the ~MB-scale memset stays off the hot path.
-        gen = self._staging_gen(b_seq, b_map, k)
-        slot_full = gen["slot"]
-        cseq0_full = gen["cseq0"]
-        ref_full = gen["ref"]
-        seq_counts = gen["seq_counts"]
-        ts_full = gen["ts"]
-        ts_full.fill(now)
-        words_full = gen["words"]
-        map_counts = gen["map_counts"]
-        gather = gen["gather"]
-        slot_full[seq_rows] = slots
-        cseq0_full[seq_rows] = desc_arr[:, 0]
-        ref_full[seq_rows] = desc_arr[:, 1]
-        if lane_rows.size:
-            # Live metas already carry 0 here (megadoc._meta_for); the
-            # REPLAY path rebuilds metas from WAL entries, whose ref
-            # column is the doc-space ref the records need — force the
-            # device feed back to the lane contract either way.
-            ref_full[lane_rows] = 0
-        seq_counts[seq_rows] = desc_arr[:, 2]
-        map_counts[map_rows] = desc_arr[:, 2]
-        gather[map_rows] = seq_rows
-        if counts_col.min() == counts_col.max() == k:
-            # Uniform storm (the common shape): one fancy-index scatter
-            # PER FRAME, reading straight from each frame's receive
-            # buffer (a reshape view) — no np.stack copy, no per-doc
-            # Python loop between the socket and the device staging.
-            pos = 0
-            for fw, fc in zip(frame_words, frame_counts):
-                n = len(fc)
-                words_full[map_rows[pos:pos + n]] = fw.reshape(n, k)
-                pos += n
-        else:
-            pos = 0
-            for fw, fc in zip(frame_words, frame_counts):
-                off = 0
-                for n in fc.tolist():
-                    words_full[map_rows[pos], :n] = fw[off:off + n]
-                    off += n
-                    pos += 1
-
-        seq_host._host_state = None  # device state is about to move
-        t_dispatch0 = _time.monotonic_ns()
-        (seq_host._state, merge_host._xstate, n_seq, first, last,
-         msn, bad, kstats) = _storm_tick(
-            seq_host._state, merge_host._xstate,
-            jnp.asarray(slot_full), jnp.asarray(cseq0_full),
-            jnp.asarray(ref_full), jnp.asarray(ts_full),
-            jnp.asarray(seq_counts), jnp.asarray(gather),
-            jnp.asarray(words_full), jnp.asarray(map_counts))
-        # Chaos kill class "mid-tick": device state mutated, durable
-        # record NOT yet enqueued — the mutation is volatile and must be
-        # reconstructible from snapshot + WAL replay + client resend.
-        faults.crashpoint("storm.mid_tick")
-        # Pipeline: enqueue this tick's device work (and start its
-        # device→host copies), then harvest only what has ≥ depth later
-        # ticks already in flight behind it.
-        rec = dict(
-            descs=descs, frame_words=frame_words, counts=counts_col,
-            map_rows=map_rows, mrows=mrows,
-            acks=acks, now=now, submitted=int(counts_col.sum()),
-            out=(n_seq, first, last, msn, bad, kstats), start=round_start,
-            start_ns=t_scatter0, depth=self.pipeline_depth,
-            stage_ns=stage_ns, queue_depth=queue_depth,
-            mega_rows=mega_rows or None, mega_plans=mega_plans or None,
-            # Scheduler state AS OF this tick's composition (harvest may
-            # run rounds later under pipelining — the WAL header must
-            # journal the state the tick was composed against, so replay
-            # restores it at the identical point) + the per-tenant slot
-            # slices for the windowed attribution ring.
-            qos_state=(None if self.qos.is_trivial()
-                       else self.qos.export_state()),
-            qos_slices=qplan["slices"] or None)
-        for out_arr in rec["out"]:
-            copy_async = getattr(out_arr, "copy_to_host_async", None)
-            if copy_async is not None:
-                copy_async()
-        t_dispatched = _time.monotonic_ns()
-        stage_ns["scatter"] = t_dispatch0 - t_scatter0
-        stage_ns["device_dispatch"] = t_dispatched - t_dispatch0
+            seq_host._host_state = None  # device state is about to move
+        with self._stage("device_dispatch") as dispatch:
+            (seq_host._state, merge_host._xstate, n_seq, first, last,
+             msn, bad, kstats) = _storm_tick(
+                seq_host._state, merge_host._xstate,
+                jnp.asarray(slot_full), jnp.asarray(cseq0_full),
+                jnp.asarray(ref_full), jnp.asarray(ts_full),
+                jnp.asarray(seq_counts), jnp.asarray(gather),
+                jnp.asarray(words_full), jnp.asarray(map_counts))
+            # Chaos kill class "mid-tick": device state mutated, durable
+            # record NOT yet enqueued — the mutation is volatile and must be
+            # reconstructible from snapshot + WAL replay + client resend.
+            faults.crashpoint("storm.mid_tick")
+            # Pipeline: enqueue this tick's device work (and start its
+            # device→host copies), then harvest only what has ≥ depth later
+            # ticks already in flight behind it.
+            rec = dict(
+                descs=descs, frame_words=frame_words, counts=counts_col,
+                map_rows=map_rows, mrows=mrows,
+                acks=acks, now=now, submitted=int(counts_col.sum()),
+                out=(n_seq, first, last, msn, bad, kstats),
+                depth=self.pipeline_depth, queue_depth=queue_depth,
+                mega_rows=mega_rows or None, mega_plans=mega_plans or None,
+                # Scheduler state AS OF this tick's composition (harvest may
+                # run rounds later under pipelining — the WAL header must
+                # journal the state the tick was composed against, so replay
+                # restores it at the identical point) + the per-tenant slot
+                # slices for the windowed attribution ring.
+                qos_state=(None if self.qos.is_trivial()
+                           else self.qos.export_state()),
+                qos_slices=qplan["slices"] or None)
+            for out_arr in rec["out"]:
+                copy_async = getattr(out_arr, "copy_to_host_async", None)
+                if copy_async is not None:
+                    copy_async()
         if not self._replay:
             for frame, _i0, _i1 in acks:
                 if frame.trace is not None:
-                    self.tracer.mark(frame.trace, "dispatch", t_dispatched)
+                    self.tracer.mark(frame.trace, "dispatch", dispatch.t1)
         self._inflight.append(rec)
         if self._group_wal is not None and not self._replay:
             # Chaos kill class "mid-overlap dispatch": this tick's device
@@ -1605,237 +1643,226 @@ class StormController:
             self._harvest_one(self._inflight.pop(0))
 
     def _harvest_one(self, rec: dict) -> None:
-        import time as _time
-
-        t_read0 = _time.monotonic_ns()
-        n_seq, first, last, msn, bad, kstats = (np.asarray(a)
-                                                for a in rec["out"])
-        # Device-side kernel counters (the i32[3] stats plane riding this
-        # readback): sequenced / dup-dropped / sentinel docs, device-true.
-        kstats = kstats.tolist()
-        t_readback = _time.monotonic_ns()
-        if self._group_wal is not None and not self._replay:
-            # Chaos kill class "readback-before-fsync": this tick's
-            # results are read back but its durable record has not yet
-            # reached the writer thread — the whole tick is volatile and
-            # must be reconstructible from snapshot + WAL replay +
-            # client resend (nothing of it was ever acked).
-            faults.crashpoint("storm.readback_pre_wal")
-        stage_ns = rec.get("stage_ns", {})
-        stage_ns["readback"] = t_readback - t_read0
-        map_rows = rec["map_rows"]
-        # ONE batched gather+pack builds the tick's per-doc ack matrix
-        # (n_seq, first, last, msn) — the columnar twin of
-        # pack_map_words; the WAL-header lists and every frame's ack are
-        # derived from it (columnar → Python exactly once; int() per
-        # device element inside the doc loop would dominate the harvest).
-        ack_rows = np.stack(
-            (n_seq[map_rows], first[map_rows], last[map_rows],
-             msn[map_rows]), axis=1).astype(np.int32, copy=False)
-        ns_l = ack_rows[:, 0].tolist()
-        fs_l = ack_rows[:, 1].tolist()
-        ls_l = ack_rows[:, 2].tolist()
-        m_l = ack_rows[:, 3].tolist()
-        bad_rows = bad[map_rows]
-        any_bad = bool(bad_rows.any())
-        bad_l = bad_rows.tolist()
-        if not self._replay:
-            for frame, _i0, _i1 in rec["acks"]:
-                if frame.trace is not None:
-                    self.tracer.mark(frame.trace, "sequenced", t_readback)
-        fanout = self.service.fanout
-        viewers = getattr(self.service, "viewers", None)
-        if viewers is not None and (self._replay
-                                    or not viewers.active_rooms):
-            viewers = None
-        # Desc indices whose docs have viewer rooms — collected inside
-        # the one existing per-desc loop (no second O(descs) pass).
-        # Rooms key by the PARENT doc for mega-lane descs (viewer frames
-        # must keep flowing while a doc is promoted), publishing the
-        # combiner's DOC-space quad instead of the lane-space device row.
-        viewer_idx: list[int] = []
-        viewer_rooms: dict[int, str] = {}
-        megadoc = self.megadoc
-        mega_rows_all = rec.get("mega_rows") or {}
-        now = rec["now"]
-        mrows = rec["mrows"]
-        # scriptorium tick record: ONE blob per tick — a json header of
-        # every document's columnar record followed by the raw words.
-        # RAM keeps only a compact (first_seq, last_seq, tick) triplet
-        # per (doc, tick), so the serving host's memory stays bounded by
-        # the tick RATE it retains, not the op volume (with a spill dir
-        # the blob rides the disk oplog — the Mongo-storage analog).
         tick_id = self._tick_counter
-        self._tick_counter += 1
-        # The WAL words region is the frames' receive-buffer views,
-        # appended as-is; per-doc byte offsets are one cumsum, not a loop.
-        counts_col = rec["counts"]
-        word_parts: list = rec["frame_words"]
-        total_seq = int(sum(ns_l))
-        w_offs = np.zeros(len(counts_col), np.int64)
-        w_offs[1:] = np.cumsum(counts_col[:-1].astype(np.int64) * 4)
-        offsets = w_offs.tolist()
-        header_docs = []
-        replaying = self._replay
-        doc_tick_counts = self.doc_tick_counts
-        pubs: list = [] if fanout is not None and not replaying else None
-        for i, (doc, client, cseq0, ref, count) in enumerate(rec["descs"]):
-            ns, fs, ls, m = ns_l[i], fs_l[i], ls_l[i], m_l[i]
-            mrow = mrows[i]
-            if ls > mrow.last_seq:
-                mrow.last_seq = ls
-            header_docs.append([doc, client, cseq0, ref, count,
-                                ns, fs, ls, m, offsets[i]])
-            if not replaying:
-                if ns > 0:
-                    dt = self._doc_ticks.setdefault(doc, [])
-                    dt.append((fs, ls, tick_id))
-                    retention = self.doc_index_retention_ticks
-                    if retention is not None and dt[0][2] < (
-                            tick_id - retention):
-                        # Opt-in index retention (see __init__): drop
-                        # entries below the horizon; ticks are appended
-                        # in order, so the trim is a prefix cut.
-                        horizon = tick_id - retention
-                        keep = 0
-                        while keep < len(dt) and dt[keep][2] < horizon:
-                            keep += 1
-                        del dt[:keep]
-                # Telemetry for the quarantine blast-radius invariant:
-                # batch peers of a quarantined doc lose zero ticks.
-                doc_tick_counts[doc] = doc_tick_counts.get(doc, 0) + 1
-                if any_bad and bad_l[i] and doc not in self.quarantined:
-                    self._quarantine_doc(doc, "sentinel", tick_id)
-                # broadcaster: compact tick frame into the pub/sub hop.
-                if pubs is not None:
-                    pubs.append((doc, b"\x00storm%d:%d:%d" % (fs, ls, m)))
-                if viewers is not None and ns > 0:
-                    room_doc = doc
-                    if megadoc is not None:
-                        parent = megadoc.parent_of(doc)
-                        if parent is not None:
-                            room_doc = parent
-                    if viewers.has_viewers(room_doc):
-                        viewer_idx.append(i)
-                        viewer_rooms[i] = room_doc
-        t_assembled = _time.monotonic_ns()
-        stage_ns["ack_pack"] = t_assembled - t_readback
-        if pubs:
-            # O(batch) broadcast: the whole tick's room publishes go down
-            # in ONE native call (fanout_publish_batch) — never one
-            # Python write per subscriber connection.
-            batch_pub = getattr(fanout, "publish_batch", None)
-            if batch_pub is not None:
-                batch_pub(pubs)
-            else:  # duck-typed fanout without the batch surface
-                for room, body in pubs:
-                    fanout.publish(room, body)
-        # Viewer plane: docs with viewer rooms get this tick's broadcast
-        # frame (sequenced window + raw words) serialized ONCE per doc
-        # and fanned out in one batched publish — encodes-per-tick ==
-        # hot docs with viewers, independent of viewer count (the
-        # serialize-once invariant BENCH_r13 pins). Words resolve
-        # straight from each frame's receive-buffer view (the same
-        # positional layout the WAL appends); only frames CONTAINING a
-        # viewer doc pay an offsets walk — a 10k-doc tick with one
-        # viewer room touches one frame, not every desc.
-        if viewer_idx:
-            import bisect
-            frame_words = rec["frame_words"]
-            items = []
-            for f_idx, (_frame, i0, i1) in enumerate(rec["acks"]):
-                lo = bisect.bisect_left(viewer_idx, i0)
-                hi = bisect.bisect_left(viewer_idx, i1)
-                if lo == hi:
-                    continue  # no viewer docs in this frame
-                fcounts = counts_col[i0:i1].tolist()
-                target = viewer_idx[lo]
-                off = 0
-                for local, count in enumerate(fcounts):
-                    gi = i0 + local
-                    if gi == target:
-                        words = frame_words[f_idx][off:off + count]
-                        # Lane descs broadcast the combiner's doc-space
-                        # quad (the same rewrite the client ack gets);
-                        # viewers of a promoted doc see continuous doc
-                        # seq windows, never lane-space ones.
-                        quad = mega_rows_all.get(gi) or (
-                            ns_l[gi], fs_l[gi], ls_l[gi], m_l[gi])
-                        items.append((viewer_rooms[gi], quad[0],
-                                      quad[1], quad[2], quad[3],
-                                      count, words.tobytes()))
-                        lo += 1
-                        if lo == hi:
-                            break
-                        target = viewer_idx[lo]
-                    off += count
-            viewers.publish_ticks(items)
-        t_fanout = _time.monotonic_ns()
-        stage_ns["fanout_publish"] = t_fanout - t_assembled
-        import json as _json
-        import struct as _struct
+        with self._stage("readback", tick_id) as readback:
+            n_seq, first, last, msn, bad, kstats = (np.asarray(a)
+                                                    for a in rec["out"])
+            # Device-side kernel counters (the i32[3] stats plane riding this
+            # readback): sequenced / dup-dropped / sentinel docs, device-true.
+            kstats = kstats.tolist()
+        with self._stage("ack_pack", tick_id):
+            if self._group_wal is not None and not self._replay:
+                # Chaos kill class "readback-before-fsync": this tick's
+                # results are read back but its durable record has not yet
+                # reached the writer thread — the whole tick is volatile and
+                # must be reconstructible from snapshot + WAL replay +
+                # client resend (nothing of it was ever acked).
+                faults.crashpoint("storm.readback_pre_wal")
+            map_rows = rec["map_rows"]
+            # ONE batched gather+pack builds the tick's per-doc ack matrix
+            # (n_seq, first, last, msn) — the columnar twin of
+            # pack_map_words; the WAL-header lists and every frame's ack are
+            # derived from it (columnar → Python exactly once; int() per
+            # device element inside the doc loop would dominate the harvest).
+            ack_rows = np.stack(
+                (n_seq[map_rows], first[map_rows], last[map_rows],
+                 msn[map_rows]), axis=1).astype(np.int32, copy=False)
+            ns_l = ack_rows[:, 0].tolist()
+            fs_l = ack_rows[:, 1].tolist()
+            ls_l = ack_rows[:, 2].tolist()
+            m_l = ack_rows[:, 3].tolist()
+            bad_rows = bad[map_rows]
+            any_bad = bool(bad_rows.any())
+            bad_l = bad_rows.tolist()
+            if not self._replay:
+                for frame, _i0, _i1 in rec["acks"]:
+                    if frame.trace is not None:
+                        self.tracer.mark(frame.trace, "sequenced",
+                                         readback.t1)
+            fanout = self.service.fanout
+            viewers = getattr(self.service, "viewers", None)
+            if viewers is not None and (self._replay
+                                        or not viewers.active_rooms):
+                viewers = None
+            # Desc indices whose docs have viewer rooms — collected inside
+            # the one existing per-desc loop (no second O(descs) pass).
+            # Rooms key by the PARENT doc for mega-lane descs (viewer frames
+            # must keep flowing while a doc is promoted), publishing the
+            # combiner's DOC-space quad instead of the lane-space device row.
+            viewer_idx: list[int] = []
+            viewer_rooms: dict[int, str] = {}
+            megadoc = self.megadoc
+            mega_rows_all = rec.get("mega_rows") or {}
+            now = rec["now"]
+            mrows = rec["mrows"]
+            # scriptorium tick record: ONE blob per tick — a json header of
+            # every document's columnar record followed by the raw words.
+            # RAM keeps only a compact (first_seq, last_seq, tick) triplet
+            # per (doc, tick), so the serving host's memory stays bounded by
+            # the tick RATE it retains, not the op volume (with a spill dir
+            # the blob rides the disk oplog — the Mongo-storage analog).
+            self._tick_counter += 1
+            # The WAL words region is the frames' receive-buffer views,
+            # appended as-is; per-doc byte offsets are one cumsum, not a loop.
+            counts_col = rec["counts"]
+            word_parts: list = rec["frame_words"]
+            total_seq = int(sum(ns_l))
+            w_offs = np.zeros(len(counts_col), np.int64)
+            w_offs[1:] = np.cumsum(counts_col[:-1].astype(np.int64) * 4)
+            offsets = w_offs.tolist()
+            header_docs = []
+            replaying = self._replay
+            doc_tick_counts = self.doc_tick_counts
+            pubs: list = [] if fanout is not None and not replaying else None
+            for i, (doc, client, cseq0, ref, count) in enumerate(rec["descs"]):
+                ns, fs, ls, m = ns_l[i], fs_l[i], ls_l[i], m_l[i]
+                mrow = mrows[i]
+                if ls > mrow.last_seq:
+                    mrow.last_seq = ls
+                header_docs.append([doc, client, cseq0, ref, count,
+                                    ns, fs, ls, m, offsets[i]])
+                if not replaying:
+                    if ns > 0:
+                        dt = self._doc_ticks.setdefault(doc, [])
+                        dt.append((fs, ls, tick_id))
+                        retention = self.doc_index_retention_ticks
+                        if retention is not None and dt[0][2] < (
+                                tick_id - retention):
+                            # Opt-in index retention (see __init__): drop
+                            # entries below the horizon; ticks are appended
+                            # in order, so the trim is a prefix cut.
+                            horizon = tick_id - retention
+                            keep = 0
+                            while keep < len(dt) and dt[keep][2] < horizon:
+                                keep += 1
+                            del dt[:keep]
+                    # Telemetry for the quarantine blast-radius invariant:
+                    # batch peers of a quarantined doc lose zero ticks.
+                    doc_tick_counts[doc] = doc_tick_counts.get(doc, 0) + 1
+                    if any_bad and bad_l[i] and doc not in self.quarantined:
+                        self._quarantine_doc(doc, "sentinel", tick_id)
+                    # broadcaster: compact tick frame into the pub/sub hop.
+                    if pubs is not None:
+                        pubs.append((doc, b"\x00storm%d:%d:%d" % (fs, ls, m)))
+                    if viewers is not None and ns > 0:
+                        room_doc = doc
+                        if megadoc is not None:
+                            parent = megadoc.parent_of(doc)
+                            if parent is not None:
+                                room_doc = parent
+                        if viewers.has_viewers(room_doc):
+                            viewer_idx.append(i)
+                            viewer_rooms[i] = room_doc
+        with self._stage("fanout_publish", tick_id):
+            if pubs:
+                # O(batch) broadcast: the whole tick's room publishes go down
+                # in ONE native call (fanout_publish_batch) — never one
+                # Python write per subscriber connection.
+                batch_pub = getattr(fanout, "publish_batch", None)
+                if batch_pub is not None:
+                    batch_pub(pubs)
+                else:  # duck-typed fanout without the batch surface
+                    for room, body in pubs:
+                        fanout.publish(room, body)
+            # Viewer plane: docs with viewer rooms get this tick's broadcast
+            # frame (sequenced window + raw words) serialized ONCE per doc
+            # and fanned out in one batched publish — encodes-per-tick ==
+            # hot docs with viewers, independent of viewer count (the
+            # serialize-once invariant BENCH_r13 pins). Words resolve
+            # straight from each frame's receive-buffer view (the same
+            # positional layout the WAL appends); only frames CONTAINING a
+            # viewer doc pay an offsets walk — a 10k-doc tick with one
+            # viewer room touches one frame, not every desc.
+            if viewer_idx:
+                import bisect
+                frame_words = rec["frame_words"]
+                items = []
+                for f_idx, (_frame, i0, i1) in enumerate(rec["acks"]):
+                    lo = bisect.bisect_left(viewer_idx, i0)
+                    hi = bisect.bisect_left(viewer_idx, i1)
+                    if lo == hi:
+                        continue  # no viewer docs in this frame
+                    fcounts = counts_col[i0:i1].tolist()
+                    target = viewer_idx[lo]
+                    off = 0
+                    for local, count in enumerate(fcounts):
+                        gi = i0 + local
+                        if gi == target:
+                            words = frame_words[f_idx][off:off + count]
+                            # Lane descs broadcast the combiner's doc-space
+                            # quad (the same rewrite the client ack gets);
+                            # viewers of a promoted doc see continuous doc
+                            # seq windows, never lane-space ones.
+                            quad = mega_rows_all.get(gi) or (
+                                ns_l[gi], fs_l[gi], ls_l[gi], m_l[gi])
+                            items.append((viewer_rooms[gi], quad[0],
+                                          quad[1], quad[2], quad[3],
+                                          count, words.tobytes()))
+                            lo += 1
+                            if lo == hi:
+                                break
+                            target = viewer_idx[lo]
+                        off += count
+                viewers.publish_ticks(items)
+        with self._stage("wal_append", tick_id):
+            import json as _json
+            import struct as _struct
 
-        hdr: dict = {"v": STORM_WAL_VERSION, "ts": now,
-                     "docs": header_docs}
-        if rec.get("qos_state") is not None:
-            # Multi-tenant scheduler state as of this tick's composition
-            # (single-tenant headers stay byte-compatible with every
-            # pre-QoS reader/golden — the field simply never appears).
-            # Replay imports it tick by tick, so a recovered host's
-            # deficits equal the crashed host's at the durable frontier.
-            hdr["qos"] = rec["qos_state"]
-        header = _json.dumps(hdr, separators=(",", ":")).encode()
-        prefix = _struct.pack("<I", len(header)) + header
-        if self._replay:
-            pass  # the blob IS the replay source; never re-persist it
-        elif self._group_wal is not None:
-            # Async group commit: the hot path pays ONE queue put; the
-            # join + CRC + append + fsync all run on the writer thread.
-            # The tick's acks are withheld until the durability watermark
-            # passes it (_drain_durable_acks) — the sync per-tick blob
-            # write this replaces was the round-5 regression suspect.
-            idx = self._group_wal.append([prefix, *word_parts])
-            assert idx == tick_id, (idx, tick_id)
-            if self.pipeline_depth == 0 and not self._replay:
-                # Serial fallback: the durability barrier is tick time
-                # ON this thread — nothing overlaps it — so it is
-                # measured directly as the commit-wait stage and the
-                # tick's wall-clock slice covers it (no amend-at-drain;
-                # the ledger must never report phantom overlap for a
-                # genuinely sequential tick).
-                from .durable_store import WalDegradedError
-                t_sync0 = _time.monotonic_ns()
-                try:
-                    self._group_wal.sync()
-                except WalDegradedError:
-                    # Breaker open: acks stay withheld (not durable);
-                    # _admit is already shedding new writes.
-                    self.merge_host.metrics.counter(
-                        "storm.degraded_flushes").inc()
-                stage_ns["wal_commit_wait"] = (_time.monotonic_ns()
-                                               - t_sync0)
-        elif self._blob_log is not None:
-            blob_bytes = prefix + b"".join(
-                bytes(memoryview(p)) for p in word_parts)
-            idx = self._blob_log.append(blob_bytes)
-            assert idx == tick_id, (idx, tick_id)
-            if self.durability == "sync":
-                t_sync0 = _time.monotonic_ns()
-                self._blob_log.sync()
-                stage_ns["wal_commit_wait"] = (_time.monotonic_ns()
-                                               - t_sync0)
-        else:
-            self._tick_blobs[tick_id] = prefix + b"".join(
-                bytes(memoryview(p)) for p in word_parts)
-        t_wal = _time.monotonic_ns()
-        stage_ns["wal_append"] = (t_wal - t_fanout
-                                  - stage_ns.get("wal_commit_wait", 0))
+            hdr: dict = {"v": STORM_WAL_VERSION, "ts": now,
+                         "docs": header_docs}
+            if rec.get("qos_state") is not None:
+                # Multi-tenant scheduler state as of this tick's composition
+                # (single-tenant headers stay byte-compatible with every
+                # pre-QoS reader/golden — the field simply never appears).
+                # Replay imports it tick by tick, so a recovered host's
+                # deficits equal the crashed host's at the durable frontier.
+                hdr["qos"] = rec["qos_state"]
+            header = _json.dumps(hdr, separators=(",", ":")).encode()
+            prefix = _struct.pack("<I", len(header)) + header
+            if self._replay:
+                pass  # the blob IS the replay source; never re-persist it
+            elif self._group_wal is not None:
+                # Async group commit: the hot path pays ONE queue put; the
+                # join + CRC + append + fsync all run on the writer thread.
+                # The tick's acks are withheld until the durability watermark
+                # passes it (_drain_durable_acks) — the sync per-tick blob
+                # write this replaces was the round-5 regression suspect.
+                idx = self._group_wal.append([prefix, *word_parts])
+                assert idx == tick_id, (idx, tick_id)
+                if self.pipeline_depth == 0:
+                    # Serial fallback: the durability barrier is tick time
+                    # ON this thread — nothing overlaps it — so it is
+                    # timed directly as the commit-wait stage and the
+                    # tick's wall-clock slice covers it (no amend-at-drain;
+                    # the ledger must never report phantom overlap for a
+                    # genuinely sequential tick).
+                    from .durable_store import WalDegradedError
+                    with self._stage("wal_commit_wait", tick_id):
+                        try:
+                            self._group_wal.sync()
+                        except WalDegradedError:
+                            # Breaker open: acks stay withheld (not
+                            # durable); _admit already sheds new writes.
+                            self.merge_host.metrics.counter(
+                                "storm.degraded_flushes").inc()
+            elif self._blob_log is not None:
+                blob_bytes = prefix + b"".join(
+                    bytes(memoryview(p)) for p in word_parts)
+                idx = self._blob_log.append(blob_bytes)
+                assert idx == tick_id, (idx, tick_id)
+                if self.durability == "sync":
+                    with self._stage("wal_commit_wait", tick_id):
+                        self._blob_log.sync()
+            else:
+                self._tick_blobs[tick_id] = prefix + b"".join(
+                    bytes(memoryview(p)) for p in word_parts)
         # Stats BEFORE acks: once an ack leaves the process, this host's
         # bookkeeping must already reflect the tick (clients/tests react
         # to acks immediately).
         self.stats["ticks"] += 1
         self.stats["sequenced_ops"] += total_seq
         self.stats["nacked_or_ignored_ops"] += rec["submitted"] - total_seq
+        self._sync_wal_stats()
         # Storm ops are serving-path device ops: count them in the merge
         # host's routing stats so scalar_fraction spans BOTH ingest paths.
         self.merge_host.stats["device_ops"] += total_seq
@@ -1863,11 +1890,6 @@ class StormController:
                 seq_by_t[frame.tenant] = seq_by_t.get(frame.tenant, 0) \
                     + int(sum(ns_l[i0:i1]))
             self.qos.note_tick(tick_id, rec["qos_slices"], seq_by_t)
-        done = _time.perf_counter()
-        self.tick_seconds.append(done - rec["start"])
-        if self._last_harvest is not None:
-            self.harvest_intervals.append(done - self._last_harvest)
-        self._last_harvest = done
         # Mega combiner egress: lane descs' device rows carry LANE-space
         # seqs (what the WAL header above recorded — reads translate);
         # the CLIENT sees doc-space quads, pre-decided by the combiner.
@@ -1892,53 +1914,46 @@ class StormController:
         # without ever building per-doc dicts. Frames the mega transform
         # shrank rebuild their rows positionally from the plan
         # (synthesized zero-op quads interleaved with harvested rows).
-        from ..protocol.codec import StormAck
-        t_ack0 = _time.monotonic_ns()
-        mega_plans = rec.get("mega_plans") or {}
-        acks = []
-        for ack_i, (frame, i0, i1) in enumerate(rec["acks"]):
-            if frame.push is None:
-                continue
-            plan = mega_plans.get(ack_i)
-            if plan is None:
-                payload = StormAck(frame.rid, ack_rows[i0:i1])
-            else:
-                rows = np.empty((len(plan), 4), np.int32)
-                for j, (kind, v) in enumerate(plan):
-                    rows[j] = v if kind == "s" else ack_rows[v]
-                payload = StormAck(frame.rid, rows)
-            if any_bad and bad_rows[i0:i1].any():
-                # The tick's sequencing is durable and correct (the
-                # ticket is exact; the poison is in the served planes) —
-                # the ack stands, but the client learns its doc is
-                # frozen: further submits nack until readmission.
-                payload["quarantined"] = [
-                    rec["descs"][i][0] for i in range(i0, i1) if bad_l[i]]
-                payload["retry_after_s"] = self.busy_retry_s
-            acks.append((frame, payload))
-        t_harvest_done = _time.monotonic_ns()
-        stage_ns["ack_pack"] += t_harvest_done - t_ack0
+        with self._stage("ack_pack", tick_id) as packed:
+            from ..protocol.codec import StormAck
+            mega_plans = rec.get("mega_plans") or {}
+            acks = []
+            for ack_i, (frame, i0, i1) in enumerate(rec["acks"]):
+                if frame.push is None:
+                    continue
+                plan = mega_plans.get(ack_i)
+                if plan is None:
+                    payload = StormAck(frame.rid, ack_rows[i0:i1])
+                else:
+                    rows = np.empty((len(plan), 4), np.int32)
+                    for j, (kind, v) in enumerate(plan):
+                        rows[j] = v if kind == "s" else ack_rows[v]
+                    payload = StormAck(frame.rid, rows)
+                if any_bad and bad_rows[i0:i1].any():
+                    # The tick's sequencing is durable and correct (the
+                    # ticket is exact; the poison is in the served planes) —
+                    # the ack stands, but the client learns its doc is
+                    # frozen: further submits nack until readmission.
+                    payload["quarantined"] = [
+                        rec["descs"][i][0] for i in range(i0, i1) if bad_l[i]]
+                    payload["retry_after_s"] = self.busy_retry_s
+                acks.append((frame, payload))
         # Commit the tick's ledger record (fixed shape; replay ticks are
         # reconstruction, not serving — they don't pollute attribution).
         # Group-mode commit-wait is unknown until the fsync watermark
         # passes the tick; the drain backfills it on the record object.
         led = None
         if not self._replay:
-            # The tick's exclusive wall-clock slice: harvest-to-harvest
-            # cadence at steady state, its own stage span after an idle
-            # gap (min of the two — an idle wait is not tick time).
-            # Under pipelining the per-stage splits legitimately sum
-            # PAST this wall slice; attribution() reports the difference
-            # as overlap_ms instead of double-counting it.
-            start_ns = rec.get("start_ns", t_harvest_done)
-            wall_ns = t_harvest_done - start_ns
-            if self._last_harvest_done_ns is not None:
-                wall_ns = min(wall_ns,
-                              t_harvest_done - self._last_harvest_done_ns)
-            self._last_harvest_done_ns = t_harvest_done
+            # The tick's wall-clock slice is the harvest-to-harvest
+            # cadence: every serving-thread stage timed since the
+            # previous harvest lands in this record, so wall minus their
+            # sum is what no stage covered. The commit-wait amended in
+            # later ran on the writer thread, under the next tick's
+            # stages; attribution() reports it as overlap_ms instead of
+            # double-counting it.
             led = self.ledger.record(tick_id, rec.get("queue_depth", 0),
                                      len(rec["descs"]), rec["submitted"],
-                                     stage_ns, wall_ns=max(0, wall_ns),
+                                     wall_ns=None,
                                      depth=rec.get("depth",
                                                    self.pipeline_depth))
         if self._group_wal is not None and not self._replay:
@@ -1947,22 +1962,23 @@ class StormController:
             # above the watermark after a reconnect). The serial fallback
             # already measured its inline barrier as wal_commit_wait, so
             # its record must NOT be amended at drain (led=None there).
-            self._unacked.append((tick_id, acks, t_harvest_done,
+            self._unacked.append((tick_id, acks, packed.t1,
                                   led if self.pipeline_depth > 0
                                   else None))
             self._drain_durable_acks()
-        else:
+        elif acks:
             dw = self.durable_watermark
-            t_ack_tx = _time.monotonic_ns()
-            for frame, payload in acks:
-                faults.crashpoint("storm.pre_ack")
-                payload["dw"] = dw
-                if frame.trace is not None:
-                    self._stamp_trace_ack(frame, payload)
-                if frame.t0:
-                    self.qos.observe_ack(frame.tenant,
-                                         (t_ack_tx - frame.t0) / 1e9)
-                frame.push(payload)
+            with self._stage("ack_send", tick_id):
+                t_ack_tx = time.monotonic_ns()
+                for frame, payload in acks:
+                    faults.crashpoint("storm.pre_ack")
+                    payload["dw"] = dw
+                    if frame.trace is not None:
+                        self._stamp_trace_ack(frame, payload)
+                    if frame.t0:
+                        self.qos.observe_ack(frame.tenant,
+                                             (t_ack_tx - frame.t0) / 1e9)
+                    frame.push(payload)
 
     # -- snapshot / recovery ---------------------------------------------------
     #
@@ -2308,8 +2324,8 @@ class StormController:
             # Refund the shed frame's staged ledger ns and trace slot:
             # a tick that never served it must not inherit its
             # attribution, and its sampling-cap slot frees for peers.
-            self._staged_ns["ingress_decode"] -= frame.staged_ns[0]
-            self._staged_ns["admission"] -= frame.staged_ns[1]
+            for stage in frame.staged:
+                self.ledger.refund(stage)
             if frame.trace is not None:
                 self._traced_pending = max(0, self._traced_pending - 1)
             self._shed(frame.push, {"rid": frame.rid},

@@ -25,7 +25,7 @@ import sys
 import time
 
 from ..protocol.codec import decode_body, encode_frame
-from ..utils.metrics import STORM_STAGES
+from ..utils.metrics import STORM_STAGES, WAIT_STAGES
 
 
 def scrape(host: str, port: int, timeout: float = 10.0) -> dict:
@@ -84,11 +84,12 @@ def render_stage_bar(metrics: dict, width: int = 52,
                      prev: dict | None = None) -> str:
     """The live stage-attribution view: one proportional bar over the
     stage shares (windowed vs ``prev`` when given) plus a per-stage
-    p50/p99 table (ms, cumulative histograms)."""
+    p50/p99 table (ms, cumulative histograms). A stage in which the
+    serving thread waits for work (``frame_wait``) is labelled a wait."""
     shares = stage_shares(metrics, prev)
     if not shares:
         return "stage ledger: (no storm ticks yet)"
-    glyphs = "#=+*o.:%~-"
+    glyphs = "#=+*o.:%~-@&"
     bar = ""
     legend = []
     for i, stage in enumerate(STORM_STAGES):
@@ -98,7 +99,8 @@ def render_stage_bar(metrics: dict, width: int = 52,
         bar += g * cells
         p50 = metrics.get(f"storm.stage.{stage}.p50", 0.0) * 1e3
         p99 = metrics.get(f"storm.stage.{stage}.p99", 0.0) * 1e3
-        legend.append(f"  {g} {stage:<16} {100 * share:5.1f}%"
+        label = f"{stage} (wait)" if stage in WAIT_STAGES else stage
+        legend.append(f"  {g} {label:<17} {100 * share:5.1f}%"
                       f"  p50 {p50:8.3f}ms  p99 {p99:8.3f}ms")
     lines = [f"stage ledger  [{bar:<{width}}]"]
     lines.extend(legend)
@@ -111,29 +113,37 @@ def render_pipeline(metrics: dict, prev: dict | None = None) -> str:
     window, and the overlap share — how much concurrent stage time
     (tick N's WAL commit-wait under tick N+1's dispatch) the pipeline
     bought per unit of wall clock. Empty before any tick records a wall
-    split (pre-r14 service, or no storm ticks yet)."""
+    split (pre-r14 service, or no storm ticks yet). The attributed time
+    names the part the serving thread spent waiting for frames; the
+    ledger's garbage-collection field overlaps the stages and is not
+    in the sum."""
+    def stage_s(snap, stage):
+        return snap.get(f"storm.stage.{stage}.mean", 0.0) \
+            * snap.get(f"storm.stage.{stage}.count", 0.0)
+
     def totals(snap):
         wall = snap.get("storm.stage.wall.mean", 0.0) \
             * snap.get("storm.stage.wall.count", 0.0)
-        att = sum(snap.get(f"storm.stage.{s}.mean", 0.0)
-                  * snap.get(f"storm.stage.{s}.count", 0.0)
-                  for s in STORM_STAGES)
-        return wall, att, snap.get("storm.stage.wall.count", 0.0)
+        att = sum(stage_s(snap, s) for s in STORM_STAGES)
+        wait = sum(stage_s(snap, s) for s in WAIT_STAGES)
+        return wall, att, wait, snap.get("storm.stage.wall.count", 0.0)
 
-    wall, att, ticks = totals(metrics)
+    wall, att, wait, ticks = totals(metrics)
     if wall <= 0:
         return ""
     if prev is not None:
-        p_wall, p_att, p_ticks = totals(prev)
+        p_wall, p_att, p_wait, p_ticks = totals(prev)
         w_wall, w_att = wall - p_wall, att - p_att
         # Negative windows mean the service restarted (registry reset);
         # fall back to cumulative totals like the stage bar does.
         if w_wall > 0 and w_att >= 0:
-            wall, att, ticks = w_wall, w_att, ticks - p_ticks
+            wall, att, wait = w_wall, w_att, wait - p_wait
+            ticks -= p_ticks
     overlap = max(0.0, att - wall)
     depth = metrics.get("storm.pipeline.depth", 0)
     return (f"pipeline: depth {depth:g}  wall {wall * 1e3:,.0f}ms  "
-            f"attributed {att * 1e3:,.0f}ms  "
+            f"attributed {att * 1e3:,.0f}ms "
+            f"(waiting for frames {wait * 1e3:,.0f}ms)  "
             f"overlap {overlap * 1e3:,.0f}ms "
             f"({100.0 * overlap / wall:.0f}% of wall)  ticks {ticks:g}")
 

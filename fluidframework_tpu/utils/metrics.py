@@ -16,8 +16,10 @@ lock a hot histogram would serialize the whole assembly on.
 
 from __future__ import annotations
 
+import gc
 import math
 import threading
+import time
 from collections import deque
 from typing import Any, Iterable
 
@@ -135,20 +137,202 @@ def percentile(sorted_values, q: float):
 
 
 #: Stage order of one storm serving tick — the fixed shape of every
-#: :class:`StageLedger` record (server/storm.py fills these; the sum is
-#: the attributable slice of the tick's wall clock).
+#: :class:`StageLedger` record (server/storm.py and the bridge pump fill
+#: these). Every stage but those of ``OFF_THREAD_STAGES`` is serving-thread
+#: time, so their sum is the attributed slice of the tick's wall clock.
 STORM_STAGES = ("ingress_decode", "admission", "scatter", "device_dispatch",
                 "readback", "wal_append", "wal_commit_wait", "ack_pack",
-                "fanout_publish")
+                "fanout_publish", "frame_wait", "ack_send", "idle_service",
+                "service_pump", "cohort")
+
+#: Stages that are the serving thread waiting for work, not doing it.
+WAIT_STAGES = ("frame_wait",)
+
+#: Storm stages timed off the serving thread: the pipelined commit-wait
+#: (the WAL writer's fsync, amended into its record when the ack drains).
+OFF_THREAD_STAGES = ("wal_commit_wait",)
+
+#: Fields of every :class:`StageLedger` record that are not stage times.
+RECORD_FIELDS = ("tick", "queue_depth", "batch_docs", "batch_ops", "wall",
+                 "depth", "gc")
+
+#: Prefix of every program span on the profiler's clock; the name after
+#: it is the registry name of the same measurement
+#: (``ff.storm.stage.scatter`` is the histogram ``storm.stage.scatter``).
+SPAN_PREFIX = "ff."
+
+_trace_cls: Any = None
+
+
+def _trace_begin(name: str, args: dict):
+    """A started ``jax.profiler.TraceAnnotation`` while a profile is being
+    collected, else None: with the profiler off a span costs one
+    ``is_enabled`` check, and JAX-free processes never trace."""
+    global _trace_cls
+    if _trace_cls is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _trace_cls = TraceAnnotation
+    if not _trace_cls or not _trace_cls.is_enabled():
+        return None
+    return _trace_cls(name, **args)
+
+
+def _trace_end(tm) -> None:
+    if tm is not None:
+        tm.__exit__(None, None, None)
+
+
+class ProgramSpan:
+    """``with ProgramSpan("wal.fsync"):`` puts an ``ff.wal.fsync`` span on
+    the profiler's timeline (nothing when no profile is collected). For
+    work outside any :class:`StageLedger`, such as the WAL writer
+    thread's batches."""
+
+    __slots__ = ("name", "args", "_tm")
+
+    def __init__(self, name: str, **args: Any) -> None:
+        self.name = SPAN_PREFIX + name
+        self.args = args
+        self._tm = None
+
+    def __enter__(self) -> "ProgramSpan":
+        self._tm = _trace_begin(self.name, self.args)
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        _trace_end(self._tm)
+        self._tm = None
+
+
+class _GcClock:
+    """Process-wide clock of Python garbage-collection pauses: the ns
+    spent in collections so far (any thread), and an ``ff.process.gc``
+    span per collection. :class:`StageLedger` records difference it."""
+
+    def __init__(self) -> None:
+        self.total_ns = 0
+        self._t0: int | None = None
+        self._tm = None
+        self._installed = False
+
+    def install(self) -> None:
+        if not self._installed:
+            self._installed = True
+            gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._tm = _trace_begin(SPAN_PREFIX + "process.gc",
+                                    {"generation": info["generation"]})
+            self._t0 = time.monotonic_ns()
+        elif self._t0 is not None:
+            self.total_ns += time.monotonic_ns() - self._t0
+            self._t0 = None
+            _trace_end(self._tm)
+            self._tm = None
+
+
+GC_CLOCK = _GcClock()
+
+
+class _Stage:
+    """One stage of a :class:`StageLedger` (see its ``stage``). Only the
+    innermost open stage of a thread runs; the ones around it are
+    suspended. ``ns`` is what it charged to the ledger's pending split
+    since record cut ``cut`` (what :meth:`StageLedger.refund` takes
+    back)."""
+
+    __slots__ = ("_ledger", "name", "_args", "t0", "t1", "ns", "cut", "_tm",
+                 "_open", "_running")
+
+    def __init__(self, ledger: "StageLedger", name: str, args: dict,
+                 since_ns: int | None) -> None:
+        self._ledger = ledger
+        self.name = name
+        self._args = args
+        self.t0 = since_ns
+        self.t1 = 0
+        self.ns = 0
+        self.cut = -1
+        self._tm = None
+        self._open = False
+        self._running = False
+
+    @property
+    def open(self) -> bool:
+        return self._open
+
+    def __enter__(self) -> "_Stage":
+        led = self._ledger
+        stack = led._stack()
+        if stack:
+            stack[-1]._suspend()
+        self._tm = _trace_begin(led._span_prefix + self.name, self._args)
+        now = time.monotonic_ns()
+        if self.t0 is None:
+            self.t0 = now
+        self._open = self._running = True
+        stack.append(self)
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        if not self._open:
+            return
+        now = self.t1 = time.monotonic_ns()
+        self._open = False
+        led = self._ledger
+        if self._running:
+            # A suspended stage (closed out of order, under a stage still
+            # open) was charged up to its suspension: nothing more.
+            self._running = False
+            led._charge(self, now)
+            _trace_end(self._tm)
+            self._tm = None
+        stack = led._stack()
+        if stack and stack[-1] is self:
+            stack.pop()
+            if stack:
+                stack[-1]._resume()
+        elif self in stack:
+            stack.remove(self)
+
+    def _suspend(self) -> None:
+        # A nested stage takes over: this one's time so far is charged,
+        # and its span ends, so each instant counts in one stage only.
+        self._running = False
+        self._ledger._charge(self, time.monotonic_ns())
+        _trace_end(self._tm)
+        self._tm = None
+
+    def _resume(self) -> None:
+        self._tm = _trace_begin(self._ledger._span_prefix + self.name,
+                                self._args)
+        self.t0 = time.monotonic_ns()
+        self._running = True
 
 
 class StageLedger:
     """Per-tick stage attribution: ONE fixed-shape record per serving
-    tick — tick id, queue depth, batch size, and a monotonic-ns split per
-    pipeline stage — kept in a bounded ring buffer and mirrored into
+    tick — tick id, queue depth, batch size, a monotonic-ns split per
+    pipeline stage, and the ns of garbage collection since the previous
+    record (``gc``) — kept in a bounded ring buffer and mirrored into
     per-stage :class:`Histogram` s of a shared registry (so alfred's
     ``get_metrics`` exports ``<prefix>.<stage>.p50/p99`` and
     tools/monitor.py can render a live stage-attribution bar).
+
+    Stages are timed with :meth:`stage`: at its edges it reads
+    ``time.monotonic_ns`` and adds the ns to the pending split that the
+    next :meth:`record` consumes, and it opens a profiler span
+    ``ff.<prefix>.<stage>`` on the same edges, so the device trace and the
+    ledger share one clock. Stages nest without double counting: a nested
+    stage suspends the one around it. Charged intervals never overlap:
+    each starts no earlier than the last cut or the end of the last
+    charge, so the stages of one thread sum to at most the record's
+    ``wall``, and ``wall`` minus that sum is the time no stage covered.
+    ``gc`` overlaps the stages it interrupted and is not a stage.
 
     The record dict is intentionally flat and identical every tick
     (stages absent from a split map record 0 ns), so downstream consumers
@@ -170,8 +354,19 @@ class StageLedger:
                  prefix: str = "storm.stage", capacity: int = 1024) -> None:
         self.stages = tuple(stages)
         self.prefix = prefix
+        self._span_prefix = f"{SPAN_PREFIX}{prefix}."
         self._ring: deque = deque(maxlen=max(1, capacity))
         self._lock = threading.Lock()
+        self._local = threading.local()
+        self._pending = dict.fromkeys(self.stages, 0)
+        # _mark_ns: the last cut (record or clear); _covered_ns: where the
+        # last charged interval ended; _cuts counts the cuts. The lock
+        # guards them and the pending split: clear() may run on another
+        # thread than the stages.
+        self._mark_ns = self._covered_ns = time.monotonic_ns()
+        self._cuts = 0
+        GC_CLOCK.install()
+        self._gc_mark = GC_CLOCK.total_ns
         self._hists = None
         self._wall_hist = None
         if registry is not None:
@@ -179,24 +374,102 @@ class StageLedger:
                            for s in self.stages}
             self._wall_hist = registry.histogram(f"{prefix}.wall")
 
+    def _charge(self, stage: _Stage, now: int) -> None:
+        with self._lock:
+            self._charge_locked(stage, now)
+
+    def _charge_locked(self, stage: _Stage, now: int) -> None:
+        # Time already charged, or before the last cut, is not charged
+        # again.
+        ns = now - max(stage.t0, self._covered_ns)
+        if ns <= 0:
+            return
+        self._covered_ns = now
+        self._pending[stage.name] += ns
+        if stage.cut != self._cuts:
+            stage.cut, stage.ns = self._cuts, 0
+        stage.ns += ns
+
+    def refund(self, stage: _Stage) -> None:
+        """Take back what ``stage`` charged to the pending split: work
+        that turned out to serve no tick (a frame shed before any tick
+        took it). A record already cut keeps its share; the refunded time
+        shows as the record's uncovered rest."""
+        with self._lock:
+            if stage.cut == self._cuts:
+                self._pending[stage.name] -= stage.ns
+            stage.ns = 0
+
+    def pending(self) -> dict:
+        """The split the next :meth:`record` will hold so far."""
+        with self._lock:
+            return dict(self._pending)
+
+    def _stack(self) -> list:
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
+
+    def stage(self, name: str, tick: int | None = None,
+              since_ns: int | None = None) -> _Stage:
+        """Context manager timing one stage of the next record (see the
+        class doc). ``tick`` rides the profiler span as an argument;
+        ``since_ns`` starts the ledger's count at an earlier
+        ``time.monotonic_ns`` stamp (a receive time taken before the
+        stage could open), though never before time already charged. It
+        may also be entered and exited by hand across calls: the
+        innermost open stage of the calling thread is
+        :meth:`open_stage`."""
+        if name not in self._pending:
+            raise ValueError(f"unknown ledger stage: {name!r}")
+        return _Stage(self, name, {} if tick is None else {"tick": tick},
+                      since_ns)
+
+    def open_stage(self) -> _Stage | None:
+        """The calling thread's innermost open stage, if any."""
+        stack = self._stack()
+        return stack[-1] if stack else None
+
     def record(self, tick_id: int, queue_depth: int, batch_docs: int,
-               batch_ops: int, splits_ns: dict, wall_ns: int = 0,
-               depth: int = 0) -> dict:
-        """Commit one tick's record; unknown split keys are rejected
-        (a typo'd stage would silently vanish from the attribution —
-        and must fail under ``python -O`` too, hence no assert).
-        ``wall_ns`` is the tick's exclusive wall-clock slice (0 =
-        unknown, the pre-pipelining shape); ``depth`` the serving
-        pipeline depth that produced it."""
+               batch_ops: int, splits_ns: dict | None = None,
+               wall_ns: int | None = 0, depth: int = 0) -> dict:
+        """Commit one tick's record: the pending split of the stages timed
+        since the previous record, plus ``splits_ns`` (explicit splits;
+        unknown keys are rejected — a typo'd stage would silently vanish
+        from the attribution, and must fail under ``python -O`` too,
+        hence no assert). ``wall_ns`` is the tick's exclusive wall-clock
+        slice (0 = unknown, the pre-pipelining shape; None = the time
+        since the previous record, or since :meth:`clear`); ``depth`` the
+        serving pipeline depth that produced it."""
+        splits_ns = splits_ns or {}
         unknown = set(splits_ns) - set(self.stages)
         if unknown:
             raise ValueError(f"unknown ledger stages: {sorted(unknown)}")
-        rec = {"tick": int(tick_id), "queue_depth": int(queue_depth),
-               "batch_docs": int(batch_docs), "batch_ops": int(batch_ops),
-               "wall": int(wall_ns), "depth": int(depth)}
-        for s in self.stages:
-            rec[s] = int(splits_ns.get(s, 0))
+        stack = self._stack()
         with self._lock:
+            now = time.monotonic_ns()
+            if stack:
+                # The cut is exact: a stage still open is charged up to
+                # here.
+                top = stack[-1]
+                self._charge_locked(top, now)
+                top.t0 = now
+            pending = self._pending
+            self._pending = dict.fromkeys(self.stages, 0)
+            if wall_ns is None:
+                wall_ns = now - self._mark_ns
+            self._mark_ns = self._covered_ns = now
+            self._cuts += 1
+            gc_ns = GC_CLOCK.total_ns
+            rec = {"tick": int(tick_id), "queue_depth": int(queue_depth),
+                   "batch_docs": int(batch_docs),
+                   "batch_ops": int(batch_ops), "wall": int(wall_ns),
+                   "depth": int(depth), "gc": gc_ns - self._gc_mark}
+            self._gc_mark = gc_ns
+            for s in self.stages:
+                rec[s] = int(pending[s] + splits_ns.get(s, 0))
             self._ring.append(rec)
         if self._wall_hist is not None and rec["wall"] > 0:
             self._wall_hist.observe(rec["wall"] / 1e9)
@@ -222,11 +495,17 @@ class StageLedger:
             return len(self._ring)
 
     def clear(self) -> None:
-        """Drop the ring (benches clear warm-up/compile ticks so the
-        attribution window covers only the measured run); the registry
-        histograms keep their cumulative view."""
+        """Drop the ring and the pending split, and restart the wall
+        clock (benches clear warm-up/compile ticks so the attribution
+        window covers only the measured run); a stage open across the
+        clear, on any thread, charges only its time after it. The
+        registry histograms keep their cumulative view."""
         with self._lock:
             self._ring.clear()
+            self._pending = dict.fromkeys(self.stages, 0)
+            self._mark_ns = self._covered_ns = time.monotonic_ns()
+            self._cuts += 1
+            self._gc_mark = GC_CLOCK.total_ns
 
     def records(self) -> list[dict]:
         with self._lock:
