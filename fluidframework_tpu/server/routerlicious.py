@@ -679,6 +679,10 @@ class RouterliciousService:
         self.fanout = fanout
         self._fanout_subs: dict[tuple[str, str], int] = {}
         self._fanout_last_seq: dict[tuple[str, str], int] = {}
+        # The drain's view of _fanout_subs: its keys in order and their
+        # sub ids as one int64 array; None = rebuild at the next drain.
+        self._fanout_keys: list[tuple[str, str]] = []
+        self._fanout_ids = None
         self.logger = logger if logger is not None else NullLogger()
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         if merge_host is not None:
@@ -857,37 +861,77 @@ class RouterliciousService:
 
     def _drain_fanout(self) -> int:
         """Frontend drain: deliver each subscriber's queued room payloads
-        to its connection (the socket-server side of the pub/sub hop)."""
+        to its connection (the socket-server side of the pub/sub hop).
+
+        Each pass pops the head payload of every subscriber still in play
+        in ONE ``poll_batch`` call; the next pass polls only those that
+        returned one, so the drain ends when a pass returns nothing.
+        Compact storm tick frames (first byte 0, server/storm.py) are
+        skipped by a vectorised first-byte test: storm-aware frontends
+        consume them, and the per-op connections here catch up via
+        get_deltas materialization instead. Only per-op payloads and
+        evicted subscribers cost Python per subscriber."""
         import json as _json
 
+        import numpy as np
+
         from ..protocol.codec import from_wire
+        if self._fanout_ids is None:
+            self._fanout_keys = list(self._fanout_subs)
+            self._fanout_ids = np.fromiter(self._fanout_subs.values(),
+                                           np.int64, len(self._fanout_subs))
+        keys, ids = self._fanout_keys, self._fanout_ids
+        per_op: dict[int, list[bytes]] = {}
+        unknown: list[int] = []
+        idx, subs = np.arange(len(ids)), ids
+        passes = payloads = 0
+        while len(subs):
+            buf, lens = self.fanout.poll_batch(subs)
+            passes += 1
+            popped = lens >= 0
+            unknown += idx[lens == -2].tolist()
+            sizes = np.where(popped, lens, 0)
+            starts = np.cumsum(sizes) - sizes
+            keep = lens == 0  # popped, and no compact frame
+            full = lens > 0
+            keep[full] = np.frombuffer(buf, np.uint8)[starts[full]] != 0
+            # The view aliases a reused scratch: copy what is kept before
+            # the next pass.
+            for i, start, size in zip(idx[keep].tolist(),
+                                      starts[keep].tolist(),
+                                      lens[keep].tolist()):
+                per_op.setdefault(i, []).append(bytes(buf[start:start + size]))
+            idx, subs = idx[popped], subs[popped]
+            payloads += len(idx)
+        self.metrics.counter("fanout.drain.passes").inc(passes)
+        self.metrics.counter("fanout.drain.payloads").inc(payloads)
         delivered = 0
-        for (doc_id, client_id), sub in list(self._fanout_subs.items()):
-            if self.fanout.was_evicted(sub):
-                # Slow-consumer drop in the fan-out: the sub will never
-                # receive again, so close the connection (the client's
-                # reconnect path resyncs from the durable log) instead of
-                # leaving it silently deaf.
-                self.logger.send_event("FanoutSubscriberEvicted",
-                                       docId=doc_id, clientId=client_id)
-                self.disconnect(doc_id, client_id)
+        evicted = set(unknown)
+        for i in sorted(evicted.union(per_op)):
+            doc_id, client_id = key = keys[i]
+            if i in evicted:
+                if self.fanout.was_evicted(int(ids[i])):
+                    # Slow-consumer drop in the fan-out: the sub will
+                    # never receive again, so close the connection (the
+                    # client's reconnect path resyncs from the durable
+                    # log) instead of leaving it silently deaf.
+                    self.logger.send_event("FanoutSubscriberEvicted",
+                                           docId=doc_id, clientId=client_id)
+                    self.disconnect(doc_id, client_id)
                 continue
+            if key not in self._fanout_subs:
+                continue  # an earlier handler closed this connection
+            last = self._fanout_last_seq.get(key, 0)
             batch: list[SequencedDocumentMessage] = []
-            last_key = (doc_id, client_id)
-            while (payload := self.fanout.poll(sub)) is not None:
-                if payload[:1] == b"\x00":
-                    # Compact storm tick frame (server/storm.py): consumed
-                    # by storm-aware frontends; the per-op connections here
-                    # catch up via get_deltas materialization instead.
-                    continue
+            for payload in per_op[i]:
                 op = from_wire(_json.loads(payload.decode()))
-                if op.sequence_number <= self._fanout_last_seq.get(
-                        last_key, 0):
+                if op.sequence_number <= last:
                     continue  # crash-replay dedup, as in direct mode
-                self._fanout_last_seq[last_key] = op.sequence_number
+                last = op.sequence_number
                 batch.append(op)
             if not batch:
                 continue
+            self._fanout_last_seq[key] = last
             conn = self._connections_for(doc_id).get(client_id)
             if conn is not None and conn.open:
                 delivered += len(batch)
@@ -938,6 +982,7 @@ class RouterliciousService:
             sub = self.fanout.connect()
             self.fanout.join(sub, doc_id)
             self._fanout_subs[(doc_id, client_id)] = sub
+            self._fanout_ids = None
         self.logger.send_event("ClientConnect", docId=doc_id,
                                clientId=client_id, mode=mode)
         self._announce_audience(doc_id, connection)
@@ -975,6 +1020,7 @@ class RouterliciousService:
             sub = self._fanout_subs.pop((doc_id, client_id), None)
             if sub is not None:
                 self.fanout.disconnect(sub)
+                self._fanout_ids = None
             self._fanout_last_seq.pop((doc_id, client_id), None)
         connection = self._connections_for(doc_id).pop(client_id, None)
         if connection is not None:

@@ -235,3 +235,170 @@ def test_service_broadcast_through_fanout(force_python):
     c2.close()
     m1.set("z", 3)
     assert m1.get("z") == 3
+
+
+# -- the writers' drain (RouterliciousService._drain_fanout) -----------------
+
+
+class _CountingFanout:
+    """Delegates to a fan-out and counts the drain's native calls."""
+
+    def __init__(self, inner):
+        self.inner = inner
+        self.calls = {"poll": 0, "was_evicted": 0, "poll_batch": 0}
+
+    def poll(self, sub):
+        self.calls["poll"] += 1
+        return self.inner.poll(sub)
+
+    def was_evicted(self, sub):
+        self.calls["was_evicted"] += 1
+        return self.inner.was_evicted(sub)
+
+    def poll_batch(self, subs):
+        self.calls["poll_batch"] += 1
+        return self.inner.poll_batch(subs)
+
+    def __getattr__(self, name):
+        return getattr(self.inner, name)
+
+
+def _drain_service(force_python):
+    from fluidframework_tpu.utils.telemetry import CollectingLogger
+
+    fanout = _CountingFanout(make_fanout(force_python))
+    return RouterliciousService(fanout=fanout, auto_pump=False,
+                                logger=CollectingLogger())
+
+
+def _writer(service, doc_id):
+    """Connect one writer whose handler records each batch's seqs; the
+    joins are drained, so the record starts empty."""
+    calls = []
+    conn = service.connect(doc_id, lambda batch: calls.append(
+        [m.sequence_number for m in batch]))
+    service.pump()
+    calls.clear()
+    return conn, calls
+
+
+def _publish_op(service, doc_id, seq):
+    import json
+
+    from fluidframework_tpu.protocol.codec import to_wire
+    from fluidframework_tpu.protocol.messages import (
+        MessageType, SequencedDocumentMessage)
+    op = SequencedDocumentMessage(
+        client_id="peer", sequence_number=seq, minimum_sequence_number=0,
+        client_sequence_number=seq, reference_sequence_number=0,
+        type=MessageType.OPERATION, contents={"seq": seq})
+    service.fanout.publish(doc_id, json.dumps(to_wire(op)).encode())
+
+
+_DRAIN_IMPLS = pytest.mark.parametrize("force_python", [True, False],
+                                       ids=["python", "native"])
+
+
+@_DRAIN_IMPLS
+def test_drain_delivers_queued_ops_fifo_in_one_call(force_python):
+    service = _drain_service(force_python)
+    _conn, calls = _writer(service, "fifo")
+    for seq in (100, 101, 102):
+        _publish_op(service, "fifo", seq)
+    assert service._drain_fanout() == 3
+    assert calls == [[100, 101, 102]]
+
+
+@_DRAIN_IMPLS
+def test_drain_drops_replayed_ops(force_python):
+    service = _drain_service(force_python)
+    _conn, calls = _writer(service, "dedup")
+    _publish_op(service, "dedup", 100)
+    _publish_op(service, "dedup", 101)
+    assert service._drain_fanout() == 2
+    for seq in (100, 101, 102):  # crash replay re-publishes 100 and 101
+        _publish_op(service, "dedup", seq)
+    assert service._drain_fanout() == 1
+    assert calls == [[100, 101], [102]]
+
+
+@_DRAIN_IMPLS
+def test_drain_skips_compact_storm_frames(force_python):
+    service = _drain_service(force_python)
+    conn, calls = _writer(service, "compact")
+    sub = service._fanout_subs[("compact", conn.client_id)]
+    service.fanout.publish("compact", b"\x00storm1:8:1")
+    passes = service.metrics.counter("fanout.drain.passes").value
+    payloads = service.metrics.counter("fanout.drain.payloads").value
+    assert service._drain_fanout() == 0
+    assert calls == []
+    assert service.fanout.pending(sub) == 0
+    assert service.metrics.counter("fanout.drain.passes").value \
+        == passes + 2
+    assert service.metrics.counter("fanout.drain.payloads").value \
+        == payloads + 1
+
+
+@_DRAIN_IMPLS
+def test_drain_disconnects_evicted_writer(force_python):
+    service = _drain_service(force_python)
+    slow, slow_calls = _writer(service, "evict")
+    ok, ok_calls = _writer(service, "evict")
+    slow_calls.clear()  # the room-mate's join
+    service.fanout.set_queue_limit(
+        service._fanout_subs[("evict", slow.client_id)], 2)
+    for _ in range(3):  # the third publish passes the bound
+        service.fanout.publish("evict", b"\x00storm1:8:1")
+    _publish_op(service, "evict", 100)
+    assert service._drain_fanout() == 1
+    assert ok_calls == [[100]] and slow_calls == []
+    assert not slow.open
+    assert ("evict", slow.client_id) not in service._fanout_subs
+    events = service.logger.matching("FanoutSubscriberEvicted")
+    assert [e["clientId"] for e in events] == [slow.client_id]
+    _publish_op(service, "evict", 101)
+    assert service._drain_fanout() == 1
+    assert ok_calls == [[100], [101]] and slow_calls == []
+
+
+@_DRAIN_IMPLS
+def test_drain_follows_connects_and_disconnects(force_python):
+    service = _drain_service(force_python)
+    a, a_calls = _writer(service, "churn")
+    _publish_op(service, "churn", 100)
+    assert service._drain_fanout() == 1
+    b, b_calls = _writer(service, "churn")  # joins after the first drain
+    a_calls.clear()
+    _publish_op(service, "churn", 200)
+    assert service._drain_fanout() == 2
+    assert a_calls == [[200]] and b_calls == [[200]]
+    a.close()
+    _publish_op(service, "churn", 300)
+    assert service._drain_fanout() == 1
+    assert a_calls == [[200]] and b_calls == [[200], [300]]
+    assert service.logger.matching("FanoutSubscriberEvicted") == []
+
+
+@_DRAIN_IMPLS
+def test_drain_of_compact_frames_is_two_batched_calls(force_python):
+    """1,024 writers holding one compact frame each: one pump drains them
+    in one pass that pops every head and one that finds nothing — no
+    per-subscriber poll or eviction probe."""
+    service = _drain_service(force_python)
+    docs = [f"many-{i}" for i in range(256)]
+    for doc_id in docs:
+        for _ in range(4):
+            service.connect(doc_id, lambda batch: None)
+    service.pump()
+    assert len(service._fanout_subs) == 1024
+    fanout = service.fanout
+    fanout.publish_batch([(d, b"\x00storm1:8:1") for d in docs])
+    fanout.calls = dict.fromkeys(fanout.calls, 0)
+    passes = service.metrics.counter("fanout.drain.passes").value
+    payloads = service.metrics.counter("fanout.drain.payloads").value
+    service.pump()
+    assert service.metrics.counter("fanout.drain.passes").value \
+        == passes + 2
+    assert service.metrics.counter("fanout.drain.payloads").value \
+        == payloads + 1024
+    assert fanout.calls == {"poll": 0, "was_evicted": 0, "poll_batch": 2}
