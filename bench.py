@@ -638,7 +638,7 @@ def bench_mixed_serving(num_docs: int = 8192, ticks: int = 12,
     kwargs = dict(
         num_docs=num_docs, k=map_k, num_hosts=1, num_clients=2,
         map_slots=32, text_slots=text_slots, text_k=text_k,
-        matrix_vec_slots=4 * ticks + 16, matrix_cell_slots=256,
+        matrix_vec_slots=4 * ticks + 16, matrix_handles=2 * ticks + 16,
         matrix_k=matrix_k, tree_slots=2 * tree_k, tree_k=tree_k)
 
     rng = np.random.default_rng(11)
@@ -831,25 +831,22 @@ def bench_mixed_serving(num_docs: int = 8192, ticks: int = 12,
     return out
 
 
-def bench_matrix_config4(num_docs: int = 8192, grid: int = 1024,
+def bench_matrix_config4(num_docs: int = 8, grid: int = 1024,
                          n_writers: int = 256, k: int = 1024,
                          ticks: int = 6) -> dict:
-    """BASELINE config 4 AT ITS STATED SHAPE: a 1k x 1k SharedMatrix with
-    256 concurrent clients issuing cell writes (the grid settled, no
-    structural ops in flight), device-served through the scan-free
-    cell-run kernel (ops/matrix_kernel.apply_cell_run): one [R, S]
-    handle-resolution pass per axis, then ONE [B, R]-tile append into
-    the cell log at a shared offset. ``num_docs`` such matrices batch on
-    the doc axis — every one is the stated 1k x 1k / 256-writer shape."""
-    import jax
+    """BASELINE config 4 AT ITS STATED SHAPE: 1k x 1k SharedMatrix grids
+    with 256 concurrent clients issuing cell writes, device-served by the
+    handle-indexed plane kernel (ops/matrix_kernel.apply_tick_steps): an
+    [N, S] handle resolution per axis, then one scatter into the
+    [B, HR, HC] planes. ``num_docs`` such matrices batch on the doc
+    axis — every one is the stated 1k x 1k / 256-writer shape."""
     import jax.numpy as jnp
 
     from fluidframework_tpu.ops import matrix_kernel as mxk
     from fluidframework_tpu.ops import mergetree_kernel as mtk
 
     rng = np.random.default_rng(4)
-    state = mxk.init_state(num_docs, vec_slots=8,
-                           cell_slots=2 * k * (ticks + 1))
+    state = mxk.init_state(num_docs, vec_slots=8, row_handles=grid)
     setup = [[dict(target=mxk.MX_ROWS, kind=mtk.MT_INSERT, pos=0,
                    count=grid, handle_base=0, seq=1, ref_seq=0, client=0),
               dict(target=mxk.MX_COLS, kind=mtk.MT_INSERT, pos=0,
@@ -860,34 +857,30 @@ def bench_matrix_config4(num_docs: int = 8192, grid: int = 1024,
 
     batches = []
     seq0 = 3
+    zeros_t = jnp.zeros((num_docs, 1), jnp.int32)
     for t in range(ticks):
-        run = mxk.CellRunBatch(
-            valid=jnp.ones((num_docs, k), jnp.bool_),
-            row=jnp.asarray(rng.integers(0, grid, (num_docs, k)),
-                            jnp.int32),
-            col=jnp.asarray(rng.integers(0, grid, (num_docs, k)),
-                            jnp.int32),
-            value=jnp.asarray(rng.integers(1, 1 << 20, (num_docs, k)),
+        batches.append(mxk.MatrixStepBatch(
+            vec_valid=jnp.zeros((num_docs, 1), jnp.bool_),
+            kind=zeros_t, target=zeros_t, pos=zeros_t, end=zeros_t,
+            count=zeros_t, handle_base=zeros_t, seq=zeros_t,
+            ref_seq=zeros_t, client=zeros_t,
+            c_valid=jnp.ones((num_docs, k), jnp.bool_),
+            c_step=jnp.zeros((num_docs, k), jnp.int32),
+            c_row=jnp.asarray(rng.integers(0, grid, (num_docs, k)),
                               jnp.int32),
-            seq=jnp.asarray(
-                np.broadcast_to(seq0 + t * k + np.arange(k, dtype=np.int32),
-                                (num_docs, k)).copy()),
-            ref_seq=jnp.full((num_docs,), seq0 + t * k - 1, jnp.int32),
-            client=jnp.asarray(rng.integers(0, n_writers, num_docs),
-                               jnp.int32),
-        )
-        batches.append(run)
+            c_col=jnp.asarray(rng.integers(0, grid, (num_docs, k)),
+                              jnp.int32),
+            c_value=jnp.asarray(rng.integers(1, 1 << 20, (num_docs, k)),
+                                jnp.int32),
+            c_seq=jnp.asarray(np.broadcast_to(
+                seq0 + t * k + np.arange(k, dtype=np.int32),
+                (num_docs, k)).copy()),
+            c_ref=jnp.full((num_docs, k), seq0 + t * k - 1, jnp.int32),
+            c_client=jnp.asarray(rng.integers(0, n_writers, (num_docs, k)),
+                                 jnp.int32)))
 
-    out = _run_device(mxk.apply_cell_run, state, batches, num_docs * k,
+    out = _run_device(mxk.apply_tick_steps, state, batches, num_docs * k,
                       passes=4)
-    # One clean pass from the setup state proves the stated shape fits
-    # device capacity (the timed loops recycle batches purely for rate —
-    # a full cell log clamps appends without changing the work).
-    final = state
-    for b in batches:
-        final = mxk.apply_cell_run(final, b)
-    m = mxk.capacity_margin(final)
-    assert (m["cells"] > 0).all(), "config-4 bench overflowed the cell log"
     out["overflow_routed"] = 0
 
     # Scalar baseline: the same shape through the scalar engines —
@@ -915,7 +908,7 @@ def bench_matrix_config4(num_docs: int = 8192, grid: int = 1024,
     out["num_docs"] = num_docs
     out["grid"] = f"{grid}x{grid}"
     out["n_writers"] = n_writers
-    # Handle resolution (2 x [R, S]) + LWW sort + pack per cell.
+    # Handle resolution (2 x [N, S]) + LWW sort + scatter per cell.
     out["vpu_util_est"] = round(
         out["device_ops_per_sec"] * (2 * 8 + 60 + 40) / _vpu_peak(), 4)
     return out
@@ -965,41 +958,31 @@ def bench_matrix(num_docs: int = 16384, k: int = 64, ticks: int = 6) -> dict:
     import jax.numpy as jnp
 
     from fluidframework_tpu.ops import matrix_kernel as mxk
-    from fluidframework_tpu.ops import matrix_pallas as mxp
 
     rng = random.Random(0)
     stream = _gen_matrix_stream(rng, k * ticks)
-    # STEP/RUN layout (matrix_kernel.MatrixStepBatch): consecutive cells
-    # between vector ops share one visibility frame, so the two-axis
-    # prefix scan is paid per RUN. Measured ~1.15x the per-op kernel at
-    # this shape — the per-step floor (walk + two frame scans + the
-    # per-cell table writes) bounds the win; both layouts stay
-    # differentially pinned.
+    # Step layout (matrix_kernel.MatrixStepBatch): the vector ops scan,
+    # every cell resolves in its own frame against the table history.
     batches = []
-    lvs = [0]
     for t in range(ticks):
         chunk = [stream[t * k:(t + 1) * k]]
-        steps = mxk.make_matrix_step_batch(chunk, 1, r_max=8,
-                                           last_vec_seq=lvs)
+        steps = mxk.make_matrix_step_batch(chunk, 1, t=k, n=k)
         batches.append(type(steps)(
             *[jnp.asarray(_tile(np.asarray(f), num_docs))
               for f in steps]))
-        for op in chunk[0]:
-            if op["target"] != mxk.MX_CELL:
-                lvs[0] = max(lvs[0], op["seq"])
 
-    out = _run_device(mxp.apply_tick_steps_best,
-                      mxk.init_state(num_docs, vec_slots=256, cell_slots=256),
+    out = _run_device(mxk.apply_tick_steps,
+                      mxk.init_state(num_docs, vec_slots=256,
+                                     row_handles=128),
                       batches, num_docs * k)
-    out["kernel_path"] = ("xla_step_scan" if mxp.default_interpret()
-                          else "pallas_vmem_steps")
+    out["kernel_path"] = "xla_step_scan"
     cpu_docs = 128
     cpu_batches = [type(b)(
         *[jnp.asarray(_tile(np.asarray(f)[:1], cpu_docs)) for f in b])
         for b in batches[:2]]  # _cpu_batched_rate uses two ticks
     out["xla_cpu_batched_ops_per_sec"] = _cpu_batched_rate(
         mxk.apply_tick_steps,
-        mxk.init_state(cpu_docs, vec_slots=256, cell_slots=256),
+        mxk.init_state(cpu_docs, vec_slots=256, row_handles=128),
         cpu_batches, cpu_docs * k)
     # Two embedded merge states (6 planes x 256 vec slots) + cell table.
     out["vpu_util_est"] = round(

@@ -353,7 +353,7 @@ def phase_all_family(dev, seed: int, num_docs: int = 8192, ticks: int = 4,
         make_mesh(jax.devices()[:1]), num_docs=num_docs, k=map_k,
         num_hosts=1, num_clients=2, map_slots=32,
         text_slots=2 * text_k * ticks + 64, text_k=text_k,
-        matrix_vec_slots=4 * ticks + 16, matrix_cell_slots=256,
+        matrix_vec_slots=4 * ticks + 16, matrix_handles=2 * ticks + 16,
         matrix_k=matrix_k, tree_slots=2 * tree_k, tree_k=tree_k)
     serving.join_all()
     rng = np.random.default_rng(seed)

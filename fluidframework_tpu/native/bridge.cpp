@@ -8,6 +8,15 @@
 // DCN hop is owned by native code; Python only sees whole decoded
 // frames.
 //
+// Threads: one acceptor, one reader per connection (blocking reads of
+// whole frames), and a small fixed pool of writers (kWriters), each
+// draining the outboxes of its share of the connections with
+// non-blocking sends and poll() — so a host of thousands of connections
+// holds about one thread per connection, and a peer that stops reading
+// stalls only its own outbox. A body sent to many connections in a row
+// (one broadcast to a document's sessions) is framed once and shared by
+// their outboxes.
+//
 // Protocol: the same length-prefixed framing alfred speaks (4-byte BE
 // length + body), so the existing network driver connects to this
 // bridge unchanged. The C++ side owns accept/read/write threads and
@@ -38,9 +47,11 @@
 #include <arpa/inet.h>
 #include <netinet/in.h>
 #include <netinet/tcp.h>
+#include <poll.h>
 #include <sys/socket.h>
 #include <unistd.h>
 
+#include <cerrno>
 #include <atomic>
 #include <chrono>
 #include <condition_variable>
@@ -59,6 +70,7 @@ namespace {
 constexpr uint32_t kMaxFrame = 64u * 1024u * 1024u;
 constexpr size_t kMaxInboundQueue = 8192;  // decoded frames per conn
 constexpr size_t kMaxOutbox = 8192;        // queued responses per conn
+constexpr int kWriters = 4;                // writer threads per bridge
 
 struct Event {
     int64_t conn;
@@ -66,18 +78,39 @@ struct Event {
     std::string body;
 };
 
+struct Writer;
+
 struct Conn {
     int fd = -1;
     std::thread reader;
-    std::thread writer;
+    Writer* writer = nullptr;
     std::mutex out_mu;
-    std::condition_variable out_cv;
-    std::deque<std::string> outbox;
+    // Framed bodies (length word first), shared across connections.
+    std::deque<std::shared_ptr<const std::string>> outbox;
     // Per-connection outbox bound; 0 = use the bridge-wide default.
     // Lets connection CLASSES differ (a read-only viewer lag-drops at a
     // shallow outbox while writer connections keep the deep default).
     size_t max_outbox = 0;
     bool closing = false;
+    bool scheduled = false;  // in its writer's ready list (writer->mu)
+    // Owned by the writer thread: the frame being sent and how far.
+    bool active = false;
+    std::shared_ptr<const std::string> cur;
+    size_t off = 0;
+
+    ~Conn() {
+        if (fd >= 0) ::close(fd);
+    }
+};
+
+// One writer thread: connections with queued frames arrive in ``ready``;
+// it sends until each would block, then polls those for POLLOUT.
+struct Writer {
+    std::mutex mu;
+    std::condition_variable cv;
+    std::vector<std::shared_ptr<Conn>> ready;
+    bool stop = false;
+    std::thread thread;
 };
 
 struct Bridge {
@@ -90,7 +123,12 @@ struct Bridge {
     std::thread acceptor;
     std::mutex mu;  // guards conns, events, inbound_depth
     std::condition_variable events_cv;
-    std::map<int64_t, std::unique_ptr<Conn>> conns;
+    std::map<int64_t, std::shared_ptr<Conn>> conns;
+    Writer writers[kWriters];
+    // The last body framed by bridge_send and where it was read from: the
+    // next send of the same bytes shares it instead of copying (mu).
+    std::shared_ptr<const std::string> last_frame;
+    const char* last_src = nullptr;
     int64_t next_conn = 1;
     std::deque<Event> events;
     std::map<int64_t, size_t> inbound_depth;  // queued DATA events per conn
@@ -111,15 +149,6 @@ bool read_exact(int fd, char* buf, size_t n) {
     return true;
 }
 
-bool write_all(int fd, const char* buf, size_t n) {
-    size_t sent = 0;
-    while (sent < n) {
-        ssize_t r = ::send(fd, buf + sent, n - sent, MSG_NOSIGNAL);
-        if (r <= 0) return false;
-        sent += static_cast<size_t>(r);
-    }
-    return true;
-}
 
 void reader_loop(Bridge* b, int64_t id, int fd) {
     for (;;) {
@@ -149,27 +178,71 @@ void reader_loop(Bridge* b, int64_t id, int fd) {
     b->events_cv.notify_one();
 }
 
-void writer_loop(Conn* c) {
+// Send ``c``'s queued frames until its socket would block (1), the
+// outbox is empty (0), or the connection is closing or broken (-1: the
+// reader sees the peer go and raises CLOSE).
+int flush_conn(Conn* c) {
     for (;;) {
-        std::string body;
-        {
-            std::unique_lock<std::mutex> lock(c->out_mu);
-            c->out_cv.wait(lock, [c] {
-                return c->closing || !c->outbox.empty();
-            });
-            if (c->outbox.empty()) return;  // closing with nothing queued
-            body = std::move(c->outbox.front());
+        if (!c->cur || c->off == c->cur->size()) {
+            std::lock_guard<std::mutex> lock(c->out_mu);
+            if (c->closing) return -1;
+            if (c->outbox.empty()) {
+                c->cur.reset();
+                c->off = 0;
+                return 0;
+            }
+            c->cur = std::move(c->outbox.front());
             c->outbox.pop_front();
+            c->off = 0;
         }
-        char header[4] = {
-            static_cast<char>((body.size() >> 24) & 0xFF),
-            static_cast<char>((body.size() >> 16) & 0xFF),
-            static_cast<char>((body.size() >> 8) & 0xFF),
-            static_cast<char>(body.size() & 0xFF),
-        };
-        if (!write_all(c->fd, header, 4)) return;
-        if (!body.empty() && !write_all(c->fd, body.data(), body.size()))
-            return;
+        ssize_t r = ::send(c->fd, c->cur->data() + c->off,
+                           c->cur->size() - c->off,
+                           MSG_NOSIGNAL | MSG_DONTWAIT);
+        if (r > 0) {
+            c->off += static_cast<size_t>(r);
+        } else if (r < 0 && (errno == EAGAIN || errno == EWOULDBLOCK
+                             || errno == EINTR)) {
+            return 1;
+        } else {
+            return -1;
+        }
+    }
+}
+
+void writer_loop(Writer* w) {
+    std::vector<std::shared_ptr<Conn>> active;
+    std::vector<pollfd> blocked;
+    for (;;) {
+        {
+            std::unique_lock<std::mutex> lock(w->mu);
+            if (active.empty())
+                w->cv.wait(lock,
+                           [w] { return w->stop || !w->ready.empty(); });
+            if (w->stop) return;
+            for (auto& c : w->ready) {
+                c->scheduled = false;
+                if (!c->active) {
+                    c->active = true;
+                    active.push_back(std::move(c));
+                }
+            }
+            w->ready.clear();
+        }
+        blocked.clear();
+        size_t keep = 0;
+        for (auto& c : active) {
+            if (flush_conn(c.get()) == 1) {
+                blocked.push_back(pollfd{c->fd, POLLOUT, 0});
+                active[keep++] = std::move(c);
+            } else {
+                c->active = false;  // rescheduled by its next send
+            }
+        }
+        active.resize(keep);
+        // Bounded wait: frames queued meanwhile for other connections
+        // are picked up within a few ms.
+        if (!blocked.empty())
+            ::poll(blocked.data(), blocked.size(), 5);
     }
 }
 
@@ -184,29 +257,27 @@ void accept_loop(Bridge* b) {
         }
         int one = 1;
         ::setsockopt(fd, IPPROTO_TCP, TCP_NODELAY, &one, sizeof(one));
-        auto conn = std::make_unique<Conn>();
+        auto conn = std::make_shared<Conn>();
         conn->fd = fd;
-        Conn* raw = conn.get();
         std::lock_guard<std::mutex> lock(b->mu);
         int64_t id = b->next_conn++;
-        raw->reader = std::thread(reader_loop, b, id, fd);
-        raw->writer = std::thread(writer_loop, raw);
+        conn->writer = &b->writers[id % kWriters];
+        conn->reader = std::thread(reader_loop, b, id, fd);
         b->conns[id] = std::move(conn);
         b->events.push_back(Event{id, 0, std::string()});
         b->events_cv.notify_one();
     }
 }
 
+// Stop ``c``: its writer drops it at the next pass, its reader ends;
+// the fd closes with the last reference (~Conn), never under a send.
 void shutdown_conn(Conn* c) {
     {
         std::lock_guard<std::mutex> lock(c->out_mu);
         c->closing = true;
     }
-    c->out_cv.notify_all();
     ::shutdown(c->fd, SHUT_RDWR);
-    if (c->writer.joinable()) c->writer.join();
     if (c->reader.joinable()) c->reader.join();
-    ::close(c->fd);
 }
 
 }  // namespace
@@ -232,6 +303,7 @@ void* bridge_start(int port) {
     socklen_t len = sizeof(addr);
     ::getsockname(b->listen_fd, reinterpret_cast<sockaddr*>(&addr), &len);
     b->port = ntohs(addr.sin_port);
+    for (Writer& w : b->writers) w.thread = std::thread(writer_loop, &w);
     b->acceptor = std::thread(accept_loop, b.get());
     return b.release();
 }
@@ -291,9 +363,31 @@ int bridge_send(void* handle, int64_t conn, const char* data,
         size_t limit = c->max_outbox ? c->max_outbox
                                      : b->max_outbox.load();
         if (c->outbox.size() >= limit) return -2;
-        c->outbox.emplace_back(data, len);
+        // The same bytes as the last send share its frame; the compare
+        // (not the address alone) makes a reused buffer safe.
+        const std::string* last = b->last_frame.get();
+        if (!(last && b->last_src == data && last->size() == 4 + len
+              && std::memcmp(last->data() + 4, data, len) == 0)) {
+            auto framed = std::make_shared<std::string>(
+                4 + static_cast<size_t>(len), '\0');
+            (*framed)[0] = static_cast<char>((len >> 24) & 0xFF);
+            (*framed)[1] = static_cast<char>((len >> 16) & 0xFF);
+            (*framed)[2] = static_cast<char>((len >> 8) & 0xFF);
+            (*framed)[3] = static_cast<char>(len & 0xFF);
+            if (len) std::memcpy(&(*framed)[4], data, len);
+            b->last_frame = std::move(framed);
+            b->last_src = data;
+        }
+        c->outbox.push_back(b->last_frame);
     }
-    c->out_cv.notify_one();
+    Writer* w = c->writer;
+    {
+        std::lock_guard<std::mutex> w_lock(w->mu);
+        if (c->scheduled) return 0;
+        c->scheduled = true;
+        w->ready.push_back(it->second);
+    }
+    w->cv.notify_one();
     return 0;
 }
 
@@ -318,7 +412,7 @@ int bridge_set_conn_max_outbox(void* handle, int64_t conn, int64_t n) {
 
 int bridge_close(void* handle, int64_t conn) {
     Bridge* b = static_cast<Bridge*>(handle);
-    std::unique_ptr<Conn> owned;
+    std::shared_ptr<Conn> owned;
     {
         std::lock_guard<std::mutex> lock(b->mu);
         auto it = b->conns.find(conn);
@@ -326,17 +420,15 @@ int bridge_close(void* handle, int64_t conn) {
         owned = std::move(it->second);
         b->conns.erase(it);
     }
-    // Joining reader/writer can block on in-flight IO; do it off the
+    // Joining the reader can block on in-flight IO; do it off the
     // caller's thread (detached) so the Python pump never stalls and no
     // unjoined thread accumulates per disconnect.
-    Conn* craw = owned.release();
     {
         std::lock_guard<std::mutex> lock(b->reap_mu);
         ++b->live_reapers;
     }
-    std::thread([b, craw] {
-        shutdown_conn(craw);
-        delete craw;
+    std::thread([b, owned] {
+        shutdown_conn(owned.get());
         {
             std::lock_guard<std::mutex> lock(b->reap_mu);
             --b->live_reapers;
@@ -352,12 +444,20 @@ void bridge_stop(void* handle) {
     ::shutdown(b->listen_fd, SHUT_RDWR);
     ::close(b->listen_fd);
     if (b->acceptor.joinable()) b->acceptor.join();
-    std::map<int64_t, std::unique_ptr<Conn>> conns;
+    std::map<int64_t, std::shared_ptr<Conn>> conns;
     {
         std::lock_guard<std::mutex> lock(b->mu);
         conns.swap(b->conns);
     }
     for (auto& entry : conns) shutdown_conn(entry.second.get());
+    for (Writer& w : b->writers) {
+        {
+            std::lock_guard<std::mutex> lock(w.mu);
+            w.stop = true;
+        }
+        w.cv.notify_all();
+        if (w.thread.joinable()) w.thread.join();
+    }
     {
         std::unique_lock<std::mutex> lock(b->reap_mu);
         b->reap_cv.wait(lock, [b] { return b->live_reapers == 0; });

@@ -10,16 +10,25 @@ an LWW cell table keyed (rowHandle, colHandle). TPU composition:
     handle of its run (runs are contiguous because sequenced inserts
     allocate handles in document order — the deterministic allocation rule
     of dds/matrix.py); splits inherit ``pool_start + offset`` for free.
-  * (row, col) → handle resolution = the same masked-prefix-sum position
-    lookup the merge kernel uses for its insert walk, evaluated in the
-    (refSeq, client) visibility frame — matrix.ts's adjustPosition.
-  * cells = a device table of (row_handle, col_handle, value, seq) rows
-    with first-match-or-append placement; sequenced order makes the LWW
-    fold a plain overwrite (matrix.ts isLatestPendingWrite collapses on
-    the server-side converged stream).
-  * one sequenced op = one lax.scan step over a mixed rows/cols/cell
-    stream (total order preserved *within* the document); documents batch
-    with vmap — the 10k-doc axis (BASELINE config 4).
+  * (row, col) → handle resolution = the merge kernel's masked-prefix-sum
+    position lookup, evaluated in each cell write's own (refSeq, client)
+    visibility frame — matrix.ts's adjustPosition.
+  * cells = per-document planes ``value[B, HR, HC]`` and ``seq[B, HR, HC]``
+    indexed by (row handle, col handle). A write is a scatter; a removed
+    row or col needs no cell work, because its handle simply stops being
+    visible (as upstream, whose cells outlive their handles until the
+    handle table recycles them). HR and HC are the handle capacities; the
+    host grows them before a tick whose inserts would pass them.
+
+One tick, per document: a ``lax.scan`` applies the tick's vector ops in
+order and records the axis tables after each (a [T+1, S] history); every
+cell write then resolves its handles against the table as of its own
+sequence position (the history row after the vector ops sequenced before
+it) in its own frame — one vectorized [N, S] lookup per axis, with no
+per-cell scan. Sequenced order makes the LWW fold a plain overwrite
+(matrix.ts isLatestPendingWrite collapses on the server-side converged
+stream): of several writes to one cell in a tick the latest wins, then one
+scatter writes the winners. Documents batch on the leading axis.
 
 Differential tests feed live SharedMatrix op streams (tests/
 test_matrix_kernel.py) and assert the materialized grid matches every
@@ -28,6 +37,7 @@ converged replica cell-for-cell.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -42,18 +52,17 @@ MX_ROWS = 0
 MX_COLS = 1
 MX_CELL = 2
 
+_BIG = np.int32(2**31 - 1)
+
 
 class MatrixState(NamedTuple):
-    """Per-document matrix state. rows/cols axes [B, S]; cells [B, C]."""
+    """Per-document matrix state: axis tables [B, S], cell planes
+    [B, HR, HC] indexed by (row handle, col handle)."""
 
     rows: mtk.MergeState
     cols: mtk.MergeState
-    cell_rh: jax.Array     # i32[B, C] row handle (-1 empty)
-    cell_ch: jax.Array     # i32[B, C] col handle
-    cell_val: jax.Array    # i32[B, C] interned value id (0 = cleared)
-    cell_seq: jax.Array    # i32[B, C] seq of the winning write
-    cell_used: jax.Array   # bool[B, C]
-    cell_count: jax.Array  # i32[B]
+    cell_val: jax.Array    # i32[B, HR, HC] interned value id (0 = unset)
+    cell_seq: jax.Array    # i32[B, HR, HC] seq of the winning write
 
 
 class MatrixOpBatch(NamedTuple):
@@ -75,17 +84,12 @@ class MatrixOpBatch(NamedTuple):
 
 
 class MatrixStepBatch(NamedTuple):
-    """One tick as STEPS: each step is (optional vector op, following
-    CELL RUN). In a sequenced matrix stream ~70% of ops are cell writes,
-    and every consecutive cell between two vector ops resolves its
-    (row, col) -> handle lookup in the SAME visibility frame whenever its
-    ref_seq covers the last structural (vector) op — a host-checkable
-    exactness condition (see make_matrix_step_batch). Batching the run
-    pays the two-axis visibility prefix scan ONCE per run instead of once
-    per cell — the dominant cost of matrix.ts:547's server-side fold.
-
-    Vector-op planes are [B, T] (T = steps); run planes are [B, T, R]
-    (R = max cells per run; longer runs split into vector-less steps)."""
+    """One tick in the step layout: the tick's vector ops [B, T] in order,
+    and its cell writes [B, N] in order, each carrying ``c_step``, the
+    number of the tick's vector ops sequenced before it (so a cell
+    resolves against the tables after exactly those ops) and its own
+    (ref, client) frame. A structural op in flight costs one scan step,
+    never the cells around it."""
 
     vec_valid: jax.Array    # bool[B, T]
     kind: jax.Array         # i32[B, T] MT_INSERT/MT_REMOVE
@@ -97,41 +101,23 @@ class MatrixStepBatch(NamedTuple):
     seq: jax.Array          # i32[B, T]
     ref_seq: jax.Array      # i32[B, T]
     client: jax.Array       # i32[B, T]
-    run_ref: jax.Array      # i32[B, T] shared frame ref of the cell run
-    run_client: jax.Array   # i32[B, T] frame client (exact for 1-cell runs)
-    r_valid: jax.Array      # bool[B, T, R]
-    r_row: jax.Array        # i32[B, T, R]
-    r_col: jax.Array        # i32[B, T, R]
-    r_value: jax.Array      # i32[B, T, R]
-    r_seq: jax.Array        # i32[B, T, R]
+    c_valid: jax.Array      # bool[B, N]
+    c_step: jax.Array       # i32[B, N] vector ops of the tick before it
+    c_row: jax.Array        # i32[B, N]
+    c_col: jax.Array        # i32[B, N]
+    c_value: jax.Array      # i32[B, N]
+    c_seq: jax.Array        # i32[B, N]
+    c_ref: jax.Array        # i32[B, N]
+    c_client: jax.Array     # i32[B, N]
 
 
-class CellRunBatch(NamedTuple):
-    """One tick that is ALL cell writes, one run per document — the
-    BASELINE config-4 storm shape (a settled grid, hundreds of writers,
-    no structural ops in flight). The whole run shares one visibility
-    frame per document: with every vector segment on the device acked at
-    or below ``ref_seq``, handle resolution is client-independent, so a
-    single (ref, client) pair serves all R cells — the host admits this
-    path only when ``last vector seq <= min ref of the run`` (the same
-    exactness condition the step/run layout checks per step).
-
-    The apply is scan-free (see apply_cell_run): resolve all R handles
-    in one [R, S] masked lookup per axis, then append the run to the
-    cell log with a rotate-into-place update — no dedup at all.
-    Duplicate keys (within a tick or across ticks) coexist in the log
-    carrying their seqs; log order is sequenced order, so
-    materialization's fold takes the latest and converged state is
-    unchanged. The log costs one slot per valid cell per tick and is
-    drained by the host at its flush/harvest cadence."""
-
-    valid: jax.Array    # bool[B, R]
-    row: jax.Array      # i32[B, R]
-    col: jax.Array      # i32[B, R]
-    value: jax.Array    # i32[B, R]
-    seq: jax.Array      # i32[B, R]
-    ref_seq: jax.Array  # i32[B] shared frame
-    client: jax.Array   # i32[B]
+_VEC_FIELDS = ("kind", "target", "pos", "end", "count", "handle_base",
+               "seq", "ref_seq", "client")
+_CELL_FIELDS = {"c_row": "row", "c_col": "col", "c_value": "value",
+                "c_seq": "seq", "c_ref": "ref_seq", "c_client": "client"}
+#: Axis-table planes a cell's handle lookup reads.
+_FRAME = ("valid", "length", "ins_seq", "ins_client", "rem_seq",
+          "rem_client", "pool_start")
 
 
 class _VecOp(NamedTuple):
@@ -150,331 +136,187 @@ class _VecOp(NamedTuple):
     prop_val: jax.Array
 
 
-def init_state(num_docs: int, vec_slots: int = 64, cell_slots: int = 256,
+def init_state(num_docs: int, vec_slots: int = 64, row_handles: int = 64,
+               col_handles: int | None = None,
                overlap_words: int = 1) -> MatrixState:
-    b, c = num_docs, cell_slots
+    b = num_docs
+    hr = row_handles
+    hc = row_handles if col_handles is None else col_handles
+    if hr * hc >= _BIG:
+        raise ValueError(f"cell plane {hr} x {hc} overflows i32 keys")
     return MatrixState(
         rows=mtk.init_state(b, vec_slots, num_props=1,
                             overlap_words=overlap_words),
         cols=mtk.init_state(b, vec_slots, num_props=1,
                             overlap_words=overlap_words),
-        cell_rh=jnp.full((b, c), -1, I32),
-        cell_ch=jnp.full((b, c), -1, I32),
-        cell_val=jnp.zeros((b, c), I32),
-        cell_seq=jnp.zeros((b, c), I32),
-        cell_used=jnp.zeros((b, c), jnp.bool_),
-        cell_count=jnp.zeros((b,), I32),
+        cell_val=jnp.zeros((b, hr, hc), I32),
+        cell_seq=jnp.zeros((b, hr, hc), I32),
     )
 
 
-def _handle_at(s: mtk.MergeState, pos, ref_seq, client):
-    """Storage handle at visible position pos in the (refSeq, client) frame
-    (PermutationVector.handle_at / matrix adjustPosition). -1 = no handle."""
-    vis = mtk._vis_len(s, ref_seq, client)
-    cum = jnp.cumsum(vis) - vis
-    return _handle_lookup(s, vis, cum, pos)
+# -- device tick --------------------------------------------------------------
 
 
-def _vec_op(op) -> _VecOp:
-    return _VecOp(
-        valid=op.valid, kind=op.kind, pos=op.pos, end=op.end, seq=op.seq,
-        ref_seq=op.ref_seq, client=op.client, pool_start=op.handle_base,
-        text_len=op.count, prop_key=jnp.zeros_like(op.kind),
-        prop_val=jnp.zeros_like(op.kind))
-
-
-def _apply_matrix_op(s: MatrixState, op) -> MatrixState:
-    # Under vmap the op target is a traced value, so every branch of a
-    # switch would execute anyway — and the merge-tree walk is by far the
-    # dominant cost. Run ONE walk on the select-merged axis state instead
-    # of one per axis: ops touch exactly one of rows/cols/cell, so the
-    # un-targeted axis just keeps its old planes.
+def _walk(rows: mtk.MergeState, cols: mtk.MergeState, op: _VecOp):
+    """ONE merge walk on the select-merged axis, gated back per target:
+    an op touches exactly one of rows/cols, so the other keeps its
+    planes (under vmap both branches of a switch would run anyway)."""
     is_rows = op.target == MX_ROWS
     is_cols = op.target == MX_COLS
-    is_cell = op.target == MX_CELL
-
-    sel = jax.tree.map(lambda r, c: jnp.where(is_rows, r, c),
-                       s.rows, s.cols)
-    walked = mtk._apply_op(sel, _vec_op(op))
+    sel = jax.tree.map(lambda r, c: jnp.where(is_rows, r, c), rows, cols)
+    walked = mtk._apply_op(sel, op.op)
     rows = jax.tree.map(
-        lambda new, old: jnp.where(op.valid & is_rows, new, old),
-        walked, s.rows)
+        lambda new, old: jnp.where(op.op.valid & is_rows, new, old),
+        walked, rows)
     cols = jax.tree.map(
-        lambda new, old: jnp.where(op.valid & is_cols, new, old),
-        walked, s.cols)
-
-    # Cell LWW write (computed every step, masked unless this IS a cell op).
-    rh = _handle_at(s.rows, op.row, op.ref_seq, op.client)
-    ch = _handle_at(s.cols, op.col, op.ref_seq, op.client)
-    # A write whose row/col died concurrently resolves to no handle and
-    # drops — matrix.ts:547 processCore's None-handle guard.
-    write = op.valid & is_cell & (rh >= 0) & (ch >= 0)
-    match = s.cell_used & (s.cell_rh == rh) & (s.cell_ch == ch)
-    exists = jnp.any(match)
-    capacity = s.cell_used.shape[0]
-    # LAST match: entries are unique under this path alone, but the
-    # cell-run fast path appends duplicate keys across ticks in seq
-    # order — overwriting the newest keeps materialize's fold correct
-    # when the paths mix.
-    idx = jnp.where(exists,
-                    capacity - 1 - jnp.argmax(match[::-1]),
-                    jnp.minimum(s.cell_count, capacity - 1))
-
-    def upd(field, value):
-        return field.at[idx].set(jnp.where(write, value, field[idx]))
-
-    return MatrixState(
-        rows=rows, cols=cols,
-        cell_rh=upd(s.cell_rh, rh),
-        cell_ch=upd(s.cell_ch, ch),
-        cell_val=upd(s.cell_val, op.value),
-        cell_seq=upd(s.cell_seq, op.seq),
-        cell_used=upd(s.cell_used, True),
-        cell_count=s.cell_count
-        + jnp.where(write & ~exists, 1, 0).astype(I32),
-    )
+        lambda new, old: jnp.where(op.op.valid & is_cols, new, old),
+        walked, cols)
+    return rows, cols
 
 
-def _step(state: MatrixState, op):
-    return _apply_matrix_op(state, op), ()
+class _TargetedOp(NamedTuple):
+    target: jax.Array
+    op: _VecOp
 
 
-def _process_doc(state: MatrixState, ops: MatrixOpBatch):
-    final, _ = jax.lax.scan(_step, state, ops)
-    return final
+def _frame(s: mtk.MergeState) -> tuple:
+    return tuple(getattr(s, f) for f in _FRAME) + (s.rem_overlap,)
+
+
+def _resolve(hist: tuple, step, pos, ref, client):
+    """Handle at visible position ``pos[N]`` of each cell, in its own
+    (ref, client) frame, on the history table ``step[N]`` (planes
+    [T+1, S], overlap [T+1, S, W]). -1 = no handle (a concurrently
+    removed row or col: matrix.ts:547's None-handle drop)."""
+    (valid, length, ins_seq, ins_client, rem_seq, rem_client,
+     pool_start, overlap) = hist
+    num_words = overlap.shape[-1]
+    c = jnp.clip(client, 0, mtk.OVERLAP_WORD_BITS * num_words - 1)
+    # The writer's overlap word only, gathered per cell: [N, S].
+    word = jnp.take_along_axis(
+        overlap[step], (c >> 5)[:, None, None], axis=2)[..., 0]
+    bit = (word >> (c & 31)[:, None]) & 1
+    ref_, cl = ref[:, None], client[:, None]
+    ins_vis = valid[step] & ((ins_seq[step] <= ref_)
+                             | (ins_client[step] == cl))
+    rs = rem_seq[step]
+    removed = (rs != mtk.NONE_SEQ) & ((rs <= ref_)
+                                      | (rem_client[step] == cl)
+                                      | (bit == 1))
+    vis = jnp.where(ins_vis & ~removed, length[step], 0)
+    cum = jnp.cumsum(vis, axis=1) - vis
+    p = pos[:, None]
+    inside = (cum <= p) & (p < cum + vis)
+    handle = jnp.sum(jnp.where(inside, pool_start[step] + p - cum, 0),
+                     axis=1)
+    return jnp.where(jnp.any(inside, axis=1), handle, -1)
+
+
+def _doc_tick(rows: mtk.MergeState, cols: mtk.MergeState, steps):
+    """One document: the vector scan with its table history, then every
+    cell's (row handle, col handle)."""
+    zeros = jnp.zeros_like(steps.kind)
+    ops = _TargetedOp(steps.target, _VecOp(
+        valid=steps.vec_valid, kind=steps.kind, pos=steps.pos,
+        end=steps.end, seq=steps.seq, ref_seq=steps.ref_seq,
+        client=steps.client, pool_start=steps.handle_base,
+        text_len=steps.count, prop_key=zeros, prop_val=zeros))
+
+    def one(carry, op):
+        r, c = _walk(*carry, op)
+        return (r, c), (_frame(r), _frame(c))
+
+    (rows_out, cols_out), (hist_r, hist_c) = jax.lax.scan(
+        one, (rows, cols), ops)
+    hist_r = jax.tree.map(lambda h0, h: jnp.concatenate([h0[None], h]),
+                          _frame(rows), hist_r)
+    hist_c = jax.tree.map(lambda h0, h: jnp.concatenate([h0[None], h]),
+                          _frame(cols), hist_c)
+    rh = _resolve(hist_r, steps.c_step, steps.c_row, steps.c_ref,
+                  steps.c_client)
+    ch = _resolve(hist_c, steps.c_step, steps.c_col, steps.c_ref,
+                  steps.c_client)
+    return rows_out, cols_out, rh, ch
+
+
+def _write_cells(val, seq, rh, ch, steps):
+    """Scatter each document's winning writes into its planes: of several
+    writes to one cell in the tick the LAST (sequenced order) wins — a
+    stable sort by cell key keeps order within a key."""
+    b, hr, hc = val.shape
+    write = (steps.c_valid & (rh >= 0) & (ch >= 0) & (rh < hr)
+             & (ch < hc))
+    key = jnp.where(write, rh * hc + ch, _BIG)
+    sk, sv, ss = jax.lax.sort((key, steps.c_value, steps.c_seq),
+                              dimension=1, num_keys=1, is_stable=True)
+    nxt = jnp.concatenate([sk[:, 1:], jnp.full((b, 1), _BIG, I32)], axis=1)
+    win = (sk != _BIG) & (sk != nxt)
+    r = jnp.where(win, sk // hc, hr)   # a loser's row lands out of bounds
+    c = jnp.where(win, sk % hc, 0)
+    d = jnp.broadcast_to(jnp.arange(b, dtype=I32)[:, None], sk.shape)
+    return (val.at[d, r, c].set(sv, mode="drop"),
+            seq.at[d, r, c].set(ss, mode="drop"))
+
+
+def _tick(state: MatrixState, steps: MatrixStepBatch) -> MatrixState:
+    rows, cols, rh, ch = jax.vmap(_doc_tick)(state.rows, state.cols, steps)
+    val, seq = _write_cells(state.cell_val, state.cell_seq, rh, ch, steps)
+    return MatrixState(rows=rows, cols=cols, cell_val=val, cell_seq=seq)
+
+
+def steps_from_ops(ops: MatrixOpBatch) -> MatrixStepBatch:
+    """The flat per-op layout as steps (traceable): every op position is
+    a scan step whose vector op is masked to vector targets, and the cell
+    at position k reads the history after ops 0..k-1."""
+    is_cell = ops.target == MX_CELL
+    k = ops.valid.shape[1]
+    return MatrixStepBatch(
+        vec_valid=ops.valid & ~is_cell,
+        **{f: getattr(ops, f) for f in _VEC_FIELDS},
+        c_valid=ops.valid & is_cell,
+        c_step=jnp.broadcast_to(jnp.arange(k, dtype=I32), ops.valid.shape),
+        **{f: getattr(ops, src) for f, src in _CELL_FIELDS.items()})
 
 
 @jax.jit
 def apply_tick(state: MatrixState, ops: MatrixOpBatch) -> MatrixState:
-    """Apply one tick of sequenced matrix ops for every document."""
-    return jax.vmap(_process_doc)(state, ops)
-
-
-def _handle_lookup(s: mtk.MergeState, vis, cum, pos):
-    """Handle at visible position ``pos`` given a precomputed frame
-    (vis, cum) — the per-cell remainder of _handle_at once the run's
-    shared visibility scan is paid."""
-    inside = (cum <= pos) & (pos < cum + vis)
-    found = jnp.any(inside)
-    idx = jnp.argmax(inside)
-    return jnp.where(found, s.pool_start[idx] + pos - cum[idx], -1)
-
-
-def _apply_matrix_step(s: MatrixState, step) -> MatrixState:
-    """One STEP: masked vector walk, then the cell run in ONE shared
-    visibility frame (exactness argument in MatrixStepBatch's docstring;
-    stale-ref cells arrive as single-cell runs carrying their own exact
-    frame). Cells resolve on the POST-walk tables — with the per-op
-    formulation a cell following a vector op it can see resolves after
-    it, and one it cannot see is excluded by the frame either way."""
-    is_rows = step.target == MX_ROWS
-    is_cols = step.target == MX_COLS
-
-    sel = jax.tree.map(lambda r, c: jnp.where(is_rows, r, c),
-                       s.rows, s.cols)
-    walked = mtk._apply_op(sel, _VecOp(
-        valid=step.vec_valid, kind=step.kind, pos=step.pos, end=step.end,
-        seq=step.seq, ref_seq=step.ref_seq, client=step.client,
-        pool_start=step.handle_base, text_len=step.count,
-        prop_key=jnp.zeros_like(step.kind),
-        prop_val=jnp.zeros_like(step.kind)))
-    rows = jax.tree.map(
-        lambda new, old: jnp.where(step.vec_valid & is_rows, new, old),
-        walked, s.rows)
-    cols = jax.tree.map(
-        lambda new, old: jnp.where(step.vec_valid & is_cols, new, old),
-        walked, s.cols)
-
-    # ONE visibility scan per axis for the whole run.
-    vis_r = mtk._vis_len(rows, step.run_ref, step.run_client)
-    cum_r = jnp.cumsum(vis_r) - vis_r
-    vis_c = mtk._vis_len(cols, step.run_ref, step.run_client)
-    cum_c = jnp.cumsum(vis_c) - vis_c
-    capacity = s.cell_used.shape[0]
-
-    def cell_step(carry, cell):
-        cell_rh, cell_ch, cell_val, cell_seq, cell_used, cell_count = carry
-        valid, row, col, value, seq = cell
-        rh = _handle_lookup(rows, vis_r, cum_r, row)
-        ch = _handle_lookup(cols, vis_c, cum_c, col)
-        write = valid & (rh >= 0) & (ch >= 0)
-        match = cell_used & (cell_rh == rh) & (cell_ch == ch)
-        exists = jnp.any(match)
-        # LAST match, for composition with the cell-run append log (see
-        # _apply_matrix_op).
-        idx = jnp.where(exists,
-                        capacity - 1 - jnp.argmax(match[::-1]),
-                        jnp.minimum(cell_count, capacity - 1))
-
-        def upd(field, val):
-            return field.at[idx].set(jnp.where(write, val, field[idx]))
-
-        return (upd(cell_rh, rh), upd(cell_ch, ch), upd(cell_val, value),
-                upd(cell_seq, seq), upd(cell_used, True),
-                cell_count + jnp.where(write & ~exists, 1, 0).astype(I32)
-                ), ()
-
-    (cell_rh, cell_ch, cell_val, cell_seq, cell_used, cell_count), _ = \
-        jax.lax.scan(
-            cell_step,
-            (s.cell_rh, s.cell_ch, s.cell_val, s.cell_seq, s.cell_used,
-             s.cell_count),
-            (step.r_valid, step.r_row, step.r_col, step.r_value,
-             step.r_seq))
-    return MatrixState(
-        rows=rows, cols=cols, cell_rh=cell_rh, cell_ch=cell_ch,
-        cell_val=cell_val, cell_seq=cell_seq, cell_used=cell_used,
-        cell_count=cell_count)
-
-
-def _process_doc_steps(state: MatrixState, steps: MatrixStepBatch):
-    def one(s, step_slice):
-        return _apply_matrix_step(s, step_slice), ()
-
-    final, _ = jax.lax.scan(one, state, steps)
-    return final
+    """Apply one tick of sequenced matrix ops (flat layout) for every
+    document. The input state stays valid (the serving tick,
+    :func:`matrix_tick`, donates it instead)."""
+    return _tick(state, steps_from_ops(ops))
 
 
 @jax.jit
 def apply_tick_steps(state: MatrixState,
                      steps: MatrixStepBatch) -> MatrixState:
-    """Apply one tick in the step/run layout — same converged state as
+    """Apply one tick in the step layout — same converged state as
     :func:`apply_tick` on the equivalent flat stream (differentially
     pinned by tests/test_matrix_kernel.py)."""
-    return jax.vmap(_process_doc_steps)(state, steps)
+    return _tick(state, steps)
 
 
-def _resolve_run(vec: mtk.MergeState, pos, ref, client):
-    """Vectorized handle resolution for one doc's cell run: [R] positions
-    against an [S] vector table in one shared visibility frame."""
-    vis = mtk._vis_len(vec, ref, client)
-    cum = jnp.cumsum(vis) - vis
-    inside = (cum[None, :] <= pos[:, None]) & (
-        pos[:, None] < (cum + vis)[None, :])
-    handle = jnp.sum(
-        jnp.where(inside,
-                  vec.pool_start[None, :] + pos[:, None] - cum[None, :],
-                  0), axis=1)
-    return jnp.where(jnp.any(inside, axis=1), handle, -1)
-
-
-@jax.jit
-def apply_cell_run(state: MatrixState, run: CellRunBatch) -> MatrixState:
-    """Apply one all-cells tick for every document — the config-4 storm
-    fast path. Converges to the same materialized grid as apply_tick on
-    the equivalent stream.
-
-    Appends the whole [B, R] run tile to the cell log in sequenced order
-    with ONE dynamic_update_slice at a SHARED column offset
-    (``max(cell_count)``) — no dedup, no per-document dynamic indexing
-    (which XLA lowers to a serialized gather on TPU). Duplicate keys
-    coexist in the log carrying their seqs; log order is sequenced
-    order, so materialization's fold takes the latest. Cells whose
-    row/col died concurrently keep their slot with used=False
-    (matrix.ts:547's None-handle drop); documents with shorter runs
-    leave used=False padding up to the shared tile — the log costs one
-    R-wide tile per tick and is drained at the host's flush/harvest
-    cadence (capacity_margin accounts the tile, the host checks it
-    before the tick)."""
-    num_r = run.row.shape[1]
-    capacity = state.cell_used.shape[1]
-
-    rh = jax.vmap(_resolve_run)(state.rows, run.row, run.ref_seq,
-                                run.client)
-    ch = jax.vmap(_resolve_run)(state.cols, run.col, run.ref_seq,
-                                run.client)
-    write = run.valid & (rh >= 0) & (ch >= 0)
-    n_valid = jnp.sum(run.valid, axis=1).astype(I32)
-
-    start = jnp.clip(jnp.max(state.cell_count), 0, capacity - num_r)
-
-    def place(table, plane):
-        return jax.lax.dynamic_update_slice(
-            table, plane.astype(table.dtype), (jnp.int32(0), start))
-
-    return state._replace(
-        cell_rh=place(state.cell_rh, rh),
-        cell_ch=place(state.cell_ch, ch),
-        cell_val=place(state.cell_val, run.value),
-        cell_seq=place(state.cell_seq, run.seq),
-        cell_used=place(state.cell_used, write),
-        # Idle documents keep their count (an inflated count would
-        # collapse their reported margin); writers move to the shared
-        # tail, preserving every count <= next tick's shared start.
-        cell_count=jnp.where(n_valid > 0, start + n_valid,
-                             state.cell_count),
-    )
-
-
-def _compact_cells_doc(rh, ch, val, seq, used):
-    """Dedup one doc's cell log: keep the LAST entry per (rh, ch) key
-    (log order is sequenced order) and pack survivors to the front.
-    Stable 2-key sort groups duplicates preserving log order; the
-    log-shift cascade packs without gathers (as in mergetree compact)."""
-    cap = rh.shape[0]
-    iota = jnp.arange(cap)
-    big = jnp.int32(2**31 - 1)
-    k1 = jnp.where(used, rh, big)
-    k2 = jnp.where(used, ch, big)
-    s1, s2, sv, ss, su = jax.lax.sort(
-        (k1, k2, val, seq, used.astype(I32)), num_keys=2, is_stable=True)
-    last = iota == cap - 1
-    n1 = jnp.where(last, big, jnp.roll(s1, -1))
-    n2 = jnp.where(last, big, jnp.roll(s2, -1))
-    win = (su == 1) & ((s1 != n1) | (s2 != n2))
-    planes = mtk.pack_keep([s1, s2, sv, ss], win)
-    count = jnp.sum(win).astype(I32)
-    live = iota < count
-    return (jnp.where(live, planes[0], -1),
-            jnp.where(live, planes[1], -1),
-            jnp.where(live, planes[2], 0),
-            jnp.where(live, planes[3], 0),
-            live, count)
-
-
-@jax.jit
-def compact_cell_log(state: MatrixState) -> MatrixState:
-    """Fold each document's cell log to one entry per (rh, ch) — the
-    capacity-pressure compaction for the append-only cell-run path
-    (dropped duplicates are superseded writes; converged state is
-    unchanged). Also safe on the unique-keyed per-op table."""
-    rh, ch, val, seq, used, count = jax.vmap(_compact_cells_doc)(
-        state.cell_rh, state.cell_ch, state.cell_val, state.cell_seq,
-        state.cell_used)
-    return state._replace(cell_rh=rh, cell_ch=ch, cell_val=val,
-                          cell_seq=seq, cell_used=used, cell_count=count)
-
-
-def make_cell_run_batch(cells_per_doc: list[list[dict]], num_docs: int,
-                        r: int, ref_seq: list[int] | np.ndarray,
-                        client: list[int] | np.ndarray) -> CellRunBatch:
-    """Encode per-doc cell-write lists (dicts with row/col/value/seq)."""
-    fields = {name: np.zeros((num_docs, r), np.int32)
-              for name in ("row", "col", "value", "seq")}
-    valid = np.zeros((num_docs, r), np.bool_)
-    for d, cells in enumerate(cells_per_doc):
-        assert len(cells) <= r, f"run overflow: {len(cells)} > {r}"
-        for i, cell in enumerate(cells):
-            valid[d, i] = True
-            for name in fields:
-                fields[name][d, i] = cell.get(name, 0)
-    return CellRunBatch(
-        valid=jnp.asarray(valid),
-        ref_seq=jnp.asarray(np.asarray(ref_seq, np.int32)),
-        client=jnp.asarray(np.asarray(client, np.int32)),
-        **{n: jnp.asarray(v) for n, v in fields.items()})
+@functools.partial(jax.jit, donate_argnums=(0,))
+def matrix_tick(state: MatrixState, steps: MatrixStepBatch, min_seq):
+    """The serving host's tick: apply the steps, then zamboni both axis
+    tables at each document's MSN ``min_seq[B]`` (tombstones below it
+    drop, adjacent acked runs with contiguous handles coalesce — the
+    tables stay at collab-window size at a fixed shape). Returns the
+    state and each document's slot count per axis, i32[B, 2], for the
+    host's capacity check. The state is donated: the planes update in
+    place, a tick never copies them."""
+    state = _tick(state, steps)
+    rows = mtk.compact(state.rows, min_seq, coalesce=True)
+    cols = mtk.compact(state.cols, min_seq, coalesce=True)
+    return (state._replace(rows=rows, cols=cols),
+            jnp.stack([rows.count, cols.count], axis=1))
 
 
 def capacity_margin(state: MatrixState) -> dict[str, np.ndarray]:
-    """Free slots per document per table. Vector ops consume up to 2 vector
-    slots; a cell set consumes up to 1 cell slot. Overflow is silent — the
-    serving host must check and compact/grow/route-to-scalar, exactly as
-    for the merge-tree kernel."""
-    return {
-        "rows": mtk.capacity_margin(state.rows),
-        "cols": mtk.capacity_margin(state.cols),
-        "cells": np.asarray(state.cell_used.shape[1] - state.cell_count),
-    }
+    """Free vector slots per document per axis (a vector op consumes up
+    to 2; overflow is silent, so the serving host checks before the
+    tick, as it checks handles against the planes' shape)."""
+    return {"rows": mtk.capacity_margin(state.rows),
+            "cols": mtk.capacity_margin(state.cols)}
 
 
 # -- host-side encode / materialize -------------------------------------------
@@ -511,79 +353,50 @@ def make_matrix_op_batch(ops_per_doc: list[list[dict]], num_docs: int,
                          **{n: jnp.asarray(v) for n, v in fields.items()})
 
 
-def group_matrix_steps(doc_ops: list[dict], r_max: int = 8,
-                       last_vec_seq: int = 0) -> list[dict]:
-    """Group one document's sequenced kernel ops into steps.
-
-    Exactness: only vector ops mutate the axis tables, so every axis
-    segment's insert/remove seq is <= v (the last vector-op seq). A cell
-    with ref_seq >= v therefore sees EVERY axis segment and removal —
-    its visibility frame equals any other such cell's, and the run
-    shares one scan. A cell with ref_seq < v (stale concurrent ref)
-    becomes a single-cell run carrying its own exact (ref, client)
-    frame. ``last_vec_seq`` seeds v for ticks continuing a served
-    document (the host tracks it across flushes).
-    """
-    steps: list[dict] = []
-    v = last_vec_seq
-    cur: dict | None = None
+def split_steps(doc_ops: list[dict]) -> tuple[list[dict], list[dict]]:
+    """One document's sequenced kernel ops → (vector ops, cells), each
+    cell dict gaining ``step``: the vector ops sequenced before it."""
+    vec, cells = [], []
     for op in doc_ops:
-        if op["target"] != MX_CELL:
-            cur = {"vec": op, "cells": []}
-            steps.append(cur)
-            v = op["seq"]
-            continue
-        fresh = op["ref_seq"] >= v
-        if cur is None or not fresh or len(cur["cells"]) >= r_max:
-            cur = {"vec": None, "cells": []}
-            steps.append(cur)
-        cur["cells"].append(op)
-        if not fresh:
-            cur = None  # a stale-ref cell stays alone in its exact run
-    return steps
+        if op["target"] == MX_CELL:
+            cells.append(dict(op, step=len(vec)))
+        else:
+            vec.append(op)
+    return vec, cells
 
 
 def make_matrix_step_batch(ops_per_doc: list[list[dict]], num_docs: int,
-                           r_max: int = 8,
-                           last_vec_seq: list[int] | None = None
-                           ) -> MatrixStepBatch:
-    """Encode per-doc op lists into the step/run layout (padded [B, T] +
-    [B, T, R])."""
-    seeds = last_vec_seq or [0] * num_docs
-    grouped = [group_matrix_steps(doc_ops, r_max, seeds[d])
-               for d, doc_ops in enumerate(ops_per_doc)]
-    t = max((len(g) for g in grouped), default=1) or 1
-    r = max((len(s["cells"]) for g in grouped for s in g), default=1) or 1
-    vec_names = ("kind", "target", "pos", "end", "count", "handle_base",
-                 "seq", "ref_seq", "client", "run_ref", "run_client")
-    vec = {n: np.zeros((num_docs, t), np.int32) for n in vec_names}
+                           t: int | None = None,
+                           n: int | None = None) -> MatrixStepBatch:
+    """Encode per-doc op lists into the step layout, padded to ``t``
+    vector ops and ``n`` cells per document (default: the largest)."""
+    split = [split_steps(ops) for ops in ops_per_doc]
+    split += [([], [])] * (num_docs - len(split))
+    t = t or max([len(v) for v, _ in split] + [1])
+    n = n or max([len(c) for _, c in split] + [1])
+    vec = {f: np.zeros((num_docs, t), np.int32) for f in _VEC_FIELDS}
     vec_valid = np.zeros((num_docs, t), np.bool_)
-    run_names = ("r_row", "r_col", "r_value", "r_seq")
-    run = {n: np.zeros((num_docs, t, r), np.int32) for n in run_names}
-    r_valid = np.zeros((num_docs, t, r), np.bool_)
-    for d, g in enumerate(grouped):
-        for i, step in enumerate(g):
-            op = step["vec"]
-            if op is not None:
-                vec_valid[d, i] = True
-                for n in ("kind", "target", "pos", "end", "count",
-                          "handle_base", "seq", "ref_seq", "client"):
-                    vec[n][d, i] = op.get(n, 0)
-            cells = step["cells"]
-            if cells:
-                vec["run_ref"][d, i] = min(c["ref_seq"] for c in cells)
-                vec["run_client"][d, i] = cells[0]["client"]
-                for j, c in enumerate(cells):
-                    r_valid[d, i, j] = True
-                    run["r_row"][d, i, j] = c["row"]
-                    run["r_col"][d, i, j] = c["col"]
-                    run["r_value"][d, i, j] = c["value"]
-                    run["r_seq"][d, i, j] = c["seq"]
+    cell = {f: np.zeros((num_docs, n), np.int32)
+            for f in ("c_step", *_CELL_FIELDS)}
+    c_valid = np.zeros((num_docs, n), np.bool_)
+    for d, (vops, cops) in enumerate(split):
+        if len(vops) > t or len(cops) > n:
+            raise ValueError(f"doc {d}: {len(vops)} vector ops / "
+                             f"{len(cops)} cells exceed the tick ({t}, {n})")
+        for i, op in enumerate(vops):
+            vec_valid[d, i] = True
+            for f in _VEC_FIELDS:
+                vec[f][d, i] = op.get(f, 0)
+        for i, op in enumerate(cops):
+            c_valid[d, i] = True
+            cell["c_step"][d, i] = op["step"]
+            for f, src in _CELL_FIELDS.items():
+                cell[f][d, i] = op.get(src, 0)
     return MatrixStepBatch(
         vec_valid=jnp.asarray(vec_valid),
-        **{n: jnp.asarray(a) for n, a in vec.items()},
-        r_valid=jnp.asarray(r_valid),
-        **{n: jnp.asarray(a) for n, a in run.items()})
+        **{f: jnp.asarray(a) for f, a in vec.items()},
+        c_valid=jnp.asarray(c_valid),
+        **{f: jnp.asarray(a) for f, a in cell.items()})
 
 
 def encode_matrix_op(channel_op: dict, base: dict, alloc_rows, alloc_cols,
@@ -662,15 +475,12 @@ def _axis_handles(s: mtk.MergeState, doc: int) -> list[int]:
 
 
 def materialize_grid(state: MatrixState, doc: int,
-                     val_rev: list) -> list[list]:
+                     val_rev) -> list[list]:
     """Converged dense grid of one document (None = unset cell)."""
-    row_handles = _axis_handles(state.rows, doc)
-    col_handles = _axis_handles(state.cols, doc)
-    used = np.asarray(state.cell_used[doc])
-    rh = np.asarray(state.cell_rh[doc])
-    ch = np.asarray(state.cell_ch[doc])
-    val = np.asarray(state.cell_val[doc])
-    cells = {(int(rh[i]), int(ch[i])): int(val[i])
-             for i in range(used.shape[0]) if used[i]}
-    return [[val_rev[cells[(r, c)]] if (r, c) in cells else None
-             for c in col_handles] for r in row_handles]
+    rows = _axis_handles(state.rows, doc)
+    cols = _axis_handles(state.cols, doc)
+    if not rows or not cols:
+        return [[] for _ in rows]
+    val = np.asarray(state.cell_val[doc])[np.ix_(rows, cols)]
+    return [[None if v == 0 else val_rev[v] for v in line]
+            for line in val.tolist()]

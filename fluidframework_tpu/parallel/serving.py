@@ -163,7 +163,7 @@ class ShardedServing:
                  durable_retention_ticks: int = 1024,
                  text_slots: int = 0, text_k: int = 0, text_props: int = 4,
                  text_locality: float = 0.0,
-                 matrix_vec_slots: int = 0, matrix_cell_slots: int = 0,
+                 matrix_vec_slots: int = 0, matrix_handles: int = 0,
                  matrix_k: int = 0,
                  tree_slots: int = 0, tree_k: int = 0,
                  pipeline_depth: int = 0) -> None:
@@ -216,11 +216,14 @@ class ShardedServing:
         self.rebalance_stats = {"ticks": 0, "fired": 0,
                                 "blocks_touched": 0}
         self.matrix_vec_slots = matrix_vec_slots
-        self.matrix_cell_slots = matrix_cell_slots
+        # Handle capacity of each axis: the cell planes are
+        # [matrix_handles, matrix_handles] per doc (one handle counter
+        # per doc serves both axes, so either axis may use all of it).
+        self.matrix_handles = matrix_handles
         self.matrix_k = matrix_k or (k if matrix_vec_slots else 0)
         self.matrix_state = lift(mxk.init_state(
-            b_local, matrix_vec_slots, matrix_cell_slots,
-            overlap_words)) if matrix_vec_slots else None
+            b_local, matrix_vec_slots, matrix_handles,
+            overlap_words=overlap_words)) if matrix_vec_slots else None
         self.tree_slots = tree_slots
         self.tree_k = tree_k or (k if tree_slots else 0)
         self.tree_state = lift(tk.init_state(
@@ -229,14 +232,14 @@ class ShardedServing:
         # Host-side text pools + capacity high-water marks for OWNED rows
         # (device overflow is silent by kernel contract, so admission
         # checks worst-case growth BEFORE the tick: 2 slots per text op,
-        # 2 vector slots + 1 cell slot per matrix op).
+        # 2 vector slots per matrix vector op, handles within the planes).
         local_rows = range(self.local_lo, self.local_hi)
         self.text_pool = ({row: "" for row in local_rows}
                           if text_slots else {})
         self._text_high = ({row: 0 for row in local_rows}
                            if text_slots else {})
-        self._mx_high = ({row: [0, 0, 0] for row in local_rows}
-                         if matrix_vec_slots else {})  # [rows, cols, cells]
+        self._mx_high = ({row: [0, 0] for row in local_rows}
+                         if matrix_vec_slots else {})  # [rows, cols]
         # ONE handle counter per doc SHARED by both axes (the
         # deterministic in-sequence-order rule of dds/matrix.py that
         # mxk.HandleAllocator mirrors).
@@ -347,16 +350,13 @@ class ShardedServing:
                                             int(tops.max()))
             n_row = int(np.sum((planes["target"] == mxk.MX_ROWS)[:sub.count]))
             n_col = int(np.sum((planes["target"] == mxk.MX_COLS)[:sub.count]))
-            n_cell = sub.count - n_row - n_col
-            grown = [high[0] + 2 * n_row, high[1] + 2 * n_col,
-                     high[2] + n_cell]
-            if (grown[0] > self.matrix_vec_slots
-                    or grown[1] > self.matrix_vec_slots
-                    or grown[2] > self.matrix_cell_slots):
+            grown = [high[0] + 2 * n_row, high[1] + 2 * n_col]
+            if (max(grown) > self.matrix_vec_slots
+                    or self._mx_handles[row] > self.matrix_handles):
                 raise ValueError(
-                    f"row {row}: matrix capacity exceeded {grown} vs "
-                    f"({self.matrix_vec_slots}, {self.matrix_vec_slots}, "
-                    f"{self.matrix_cell_slots})")
+                    f"row {row}: matrix capacity exceeded: slots {grown}, "
+                    f"handles {self._mx_handles[row]} vs "
+                    f"({self.matrix_vec_slots}, {self.matrix_handles})")
             self._mx_high[row] = grown
         pending[row] = sub
 
@@ -881,8 +881,7 @@ class ShardedServing:
                 self._mx_handles[row] = max(tops)
                 self._mx_high[row] = [
                     int(np.asarray(mx.rows.count[offset])),
-                    int(np.asarray(mx.cols.count[offset])),
-                    int(np.asarray(mx.cell_count[offset]))]
+                    int(np.asarray(mx.cols.count[offset]))]
         if "tree" in states:
             self.tree_state = write(self.tree_state, states["tree"])
         for row, pool in checkpoint.get("text_pool", {}).items():
@@ -1270,7 +1269,8 @@ class ShardResidency:
                     1, *s.text_geometry, s.text_props, overlap)
             if s.matrix_state is not None:
                 states["matrix"] = mxk.init_state(
-                    1, s.matrix_vec_slots, s.matrix_cell_slots, overlap)
+                    1, s.matrix_vec_slots, s.matrix_handles,
+                    overlap_words=overlap)
             if s.tree_state is not None:
                 states["tree"] = tk.init_state(1, s.tree_slots)
             self._blank1 = (s.text_geometry,
@@ -1293,7 +1293,7 @@ class ShardResidency:
             s.text_pool[row] = ""
             s._text_high[row] = 0
         if row in s._mx_high:
-            s._mx_high[row] = [0, 0, 0]
+            s._mx_high[row] = [0, 0]
             s._mx_handles[row] = 0
 
     def evict_idle(self, keep_per_host: int) -> list[str]:

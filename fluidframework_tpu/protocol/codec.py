@@ -43,8 +43,21 @@ def register_codec(tag: str, cls: type, to_fn, from_fn) -> None:
     _EXT_BY_TAG[tag] = from_fn
 
 
+#: Leaf types that encode as themselves (exact types: an IntEnum or a
+#: dict or list subclass takes the checks below).
+_LEAVES = frozenset((str, int, float, bool, type(None)))
+
+
 def to_wire(obj: Any) -> Any:
     """Recursively convert protocol objects into JSON-able structures."""
+    t = type(obj)
+    if t in _LEAVES:
+        return obj
+    if t is dict:
+        return {k: v if type(v) in _LEAVES else to_wire(v)
+                for k, v in obj.items()}
+    if t is list or t is tuple:
+        return [v if type(v) in _LEAVES else to_wire(v) for v in obj]
     if isinstance(obj, SequencedDocumentMessage):
         return {"_t": "seq", "client_id": obj.client_id,
                 "sequence_number": obj.sequence_number,
@@ -87,6 +100,8 @@ def to_wire(obj: Any) -> Any:
 
 
 def from_wire(obj: Any) -> Any:
+    if type(obj) in _LEAVES:
+        return obj
     if isinstance(obj, dict):
         tag = obj.get("_t")
         if tag == "seq":
@@ -127,9 +142,10 @@ def from_wire(obj: Any) -> Any:
         if tag in _EXT_BY_TAG:
             body = {k: from_wire(v) for k, v in obj.items() if k != "_t"}
             return _EXT_BY_TAG[tag](body)
-        return {k: from_wire(v) for k, v in obj.items()}
+        return {k: v if type(v) in _LEAVES else from_wire(v)
+                for k, v in obj.items()}
     if isinstance(obj, list):
-        return [from_wire(v) for v in obj]
+        return [v if type(v) in _LEAVES else from_wire(v) for v in obj]
     return obj
 
 

@@ -24,6 +24,7 @@ from typing import Any
 from ..native.bridge import EV_CLOSE, EV_DATA, EV_OPEN, start_bridge
 from ..protocol.codec import decode_body, encode_push, is_storm_body
 from ..utils import MetricsRegistry, NullLogger, TelemetryLogger
+from ..utils.metrics import PEROP_STAGES, StageLedger, maybe_stage
 from .alfred import RequestSession
 
 
@@ -92,6 +93,19 @@ class BridgeFrontDoor:
         self._bridge = bridge
         self.port = bridge.port
         self._sessions: dict[int, _BridgeSession] = {}
+        # A service in batched cadence (auto_pump=False) with no storm
+        # controller is served per op in rounds: this thread drains the
+        # poll pass, then pumps the service once (see _perop_round).
+        self._perop = (not getattr(service, "_auto_pump", True)
+                       and getattr(service, "storm", None) is None)
+        self._ledger = None
+        self._rounds = 0
+        self._round_ops = 0
+        self._round_docs: set = set()
+        if self._perop:
+            self._ledger = service.ledger = StageLedger(
+                PEROP_STAGES, registry=service.metrics,
+                prefix="perop.stage")
         self._stop = threading.Event()
         self._thread = threading.Thread(target=self._pump_loop, daemon=True)
         self._thread.start()
@@ -104,6 +118,12 @@ class BridgeFrontDoor:
         # stages of the storm ledger (frame_wait, idle_service), so a
         # tick's wall clock is accounted for between two harvests.
         while not self._stop.is_set():
+            if self._perop:
+                try:
+                    self._perop_round()
+                except Exception as err:  # the pump must never die
+                    self.logger.send_error("BridgePumpFailed", err)
+                continue
             storm = getattr(self.service, "storm", None)
             ledger = storm.ledger if storm is not None else None
             # Blocking poll (cv in the C++ side): no busy-wait, and the
@@ -124,6 +144,32 @@ class BridgeFrontDoor:
                 self._dispatch(*event)
             except Exception as err:  # the pump must never die
                 self.logger.send_error("BridgePumpFailed", err)
+
+    def _perop_round(self) -> None:
+        """One per-op serving round: wait for a request, decode and submit
+        every request ready in this poll pass, then ONE service pump —
+        one device deli tick and one merge-host flush over every
+        document's ops, one broadcast batch per document — and one
+        ``perop.stage`` ledger record."""
+        ledger = self._ledger
+        with ledger.stage("frame_wait"):
+            event = self._bridge.poll(wait_ms=50)
+        if event is None:
+            self._idle_pass(None)
+            return
+        while event is not None:
+            try:
+                self._dispatch(*event)
+            except Exception as err:
+                self.logger.send_error("BridgePumpFailed", err)
+            event = self._bridge.poll()
+        self.service.pump()
+        self._rounds += 1
+        ledger.record(self._rounds, queue_depth=0,
+                      batch_docs=len(self._round_docs),
+                      batch_ops=self._round_ops, wall_ns=None)
+        self._round_ops = 0
+        self._round_docs.clear()
 
     def _idle_pass(self, storm) -> None:
         # Idle: non-blocking storm service — drain acks whose group
@@ -202,21 +248,34 @@ class BridgeFrontDoor:
         session = self._sessions.get(conn_id)
         if session is None:
             return
-        try:
-            req: Any = decode_body(body)
-        except Exception:
+        decode = maybe_stage(self._ledger, "ingress_decode")
+        refused = False
+        with decode:
+            try:
+                req: Any = decode_body(body)
+            except Exception:
+                refused = True
+        if refused:
+            # A body the codec refused serves no round: its time goes.
+            if self._ledger is not None:
+                self._ledger.refund(decode)
             self._bridge.close_conn(conn_id)
             return
-        if not isinstance(req, dict):
-            session.push({"rid": None, "error": "request must be an object"})
-            return
-        try:
-            resp = session.handle_request(req)
-        except Exception as err:  # keep the socket alive, report
-            self.logger.send_error("BridgeRequestFailed", err,
-                                   op=req.get("op"))
-            resp = {"rid": req.get("rid"), "error": repr(err)}
-        session.push(resp)
+        with maybe_stage(self._ledger, "submit"):
+            if not isinstance(req, dict):
+                session.push({"rid": None,
+                              "error": "request must be an object"})
+                return
+            try:
+                resp = session.handle_request(req)
+            except Exception as err:  # keep the socket alive, report
+                self.logger.send_error("BridgeRequestFailed", err,
+                                       op=req.get("op"))
+                resp = {"rid": req.get("rid"), "error": repr(err)}
+            if self._perop and req.get("op") == "submit":
+                self._round_ops += len(req.get("messages", ()))
+                self._round_docs.add(session.doc_id)
+            session.push(resp)
 
     def _handle_storm(self, conn_id: int, body: bytes, t_rx: int) -> None:
         session = self._sessions.get(conn_id)

@@ -591,7 +591,11 @@ class FileStateStore:
     """Journaled key→document store (same duck-typed surface as the
     in-memory StateStore). Every put/append is one journal record; open
     replays the journal into memory. ``compact()`` rewrites the journal as
-    one snapshot record per key (the Mongo-compaction analog)."""
+    one snapshot record per key (the Mongo-compaction analog). It runs
+    when the records a later put superseded outnumber the live ones (and
+    ``COMPACT_THRESHOLD``), so the journal stays within about twice what
+    it holds and each rewrite is paid for by as many superseded records:
+    an append-only key (an op log) never forces one."""
 
     COMPACT_THRESHOLD = 8192
 
@@ -602,12 +606,16 @@ class FileStateStore:
         self._journal = OpLog(self._path)
         self._dirty = False  # appends since the last sync (group commit)
         self._data: dict[str, Any] = {}
+        # Journal records per key, and those a later put superseded.
+        self._key_records: dict[str, int] = {}
+        self._garbage = 0
         for i in range(len(self._journal)):
             kind, key, value = _load(self._journal.read(i))
             if kind == "put":
                 self._data[key] = value
             else:
                 self._data.setdefault(key, []).extend(value)
+            self._count(kind, key)
         self._records = len(self._journal)
 
     def get(self, key: str, default: Any = None) -> Any:
@@ -621,18 +629,27 @@ class FileStateStore:
         # bugs between first run and recovery. One serialization pass: the
         # journal bytes are the source.
         self._data[key] = _load(data)[2]
-        self._bump()
+        self._bump("put", key)
 
     def append(self, key: str, items: list) -> None:
         data = _dump(["append", key, items])
         self._journal.append(data)
         self._data.setdefault(key, []).extend(_load(data)[2])
-        self._bump()
+        self._bump("append", key)
 
-    def _bump(self) -> None:
+    def _count(self, kind: str, key: str) -> None:
+        held = self._key_records.get(key, 0)
+        if kind == "put":
+            self._garbage += held
+            held = 0
+        self._key_records[key] = held + 1
+
+    def _bump(self, kind: str, key: str) -> None:
         self._records += 1
         self._dirty = True
-        if self._records > max(self.COMPACT_THRESHOLD, 8 * len(self._data)):
+        self._count(kind, key)
+        if self._garbage > max(self.COMPACT_THRESHOLD,
+                               self._records - self._garbage):
             self.compact()
 
     def keys(self, prefix: str = "") -> list[str]:
@@ -657,6 +674,8 @@ class FileStateStore:
         tmp.replace(self._path)
         self._journal = OpLog(self._path)
         self._records = len(self._journal)
+        self._key_records = dict.fromkeys(self._data, 1)
+        self._garbage = 0
         self._dirty = False  # the compacted journal was synced pre-publish
 
     def close(self) -> None:

@@ -122,6 +122,8 @@ class KernelSequencerHost:
         # calls must not each pay a device readback (10k docs would turn
         # one pump into 10k round trips).
         self._host_state = None
+        #: Device sequencer ticks run so far (one per flush with work).
+        self.device_ticks = 0
 
     @property
     def _ghost(self) -> int:
@@ -361,8 +363,9 @@ class KernelSequencerHost:
             fresh_by_doc[doc_id] = fresh
             max_k = max(max_k, len(per_doc_ops[row]))
         ops = seqk.make_op_batch(per_doc_ops, self._capacity,
-                                 _next_pow2(max_k))
+                                 _tick_k(max_k))
         self._state, out = seqp.process_batch_best(self._state, ops)
+        self.device_ticks += 1
         self._host_state = None
         # One transfer for the whole tick: the per-op decode below
         # must index HOST arrays, not a device buffer (each device
@@ -374,6 +377,18 @@ class KernelSequencerHost:
                 row, self._pending[row], per_doc_ops[row], out, row,
                 fresh_by_doc[doc_id]))
             self._pending[row] = []
+
+    def warm_ticks(self, max_ops: int) -> None:
+        """Compile the tick of every width a flush of up to ``max_ops``
+        ops per document can take (an all-padding batch; the state is
+        left as it was), so that serving never compiles one."""
+        k = _tick_k(1)
+        while True:
+            ops = seqk.make_op_batch([], self._capacity, k)
+            jax.block_until_ready(seqp.process_batch_best(self._state, ops))
+            if k >= _tick_k(max_ops):
+                return
+            k *= 2
 
     # -- idle ejection (deli checkIdleClients) ---------------------------------
 

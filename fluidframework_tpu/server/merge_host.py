@@ -52,7 +52,6 @@ from ..dds.mergetree import Marker, MergeEngine, Segment
 from ..dds.tree_core import ROOT_ID, VALID, Transaction, TreeSnapshot
 from ..ops import map_kernel as mk
 from ..ops import matrix_kernel as mxk
-from ..ops import matrix_pallas as mxp
 from ..ops import mergetree_blocks as mtb
 from ..ops import mergetree_blocks_pallas as mtbp
 from ..ops import mergetree_kernel as mtk
@@ -137,8 +136,7 @@ class _MapRow:
 class _MatrixRow:
     __slots__ = ("row", "client_slots", "pending", "raw_log", "scalar",
                  "last_seq", "min_seq", "next_row_handle",
-                 "next_col_handle", "applied_seq", "applied_min_seq",
-                 "last_vec_seq")
+                 "next_col_handle", "applied_seq", "applied_min_seq")
 
     def __init__(self, row: int) -> None:
         self.row = row
@@ -155,9 +153,6 @@ class _MatrixRow:
         self.applied_min_seq = 0
         self.next_row_handle = 0
         self.next_col_handle = 0
-        # Seq of the newest structural (vector) op — the cell-run fast
-        # path is exact only when every cell's refSeq covers it.
-        self.last_vec_seq = 0
 
 
 class _TreeRow:
@@ -577,7 +572,9 @@ class KernelMergeHost:
                  tree_slots: int = 32,
                  max_client_slots: int = 1024,
                  megadoc_writer_threshold: int | None = None,
-                 megadoc_demote_idle_flushes: int = 64) -> None:
+                 megadoc_demote_idle_flushes: int = 64,
+                 matrix_vec_slots: int = 64, matrix_handles: int = 64,
+                 matrix_writers: int = 32) -> None:
         from ..utils import MetricsRegistry
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         # Sequence-parallel escape hatch: documents whose segment tables
@@ -636,9 +633,13 @@ class KernelMergeHost:
         self._xstate = mk.init_state(self._map_capacity, self._map_slots)
         self._matrix_state: mxk.MatrixState | None = None
         self._matrix_capacity = max(1, row_capacity)
-        self._matrix_vec_slots = 64
-        self._matrix_cell_slots = 256
-        self._matrix_overlap_words = 1
+        # Matrix sizes (all grow on demand, each growth a new program):
+        # vector slots per axis, the (row, col) handle capacity of the
+        # cell planes, and the writers the overlap planes track.
+        self._matrix_vec_slots = max(8, matrix_vec_slots)
+        self._matrix_handles = (max(8, matrix_handles),) * 2
+        self._matrix_overlap_words = mtk.overlap_words_for(matrix_writers)
+        self._matrix_counts = np.zeros((0, 2), np.int64)
         self._matrix_rows: dict[ChannelKey, _MatrixRow] = {}
 
         # Tree channels share one pooled TreeState [B, N] (uniform slot
@@ -1094,12 +1095,14 @@ class KernelMergeHost:
             # Scalar-served: no device state to rebuild later, no log.
             self._matrix_scalar_apply(row, channel_op, seq, ref_seq, client)
             self.stats["scalar_ops"] += 1
+            self.metrics.counter("matrix.ops_scalar").inc()
             return
         row.raw_log.append((channel_op, seq, ref_seq, client))
         if (client not in row.client_slots
                 and len(row.client_slots) >= self.max_client_slots):
             self._route_matrix_to_scalar(row)
             self.stats["scalar_ops"] += 1
+            self.metrics.counter("matrix.ops_scalar").inc()
             return
         slot = row.client_slots.setdefault(client, len(row.client_slots))
         if slot >= mtk.OVERLAP_WORD_BITS * self._matrix_overlap_words:
@@ -1117,9 +1120,6 @@ class KernelMergeHost:
             alloc("next_row_handle"), alloc("next_col_handle"),
             self._intern)
         row.pending.extend(encoded)
-        for enc in encoded:
-            if enc["target"] != mxk.MX_CELL:
-                row.last_vec_seq = max(row.last_vec_seq, enc["seq"])
         self._pending_ops += len(encoded)
 
     def _seed_matrix_scalar(self, row: _MatrixRow) -> tuple:
@@ -1163,15 +1163,10 @@ class KernelMergeHost:
                 ))
             return vec
 
-        cells: dict[tuple[int, int], Any] = {}
-        used = np.asarray(s.cell_used[row.row])
-        cell_rh = np.asarray(s.cell_rh[row.row])
-        cell_ch = np.asarray(s.cell_ch[row.row])
-        cell_val = np.asarray(s.cell_val[row.row])
-        for c in range(used.shape[0]):
-            if used[c]:
-                cells[(int(cell_rh[c]), int(cell_ch[c]))] = \
-                    self._val_rev[int(cell_val[c])]
+        val = np.asarray(s.cell_val[row.row])
+        cells: dict[tuple[int, int], Any] = {
+            (rh, ch): self._val_rev[val[rh, ch]]
+            for rh, ch in zip(*(a.tolist() for a in np.nonzero(val)))}
         return (seed_vec(s.rows, row.next_row_handle),
                 seed_vec(s.cols, row.next_col_handle), cells)
 
@@ -1219,14 +1214,22 @@ class KernelMergeHost:
 
         return s._replace(
             rows=blank_merge(s.rows), cols=blank_merge(s.cols),
-            cell_used=s.cell_used.at[row].set(False),
-            cell_count=s.cell_count.at[row].set(0))
+            cell_val=s.cell_val.at[row].set(0),
+            cell_seq=s.cell_seq.at[row].set(0))
 
     def _ensure_matrix_state(self) -> None:
         if self._matrix_state is None:
             self._matrix_state = mxk.init_state(
                 self._matrix_capacity, self._matrix_vec_slots,
-                self._matrix_cell_slots, self._matrix_overlap_words)
+                *self._matrix_handles, self._matrix_overlap_words)
+            self._matrix_counts = np.zeros((self._matrix_capacity, 2),
+                                           np.int64)
+
+    def _matrix_grew(self, state: mxk.MatrixState | None = None) -> None:
+        """A matrix plane changed shape: the next tick compiles anew."""
+        if state is not None:
+            self._matrix_state = jax.device_put(state)
+        self.metrics.counter("matrix.state_grows").inc()
 
     def _grow_matrix_overlap(self, need_words: int) -> None:
         """Widen the remover-bitmask planes of both permutation vectors
@@ -1236,26 +1239,28 @@ class KernelMergeHost:
         if new == self._matrix_overlap_words:
             return
         extra = new - self._matrix_overlap_words
+        self._matrix_overlap_words = new
         if self._matrix_state is not None:
             def pad_ov(ms: mtk.MergeState) -> mtk.MergeState:
                 return ms._replace(rem_overlap=jnp.asarray(
                     _pad_axis(ms.rem_overlap, 2, extra, 0)))
-            self._matrix_state = self._matrix_state._replace(
-                rows=pad_ov(self._matrix_state.rows),
-                cols=pad_ov(self._matrix_state.cols))
-        self._matrix_overlap_words = new
+            s = self._matrix_state
+            self._matrix_grew(s._replace(rows=pad_ov(s.rows),
+                                         cols=pad_ov(s.cols)))
 
     def _grow_matrix_rows(self) -> None:
         old = self._matrix_capacity
         self._matrix_capacity = old * 2
         if self._matrix_state is not None:
-            self._matrix_state = jax.device_put(
-                self._pad_matrix_state(self._matrix_state, rows_extra=old))
+            self._matrix_counts = np.pad(self._matrix_counts,
+                                         ((0, old), (0, 0)))
+            self._matrix_grew(self._pad_matrix_state(rows_extra=old))
 
-    @staticmethod
-    def _pad_matrix_state(s: mxk.MatrixState, rows_extra: int = 0,
-                          vec_extra: int = 0,
-                          cell_extra: int = 0) -> mxk.MatrixState:
+    def _pad_matrix_state(self, rows_extra: int = 0, vec_extra: int = 0,
+                          rh_extra: int = 0,
+                          ch_extra: int = 0) -> mxk.MatrixState:
+        s = self._matrix_state
+
         def pad_merge(ms: mtk.MergeState) -> mtk.MergeState:
             out = {}
             for f in mtk.MergeState._fields:
@@ -1265,118 +1270,100 @@ class KernelMergeHost:
                 out[f] = a
             return mtk.MergeState(**out)
 
-        cell_fill = dict(cell_rh=-1, cell_ch=-1, cell_val=0, cell_seq=0,
-                         cell_used=False)
-        cells = {}
-        for f, fill in cell_fill.items():
-            a = _pad_axis(getattr(s, f), 0, rows_extra, fill)
-            if cell_extra:
-                a = _pad_axis(a, 1, cell_extra, fill)
-            cells[f] = a
+        def pad_plane(a):
+            return np.pad(np.asarray(a),
+                          ((0, rows_extra), (0, rh_extra), (0, ch_extra)))
+
         return mxk.MatrixState(
             rows=pad_merge(s.rows), cols=pad_merge(s.cols),
-            cell_count=_pad_axis(s.cell_count, 0, rows_extra, 0), **cells)
+            cell_val=pad_plane(s.cell_val), cell_seq=pad_plane(s.cell_seq))
 
-    def _matrix_vec_shortfall(self, rows: list[_MatrixRow]
-                              ) -> tuple[int, int]:
-        """(vec_extra, cell_extra) pow2 growth needed for the dirty rows
-        (each vector op can consume 2 slots; each cell op 1 cell slot)."""
-        margins = mxk.capacity_margin(self._matrix_state)
-        vec_extra = cell_extra = 0
-        for r in rows:
-            vec_need = 2 * len(r.pending) + 2
-            cell_need = len(r.pending) + 1
-            worst_vec = min(int(margins["rows"][r.row]),
-                            int(margins["cols"][r.row]))
-            if vec_need > worst_vec:
-                vec_extra = max(vec_extra,
-                                _next_pow2(vec_need - worst_vec))
-            cell_margin = int(margins["cells"][r.row])
-            if cell_need > cell_margin:
-                cell_extra = max(cell_extra,
-                                 _next_pow2(cell_need - cell_margin))
-        return vec_extra, cell_extra
+    def _fit_matrix(self, rows: list[_MatrixRow], per_doc: list) -> None:
+        """Grow what the tick would overflow, BEFORE it runs (device
+        overflow is silent): the cell planes past the handles the rows
+        allocated, the vector tables past 2 slots per vector op. Each
+        growth is a new program (``matrix.state_grows``); a deployment
+        sizes both so that none happens while it serves."""
+        hr, hc = self._matrix_handles
+        new_r = _next_pow2_width(hr, max(r.next_row_handle for r in rows))
+        new_c = _next_pow2_width(hc, max(r.next_col_handle for r in rows))
+        slots = self._matrix_vec_slots
+        need_v = max(int(self._matrix_counts[r.row].max()) + 2 + 2 * sum(
+            op["target"] != mxk.MX_CELL for op in per_doc[r.row])
+            for r in rows)
+        new_v = _next_pow2_width(slots, need_v)
+        if (new_r, new_c, new_v) != (hr, hc, slots):
+            self._matrix_grew(self._pad_matrix_state(
+                vec_extra=new_v - slots, rh_extra=new_r - hr,
+                ch_extra=new_c - hc))
+            self._matrix_handles = (new_r, new_c)
+            self._matrix_vec_slots = new_v
+
+    #: Every (vector ops, cells) shape of a matrix tick: two programs to
+    #: compile (padding a tick costs the device little); a flush splits
+    #: a round with more cells or vector ops into several ticks.
+    MATRIX_TICK_SHAPES = ((8, 128), (64, 1024))
+
+    def warm_matrix_ticks(self) -> None:
+        """Compile every matrix tick shape now (an all-padding tick
+        changes nothing): serving then never compiles one."""
+        self._ensure_matrix_state()
+        for t, n in self.MATRIX_TICK_SHAPES:
+            self._run_matrix_tick([[] for _ in range(self._matrix_capacity)],
+                                  t, n)
+
+    def _run_matrix_tick(self, per_doc: list, t: int, n: int) -> None:
+        min_seq = np.full(self._matrix_capacity, -1, np.int32)
+        for r in self._matrix_rows.values():
+            # The MSN as of the last APPLIED op: every op still to apply
+            # was sequenced after it, so its ref is at or above it.
+            min_seq[r.row] = r.applied_min_seq
+        steps = mxk.make_matrix_step_batch(per_doc, self._matrix_capacity,
+                                           t, n)
+        self._matrix_state, counts = mxk.matrix_tick(
+            self._matrix_state, steps, jnp.asarray(min_seq))
+        self._matrix_counts = np.asarray(counts)
+        self.metrics.counter("matrix.step_ticks").inc()
 
     def _flush_matrix(self) -> None:
         rows = [r for r in self._matrix_rows.values() if r.pending]
         if not rows:
             return
         self._ensure_matrix_state()
-        vec_extra, cell_extra = self._matrix_vec_shortfall(rows)
-        if cell_extra:
-            # Dedup the cell append log before paying for growth on ANY
-            # path — after cell-run storms it is mostly superseded
-            # duplicates (the per-op fallback would otherwise ratchet
-            # device memory that one compaction frees).
-            self._matrix_state = mxk.compact_cell_log(self._matrix_state)
-            self.stats["compactions"] += 1
-            vec_extra, cell_extra = self._matrix_vec_shortfall(rows)
-        if vec_extra:
-            # Zamboni the permutation vectors before paying for growth —
-            # tombstoned row/col segments below the window pack away (at
-            # the applied frontier's MSN: the pending ops apply after).
-            min_seq = np.full(self._matrix_capacity, -1, np.int32)
-            for r in self._matrix_rows.values():
-                min_seq[r.row] = r.applied_min_seq
-            ms = jnp.asarray(min_seq)
-            self._matrix_state = self._matrix_state._replace(
-                rows=mtk.compact(self._matrix_state.rows, ms),
-                cols=mtk.compact(self._matrix_state.cols, ms))
-            self.stats["compactions"] += 1
-            vec_extra, cell_extra = self._matrix_vec_shortfall(rows)
-        if vec_extra or cell_extra:
-            self._matrix_state = jax.device_put(self._pad_matrix_state(
-                self._matrix_state, vec_extra=vec_extra,
-                cell_extra=cell_extra))
-            self._matrix_vec_slots += vec_extra
-            self._matrix_cell_slots += cell_extra
-        k = _tick_k(max(len(r.pending) for r in rows))
-        # Config-4 fast path: a flush that is ALL cell writes whose refs
-        # cover every structural op applies scan-free as one [B, k] tile
-        # (apply_cell_run) — the steady state of a settled grid under
-        # concurrent writers. Any vector op in flight falls back to the
-        # exact per-op/step path.
-        if all(op["target"] == mxk.MX_CELL
-               and op["ref_seq"] >= r.last_vec_seq
-               for r in rows for op in r.pending):
-            counts = np.asarray(self._matrix_state.cell_count)
-            deficit = k + 1 - (self._matrix_cell_slots - int(counts.max()))
-            if deficit > 0:
-                # Dedup the append log (superseded writes pack away)
-                # before paying for a bigger table — the cell analog of
-                # the vector zamboni above.
-                self._matrix_state = mxk.compact_cell_log(
-                    self._matrix_state)
-                self.stats["compactions"] += 1
-                counts = np.asarray(self._matrix_state.cell_count)
-                deficit = k + 1 - (self._matrix_cell_slots
-                                   - int(counts.max()))
-            if deficit > 0:
-                extra = _next_pow2(deficit)
-                self._matrix_state = jax.device_put(self._pad_matrix_state(
-                    self._matrix_state, vec_extra=0, cell_extra=extra))
-                self._matrix_cell_slots += extra
-            cells_per_doc: list[list[dict]] = [
-                [] for _ in range(self._matrix_capacity)]
-            refs = np.zeros(self._matrix_capacity, np.int32)
-            clients = np.zeros(self._matrix_capacity, np.int32)
-            for r in rows:
-                cells_per_doc[r.row] = r.pending
-                refs[r.row] = min(op["ref_seq"] for op in r.pending)
-            run = mxk.make_cell_run_batch(
-                cells_per_doc, self._matrix_capacity, k, refs, clients)
-            self._matrix_state = mxk.apply_cell_run(self._matrix_state, run)
-            self.stats["cell_run_ticks"] = (
-                self.stats.get("cell_run_ticks", 0) + 1)
-        else:
+        shapes = self.MATRIX_TICK_SHAPES
+        t_max, n_max = shapes[-1]
+        # Each row's ops in sequenced order, cut into ticks of at most
+        # t_max vector ops and n_max cells; tick i of every row runs
+        # together.
+        per_row = []
+        for r in rows:
+            ticks, cur, n_vec, n_cell = [], [], 0, 0
+            for op in r.pending:
+                is_cell = op["target"] == mxk.MX_CELL
+                if n_cell == n_max if is_cell else n_vec == t_max:
+                    ticks.append(cur)
+                    cur, n_vec, n_cell = [], 0, 0
+                cur.append(op)
+                n_cell += is_cell
+                n_vec += not is_cell
+            ticks.append(cur)
+            per_row.append((r.row, ticks))
+        for i in range(max(len(ticks) for _, ticks in per_row)):
             per_doc = [[] for _ in range(self._matrix_capacity)]
-            for r in rows:
-                per_doc[r.row] = r.pending
-            batch = mxk.make_matrix_op_batch(per_doc,
-                                             self._matrix_capacity, k)
-            self._matrix_state = mxp.apply_tick_best(self._matrix_state,
-                                                     batch)
-        self.stats["device_ops"] += sum(len(r.pending) for r in rows)
+            for row, ticks in per_row:
+                if i < len(ticks):
+                    per_doc[row] = ticks[i]
+            self._fit_matrix(rows, per_doc)
+            n_cell = [sum(op["target"] == mxk.MX_CELL for op in ops)
+                      for ops in per_doc]
+            need_n = max(n_cell)
+            need_t = max(len(ops) - c for ops, c in zip(per_doc, n_cell))
+            t, n = next((t, n) for t, n in shapes
+                        if t >= need_t and n >= need_n)
+            self._run_matrix_tick(per_doc, t, n)
+        n_ops = sum(len(r.pending) for r in rows)
+        self.stats["device_ops"] += n_ops
+        self.metrics.counter("matrix.ops_device").inc(n_ops)
         self.stats["flushes"] += 1
         for r in rows:
             r.pending = []
@@ -2330,6 +2317,11 @@ class KernelMergeHost:
             }
         return dict(sorted(out.items()))
 
+    def matrix_path(self, doc_id: str, datastore: str, channel: str) -> str:
+        """"device" or "scalar": which path serves a matrix channel."""
+        row = self._matrix_rows[ChannelKey(doc_id, datastore, channel)]
+        return "scalar" if row.scalar is not None else "device"
+
     def matrix_grid(self, doc_id: str, datastore: str,
                     channel: str) -> list[list]:
         """Converged dense grid of a matrix channel (None = unset)."""
@@ -2537,7 +2529,7 @@ class KernelMergeHost:
             matrix = {
                 "capacity": self._matrix_capacity,
                 "vec_slots": self._matrix_vec_slots,
-                "cell_slots": self._matrix_cell_slots,
+                "handles": list(self._matrix_handles),
                 "overlap_words": self._matrix_overlap_words,
                 "state": state,
                 "rows": [{
@@ -2548,7 +2540,6 @@ class KernelMergeHost:
                     "applied_min_seq": r.applied_min_seq,
                     "next_row_handle": r.next_row_handle,
                     "next_col_handle": r.next_col_handle,
-                    "last_vec_seq": r.last_vec_seq,
                     "scalar": (_dump_matrix_scalar(r.scalar)
                                if r.scalar is not None else None),
                 } for key, r in self._matrix_rows.items()],
@@ -2656,15 +2647,34 @@ class KernelMergeHost:
         if mx is not None:
             self._matrix_capacity = mx["capacity"]
             self._matrix_vec_slots = mx["vec_slots"]
-            self._matrix_cell_slots = mx["cell_slots"]
+            if "handles" in mx:
+                self._matrix_handles = tuple(mx["handles"])
+            else:  # a cell-log snapshot: planes past its rows' handles
+                self._matrix_handles = tuple(_next_pow2_width(
+                    have, max((r[f"next_{axis}_handle"] for r in mx["rows"]),
+                              default=0))
+                    for have, axis in zip(self._matrix_handles,
+                                          ("row", "col")))
             self._matrix_overlap_words = mx["overlap_words"]
             if mx["state"] is not None:
                 st = mx["state"]
-                self._matrix_state = jax.device_put(mxk.MatrixState(**{
-                    f: (mtk.MergeState(**{g: _nd_unpack(st[f][g])
-                                          for g in mtk.MergeState._fields})
-                        if f in ("rows", "cols") else _nd_unpack(st[f]))
-                    for f in mxk.MatrixState._fields}))
+                axes = {f: mtk.MergeState(**{g: _nd_unpack(st[f][g])
+                                             for g in mtk.MergeState._fields})
+                        for f in ("rows", "cols")}
+                if "handles" in mx:
+                    cells = {f: _nd_unpack(st[f])
+                             for f in ("cell_val", "cell_seq")}
+                else:
+                    cells = _cell_log_planes(
+                        {f: _nd_unpack(st[f]) for f in st
+                         if f not in ("rows", "cols")},
+                        self._matrix_capacity, *self._matrix_handles)
+                self._matrix_state = jax.device_put(
+                    mxk.MatrixState(**axes, **cells))
+                s = self._matrix_state
+                self._matrix_counts = np.stack(
+                    [np.asarray(s.rows.count), np.asarray(s.cols.count)],
+                    axis=1).astype(np.int64)
             for rec in mx["rows"]:
                 row = _MatrixRow(rec["row"])
                 row.client_slots = dict(rec["client_slots"])
@@ -2673,10 +2683,23 @@ class KernelMergeHost:
                 row.applied_min_seq = rec["applied_min_seq"]
                 row.next_row_handle = rec["next_row_handle"]
                 row.next_col_handle = rec["next_col_handle"]
-                row.last_vec_seq = rec["last_vec_seq"]
                 if rec["scalar"] is not None:
                     row.scalar = _load_matrix_scalar(rec["scalar"])
                 self._matrix_rows[ChannelKey(*rec["key"])] = row
+
+
+def _cell_log_planes(log: dict, docs: int, hr: int, hc: int) -> dict:
+    """The cell planes of a cell-log snapshot, which held each written
+    cell once: ``cell_used`` [B, C] marks the entries, each with its row
+    and col handle, value id and seq, scattered to their (handle, handle)
+    places."""
+    val = np.zeros((docs, hr, hc), np.int32)
+    seq = np.zeros((docs, hr, hc), np.int32)
+    b, c = np.nonzero(log["cell_used"])
+    rh, ch = log["cell_rh"][b, c], log["cell_ch"][b, c]
+    val[b, rh, ch] = log["cell_val"][b, c]
+    seq[b, rh, ch] = log["cell_seq"][b, c]
+    return {"cell_val": val, "cell_seq": seq}
 
 
 def _nd_pack(a: np.ndarray) -> dict:

@@ -32,6 +32,7 @@ from ..protocol.messages import (
     Trace,
 )
 from ..utils import MetricsRegistry, NullLogger, TelemetryLogger
+from ..utils.metrics import maybe_stage
 from .bus import BusMessage, MessageBus, StateStore
 from .lambdas import PartitionManager
 from .sequencer import DocumentSequencer, RawOperation, SequencerCheckpoint
@@ -250,8 +251,12 @@ class _BatchedDeliFactory:
     def flush_ready(self) -> None:
         """One host flush distributes tickets to every document's lambda
         (first checkpointing lambda pays; the rest just collect)."""
+        ticks = self.host.device_ticks
         for doc_id, tickets in self.host.flush().items():
             self._ready.setdefault(doc_id, []).extend(tickets)
+        if self._metrics is not None and self.host.device_ticks > ticks:
+            self._metrics.counter("deli.device_ticks").inc(
+                self.host.device_ticks - ticks)
 
     def take_ready(self, doc_id: str) -> list:
         return self._ready.pop(doc_id, [])
@@ -346,19 +351,26 @@ class _LiveConnection:
 
 
 class BroadcasterDocumentLambda:
-    """Fan-out to live connections (broadcaster/lambda.ts emit). Delivery is
-    per-connection resumable: each connection tracks the last seq it saw, so
-    replayed messages after a crash dedupe naturally."""
+    """Fan-out to live connections (broadcaster/lambda.ts emit). Ops
+    collect during the lambda batch and go out at its checkpoint: ONE
+    shared batch per document per batch, so every subscriber's session
+    encodes the ops event once (codec.BroadcastBatch caches the body)
+    however many ops the batch holds. Delivery is per-connection
+    resumable: each connection tracks the last seq it saw, so replayed
+    messages after a crash dedupe naturally."""
 
     def __init__(self, doc_id: str,
                  connections: dict[str, _LiveConnection],
-                 viewers=None) -> None:
+                 viewers=None, metrics: MetricsRegistry | None = None
+                 ) -> None:
         self.doc_id = doc_id
         self._connections = connections
         # Zero-arg callable resolving the service's viewer plane at
         # delivery time (the plane may attach after this lambda exists).
         self._viewers = viewers
+        self._metrics = metrics if metrics is not None else MetricsRegistry()
         self._delivered_seq: dict[str, int] = {}
+        self._batch: list[SequencedDocumentMessage] = []
 
     def handler(self, message: BusMessage) -> None:
         value = message.value
@@ -382,32 +394,43 @@ class BroadcasterDocumentLambda:
                     message=f"nack:{value['code']}",
                 ))
             return
-        self._deliver_op(value["message"])
-        # Viewer plane (read-only audience): the sequenced op fans out
-        # to the doc's viewer room, encoded once per batch (the plane
-        # dedupes crash-replay by sequence number).
-        viewers = self._viewers() if self._viewers is not None else None
-        if viewers is not None and viewers.has_viewers(self.doc_id):
-            viewers.publish_ops(self.doc_id, [value["message"]])
+        self._batch.append(value["message"])
 
-    def _deliver_op(self, op: SequencedDocumentMessage) -> None:
-        # ONE shared batch for every subscriber: sessions serialize the
-        # broadcast body once per doc (codec.BroadcastBatch caches the
-        # encoded frame), not once per connection.
+    def _deliver(self, ops: list[SequencedDocumentMessage]) -> None:
+        # ONE shared batch for every subscriber that saw none of it; a
+        # connection that saw a prefix (crash replay) gets its own tail.
         from ..protocol.codec import BroadcastBatch
-        batch = None
+        shared = None
+        last = ops[-1].sequence_number
+        pushed = 0
         for client_id, conn in list(self._connections.items()):
             if not conn.open:
                 continue
-            if op.sequence_number <= self._delivered_seq.get(client_id, 0):
+            seen = self._delivered_seq.get(client_id, 0)
+            if last <= seen:
                 continue
-            self._delivered_seq[client_id] = op.sequence_number
-            if batch is None:
-                batch = BroadcastBatch((op,))
-            conn.handler(batch)
+            self._delivered_seq[client_id] = last
+            if seen < ops[0].sequence_number:
+                if shared is None:
+                    shared = BroadcastBatch(ops)
+                conn.handler(shared)
+            else:
+                conn.handler(BroadcastBatch(
+                    op for op in ops if op.sequence_number > seen))
+            pushed += 1
+        self._metrics.counter("broadcast.frames_pushed").inc(pushed)
 
     def checkpoint(self, next_offset: int) -> None:
-        pass  # live fan-out has no durable state
+        ops, self._batch = self._batch, []
+        if not ops:
+            return  # live fan-out has no durable state
+        self._deliver(ops)
+        # Viewer plane (read-only audience): the batch fans out to the
+        # doc's viewer room, encoded once (the plane dedupes crash-replay
+        # by sequence number).
+        viewers = self._viewers() if self._viewers is not None else None
+        if viewers is not None and viewers.has_viewers(self.doc_id):
+            viewers.publish_ops(self.doc_id, ops)
 
 
 class FanoutBroadcasterDocumentLambda(BroadcasterDocumentLambda):
@@ -417,16 +440,18 @@ class FanoutBroadcasterDocumentLambda(BroadcasterDocumentLambda):
     connection. Per-connection crash-replay dedup moves to the drain."""
 
     def __init__(self, doc_id: str, connections: dict[str, _LiveConnection],
-                 fanout, viewers=None) -> None:
-        super().__init__(doc_id, connections, viewers)
+                 fanout, viewers=None,
+                 metrics: MetricsRegistry | None = None) -> None:
+        super().__init__(doc_id, connections, viewers, metrics)
         self._fanout = fanout
 
-    def _deliver_op(self, op: SequencedDocumentMessage) -> None:
+    def _deliver(self, ops: list[SequencedDocumentMessage]) -> None:
         import json as _json
 
         from ..protocol.codec import to_wire
-        self._fanout.publish(self.doc_id,
-                             _json.dumps(to_wire(op)).encode())
+        for op in ops:
+            self._fanout.publish(self.doc_id,
+                                 _json.dumps(to_wire(op)).encode())
 
 
 class _BroadcasterFactory:
@@ -435,12 +460,14 @@ class _BroadcasterFactory:
 
     def create(self, doc_id: str) -> BroadcasterDocumentLambda:
         viewers = lambda: self._service.viewers  # noqa: E731
+        metrics = self._service.metrics
         if self._service.fanout is not None:
             return FanoutBroadcasterDocumentLambda(
                 doc_id, self._service._connections_for(doc_id),
-                self._service.fanout, viewers)
+                self._service.fanout, viewers, metrics)
         return BroadcasterDocumentLambda(
-            doc_id, self._service._connections_for(doc_id), viewers)
+            doc_id, self._service._connections_for(doc_id), viewers,
+            metrics)
 
 
 # -- merger (device merge host consumer) --------------------------------------
@@ -450,9 +477,10 @@ class MergerDocumentLambda:
     """Feeds the sequenced stream into the device-resident KernelMergeHost
     (server/merge_host.py). The analogue of hosting the merge kernels
     behind the IPartitionLambdaFactory seam (BASELINE.json): ops buffer in
-    the host during the batch and hit the device once per checkpoint — the
-    lambda batch IS the device tick. Replayed messages dedupe inside the
-    host (per-channel last_seq guards).
+    the host while the service pumps and hit the device once per pump
+    round (:meth:`RouterliciousService.pump` flushes the host after the
+    lambdas drain) — the round IS the device tick. Replayed messages
+    dedupe inside the host (per-channel last_seq guards).
 
     Restart recovery: the host's device state is memory-only, but the
     consumer group's offsets are durable — so a fresh lambda (fresh host
@@ -472,7 +500,7 @@ class MergerDocumentLambda:
         self._host.ingest(self.doc_id, message.value["message"])
 
     def checkpoint(self, next_offset: int) -> None:
-        self._host.flush()
+        pass  # the device state is volatile: restart replays the op log
 
 
 class _MergerFactory:
@@ -716,34 +744,44 @@ class RouterliciousService:
         self._batched_deli_host = batched_deli_host
 
         # auto_pump=False is the batched-cadence mode: submits only produce
-        # to the bus; the operator (or load harness) pumps on its own tick,
-        # so lambda batches — and the device sequencer tick, when
-        # batched_deli_host is given — span many ops/documents.
+        # to the bus; the operator (the bridge front door, a load harness)
+        # pumps on its own tick, and each lambda takes everything that
+        # waits in one batch, so one pump round is one lambda batch — and
+        # one device sequencer tick, when batched_deli_host is given —
+        # over every document's ops.
         self._auto_pump = auto_pump
+        #: Stage ledger of the per-op serving round (utils/metrics
+        #: PEROP_STAGES), set by the front door that runs the round;
+        #: pump() times its lambdas into it.
+        self.ledger = None
+        batch = 256 if auto_pump else None
         deli_factory = (_BatchedDeliFactory(self.store, self.bus,
                                             batched_deli_host, self.metrics)
                         if batched_deli_host is not None else
                         _DeliFactory(self.store, self.bus,
                                      sequencer_factory, self.metrics))
-        self._deli = PartitionManager(self.bus, RAWDELTAS, "deli",
-                                      deli_factory)
-        self._scriptorium = PartitionManager(
-            self.bus, DELTAS, "scriptorium",
+
+        def lambdas(topic, group, factory):
+            return PartitionManager(self.bus, topic, group, factory,
+                                    batch_size=batch)
+
+        self._deli = lambdas(RAWDELTAS, "deli", deli_factory)
+        self._scriptorium = lambdas(
+            DELTAS, "scriptorium",
             _ScriptoriumFactory(self.store, ops_retention))
-        self._broadcaster = PartitionManager(
-            self.bus, DELTAS, "broadcaster", _BroadcasterFactory(self))
-        self._scribe = PartitionManager(
-            self.bus, DELTAS, "scribe",
+        self._broadcaster = lambdas(DELTAS, "broadcaster",
+                                    _BroadcasterFactory(self))
+        self._scribe = lambdas(
+            DELTAS, "scribe",
             _ScribeFactory(self.store, self.bus, self._clock,
                            self.snapshots))
-        self._merger = (PartitionManager(
-            self.bus, DELTAS, "merger",
-            _MergerFactory(merge_host, self.store))
-            if merge_host is not None else None)
-        self._copier = PartitionManager(
-            self.bus, RAWDELTAS, "copier", _CopierFactory(self.store))
-        self._foreman = PartitionManager(
-            self.bus, DELTAS, "foreman",
+        self._merger = (lambdas(DELTAS, "merger",
+                                _MergerFactory(merge_host, self.store))
+                        if merge_host is not None else None)
+        self._copier = lambdas(RAWDELTAS, "copier",
+                               _CopierFactory(self.store))
+        self._foreman = lambdas(
+            DELTAS, "foreman",
             _ForemanFactory(self.store, list(help_agents or [])))
 
     # -- internals -------------------------------------------------------------
@@ -789,24 +827,38 @@ class RouterliciousService:
             self.pump()
 
     def pump(self) -> None:
-        """Drain every lambda until quiescent (scribe may feed deli)."""
+        """Drain every lambda until quiescent (scribe may feed deli), then
+        apply what the merger took in one merge-host flush."""
         if self._pumping:
             return  # re-entrant submit during broadcast; outer loop drains
         self._pumping = True
+        merged = 0
         try:
             while True:
-                moved = self._deli.pump()
-                moved += self._scriptorium.pump()
-                moved += self._scribe.pump()
-                moved += self._broadcaster.pump()
-                moved += self._copier.pump()
-                moved += self._foreman.pump()
+                with maybe_stage(self.ledger, "deli"):
+                    moved = self._deli.pump()
+                with maybe_stage(self.ledger, "log"):
+                    moved += self._scriptorium.pump()
+                    moved += self._scribe.pump()
+                with maybe_stage(self.ledger, "broadcast"):
+                    moved += self._broadcaster.pump()
+                with maybe_stage(self.ledger, "log"):
+                    moved += self._copier.pump()
+                with maybe_stage(self.ledger, "service_other"):
+                    moved += self._foreman.pump()
                 if self._merger is not None:
-                    moved += self._merger.pump()
+                    with maybe_stage(self.ledger, "merge_flush"):
+                        n = self._merger.pump()
+                    merged += n
+                    moved += n
                 if self.fanout is not None:
-                    moved += self._drain_fanout()
+                    with maybe_stage(self.ledger, "broadcast"):
+                        moved += self._drain_fanout()
                 if moved == 0:
                     break
+            if merged:
+                with maybe_stage(self.ledger, "merge_flush"):
+                    self.merge_host.flush()
         finally:
             self._pumping = False
         self._pumps_since_idle_check += 1

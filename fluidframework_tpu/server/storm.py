@@ -267,7 +267,7 @@ def _mixed_tick(seq_state: seqk.SequencerState,
     if matrix_pack is not None:
         fields, valid, seqs = unpack(matrix_pack, MATRIX_PACK)
         ops = mxk.MatrixOpBatch(valid=valid, seq=seqs, **fields)
-        matrix_state = jax.vmap(mxk._process_doc)(matrix_state, ops)
+        matrix_state = mxk._tick(matrix_state, mxk.steps_from_ops(ops))
     tree_overflow = None
     if tree_pack is not None:
         fields, valid, _seqs = unpack(tree_pack, TREE_PACK)

@@ -19,6 +19,7 @@ from __future__ import annotations
 import gc
 import math
 import threading
+from contextlib import nullcontext
 import time
 from collections import deque
 from typing import Any, Iterable
@@ -144,6 +145,15 @@ STORM_STAGES = ("ingress_decode", "admission", "scatter", "device_dispatch",
                 "readback", "wal_append", "wal_commit_wait", "ack_pack",
                 "fanout_publish", "frame_wait", "ack_send", "idle_service",
                 "service_pump", "cohort")
+
+#: Stage order of one per-op serving round (server/bridge_host.py's pump
+#: loop in batched cadence, server/routerlicious.py's lambdas): the wait
+#: for the first request, decoding and submitting every request ready in
+#: the poll pass, then one service pump — deli, the durable log
+#: (scriptorium, scribe, copier), broadcast, the merger and its one
+#: merge-host flush, and the other lambdas.
+PEROP_STAGES = ("frame_wait", "ingress_decode", "submit", "deli", "log",
+                "broadcast", "merge_flush", "service_other")
 
 #: Stages that are the serving thread waiting for work, not doing it.
 WAIT_STAGES = ("frame_wait",)
@@ -312,6 +322,11 @@ class _Stage:
                                 self._args)
         self.t0 = time.monotonic_ns()
         self._running = True
+
+
+def maybe_stage(ledger: "StageLedger | None", name: str):
+    """``ledger.stage(name)``, or a no-op context where there is none."""
+    return ledger.stage(name) if ledger is not None else nullcontext()
 
 
 class StageLedger:

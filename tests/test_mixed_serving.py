@@ -30,7 +30,7 @@ def make_mixed(mesh, num_docs=16, num_hosts=2, k=8):
     serving = ShardedServing(
         mesh, num_docs=num_docs, k=k, num_hosts=num_hosts, num_clients=2,
         map_slots=16, text_slots=64, matrix_vec_slots=32,
-        matrix_cell_slots=64, tree_slots=16)
+        matrix_handles=16, tree_slots=16)
     serving.join_all(slots=(0, 1))
     return serving
 
@@ -136,9 +136,9 @@ def reference_text_state(slots=64):
     return state, pool
 
 
-def reference_matrix_state(vec_slots=32, cell_slots=64):
-    state = mxk.init_state(1, vec_slots, cell_slots,
-                           mtk.overlap_words_for(2))
+def reference_matrix_state(vec_slots=32, handles=16):
+    state = mxk.init_state(1, vec_slots, handles,
+                           overlap_words=mtk.overlap_words_for(2))
     alloc = mxk.HandleAllocator(1)
     seq, ref = 2, 2
     for client, ops in MATRIX_TICKS:

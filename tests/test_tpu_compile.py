@@ -19,7 +19,6 @@ from jax.sharding import SingleDeviceSharding
 from fluidframework_tpu.ops import map_kernel as mk
 from fluidframework_tpu.ops import map_pallas as mp
 from fluidframework_tpu.ops import matrix_kernel as mxk
-from fluidframework_tpu.ops import matrix_pallas as mxp
 from fluidframework_tpu.ops import mergetree_blocks as mtb
 from fluidframework_tpu.ops import mergetree_blocks_pallas as mtbp
 from fluidframework_tpu.ops import mergetree_kernel as mtk
@@ -107,14 +106,24 @@ def test_block_mergetree_kernel_compiles(one_chip, docs, writers):
 
 
 def test_matrix_step_kernel_compiles(one_chip):
-    b, t, r = 16_384, 64, 8
-    state = _shapes(one_chip, mxk.init_state, b, vec_slots=256,
-                    cell_slots=256)
-    runs = {name: (b, t, r) for name in mxk.MatrixStepBatch._fields
-            if name.startswith("r_")}
+    """The serving host's handle-indexed matrix tick (XLA: vector scan,
+    [N, S] handle lookup, plane scatter, zamboni) at the matrix_grid_1k
+    cell's shapes: 8 grids, 2,048-slot vector tables and handle planes,
+    256 writers, the largest tick (64 vector ops, 1,024 cells). The
+    state is donated, so the planes are updated in place."""
+    b, slots, handles, t, n = 8, 2048, 2048, 64, 1024
+    state = _shapes(one_chip, mxk.init_state, b, slots, handles,
+                    overlap_words=mtk.overlap_words_for(257))
     steps = _batch(one_chip, mxk.MatrixStepBatch, (b, t),
-                   {"vec_valid", "r_valid"}, tail=runs)
-    _assert_kernel(mxp.apply_tick_steps_pallas.lower(state, steps).compile())
+                   {"vec_valid", "c_valid"},
+                   tail={name: (b, n) for name in mxk.MatrixStepBatch._fields
+                         if name.startswith("c_")})
+    compiled = mxk.matrix_tick.lower(state, steps,
+                                     _array(one_chip, (b,))).compile()
+    mem = compiled.memory_analysis()
+    plane = b * handles * handles * 4
+    assert mem.alias_size_in_bytes >= 2 * plane  # both planes in place
+    assert mem.temp_size_in_bytes < 4 * 2**30
 
 
 def test_storm_tick_compiles_with_pallas_fold(one_chip, monkeypatch):
