@@ -23,6 +23,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import json
+import math
 import os
 import re
 import threading
@@ -32,9 +33,17 @@ from typing import Any, Callable
 
 from ..native import OpLog
 from ..protocol.codec import from_wire, register_codec, to_wire
-from ..protocol.messages import MessageType
+from ..protocol.messages import (
+    ClientDetail,
+    DocumentMessage,
+    MessageType,
+    NackErrorType,
+    NackMessage,
+    SequencedDocumentMessage,
+    Trace,
+)
 from ..utils import faults
-from ..utils.metrics import ProgramSpan
+from ..utils.metrics import MetricsRegistry, ProgramSpan
 from .bus import BusMessage, MessageBus, Topic
 from .sequencer import RawOperation
 
@@ -57,6 +66,178 @@ def _dump(value: Any) -> bytes:
 
 def _load(data: bytes) -> Any:
     return from_wire(json.loads(data.decode()))
+
+
+# -- the write path's in-memory copy ------------------------------------------
+#
+# A journaled record is held in memory in the shape a replay of its bytes
+# would rebuild. Most values written are in that shape already (they came
+# from a bus read, or are fresh ints and dicts), so the write path keeps
+# the value itself and decodes its bytes only when it is not.
+
+#: Leaves a JSON round trip returns as themselves, type and all (a float
+#: only when finite: NaN never equals itself).
+_PLAIN = frozenset((str, int, bool, type(None)))
+_STR = frozenset((str,))
+_RAW_FIELDS = tuple(f.name for f in dataclasses.fields(RawOperation))
+
+
+def _plain_leaves(*values: Any) -> bool:
+    for v in values:
+        t = type(v)
+        if t not in _PLAIN and not (t is float and math.isfinite(v)):
+            return False
+    return True
+
+
+def _wire(x: Any, bad: list) -> Any:
+    """``codec.to_wire(x)``, built by the walk that also decides whether
+    ``x`` is in replay shape: ``from_wire(json.loads(_dump(x)))`` equal
+    to ``x`` with the same type at every node. A node that is not appends
+    itself to ``bad``; a type the walk does not know takes ``to_wire``.
+
+    Replay shape: exact ``str``, ``int``, ``bool``, ``None`` or finite
+    ``float`` leaves; exact dicts with only ``str`` keys and no ``"_t"``;
+    exact lists; the codec's protocol dataclasses and ``RawOperation``
+    with their enum fields of their enum type, their tuple fields
+    (``traces``, ``scopes``) tuples, the fields the decoders take raw
+    plain leaves and every other field in replay shape. Nothing else."""
+    t = type(x)
+    if t in _PLAIN:
+        return x
+    if t is float:
+        if not math.isfinite(x):
+            bad.append(x)
+        return x
+    if t is dict:
+        if "_t" in x or not _STR.issuperset(map(type, x)):
+            bad.append(x)
+        return {k: v if type(v) in _PLAIN else _wire(v, bad)
+                for k, v in x.items()}
+    if t is list or t is tuple:
+        if t is tuple:
+            bad.append(x)
+        return [v if type(v) in _PLAIN else _wire(v, bad) for v in x]
+    if t is SequencedDocumentMessage:
+        if (type(x.type) is not MessageType or type(x.traces) is not tuple
+                or not _plain_leaves(
+                    x.client_id, x.sequence_number,
+                    x.minimum_sequence_number, x.client_sequence_number,
+                    x.reference_sequence_number, x.timestamp)):
+            bad.append(x)
+        return {"_t": "seq", "client_id": x.client_id,
+                "sequence_number": x.sequence_number,
+                "minimum_sequence_number": x.minimum_sequence_number,
+                "client_sequence_number": x.client_sequence_number,
+                "reference_sequence_number": x.reference_sequence_number,
+                "type": int(x.type), "contents": _wire(x.contents, bad),
+                "metadata": _wire(x.metadata, bad),
+                "server_metadata": _wire(x.server_metadata, bad),
+                "traces": [_wire(tr, bad) for tr in x.traces],
+                "timestamp": x.timestamp, "data": _wire(x.data, bad)}
+    if t is DocumentMessage:
+        if (type(x.type) is not MessageType or type(x.traces) is not tuple
+                or not _plain_leaves(x.client_sequence_number,
+                                     x.reference_sequence_number)):
+            bad.append(x)
+        return {"_t": "doc",
+                "client_sequence_number": x.client_sequence_number,
+                "reference_sequence_number": x.reference_sequence_number,
+                "type": int(x.type), "contents": _wire(x.contents, bad),
+                "metadata": _wire(x.metadata, bad),
+                "server_metadata": _wire(x.server_metadata, bad),
+                "traces": [_wire(tr, bad) for tr in x.traces]}
+    if t is Trace:
+        if not _plain_leaves(x.service, x.action, x.timestamp):
+            bad.append(x)
+        return {"_t": "trace", "service": x.service, "action": x.action,
+                "timestamp": x.timestamp}
+    if t is RawOperation:
+        if type(x.type) is not MessageType or type(x.traces) is not tuple:
+            bad.append(x)
+            return to_wire(x)
+        body: dict = {"_t": "raw"}
+        for name in _RAW_FIELDS:
+            v = getattr(x, name)
+            if name == "traces":
+                v = [_wire(tr, bad) for tr in v]
+            elif name != "type" and type(v) not in _PLAIN:
+                v = _wire(v, bad)
+            body[name] = v
+        return body
+    if t is NackMessage:
+        if (type(x.error_type) is not NackErrorType
+                or not _plain_leaves(x.sequence_number, x.code,
+                                     x.message, x.retry_after_s)):
+            bad.append(x)
+        return {"_t": "nack", "operation": _wire(x.operation, bad),
+                "sequence_number": x.sequence_number, "code": x.code,
+                "error_type": int(x.error_type), "message": x.message,
+                "retry_after_s": x.retry_after_s}
+    if t is ClientDetail:
+        if (type(x.scopes) is not tuple
+                or not _plain_leaves(x.client_id, x.mode, x.user,
+                                     *x.scopes)):
+            bad.append(x)
+        return {"_t": "cd", "client_id": x.client_id, "mode": x.mode,
+                "scopes": list(x.scopes), "user": x.user}
+    bad.append(x)
+    return to_wire(x)
+
+
+class _WriteCounters:
+    """The write path's counters, looked up once per registry:
+    ``durable.records`` (records written) and ``durable.replay_decodes``
+    (those whose in-memory copy still took the decode)."""
+
+    __slots__ = ("registry", "records", "decodes")
+
+    def __init__(self, registry: MetricsRegistry | None = None) -> None:
+        self.bind(registry if registry is not None else MetricsRegistry())
+
+    def bind(self, registry: MetricsRegistry) -> None:
+        self.registry = registry
+        self.records = registry.counter("durable.records")
+        self.decodes = registry.counter("durable.replay_decodes")
+
+    def encode(self, head: list, value: Any) -> tuple[bytes, Any]:
+        """The bytes ``_dump(head + [value])`` writes, and the in-memory
+        copy of ``value``: ``value`` itself when it is in replay shape,
+        else what a replay of those bytes gives."""
+        bad: list = []
+        data = json.dumps([*head, _wire(value, bad)],
+                          separators=(",", ":")).encode()
+        self.records.inc()
+        if not bad:
+            return data, value
+        self.decodes.inc()
+        return data, _load(data)[-1]
+
+
+class _CountedWrites:
+    """``metrics``: the registry a durable log's write path counts into
+    (the service hands over its own)."""
+
+    _counters: _WriteCounters
+
+    @property
+    def metrics(self) -> MetricsRegistry:
+        return self._counters.registry
+
+    @metrics.setter
+    def metrics(self, registry: MetricsRegistry) -> None:
+        self._counters.bind(registry)
+
+
+def _copy_containers(value: Any) -> Any:
+    """``value`` with every dict and list in it copied (leaves, tuples and
+    frozen dataclasses shared)."""
+    t = type(value)
+    if t is dict:
+        return {k: _copy_containers(v) for k, v in value.items()}
+    if t is list:
+        return [_copy_containers(v) for v in value]
+    return value
 
 
 # -- group-commit WAL writer --------------------------------------------------
@@ -446,8 +627,11 @@ class GroupCommitLog:
 class _DurablePartition:
     """In-memory view append-through to an op log file."""
 
-    def __init__(self, path: Path) -> None:
+    def __init__(self, path: Path,
+                 counters: _WriteCounters | None = None) -> None:
         self._oplog = OpLog(path)
+        self._counters = counters if counters is not None \
+            else _WriteCounters()
         self.log: list[BusMessage] = []
         #: Offset of ``log[0]`` (bus retention trims the consumed
         #: in-memory prefix; the on-disk oplog keeps full history).
@@ -463,12 +647,16 @@ class _DurablePartition:
 
     def append(self, key: str, value: Any) -> int:
         offset = self.base + len(self.log)
-        data = _dump([key, value])
+        data, kept = self._counters.encode([key], value)
         self._oplog.append(data)
         self.dirty = True
-        # Keep the codec-decoded copy in memory so consumers see identical
-        # shapes (tuples→lists etc.) before and after a restart replay.
-        self.log.append(BusMessage(offset, key, _load(data)[1]))
+        if kept is value and type(value) is RawOperation:
+            # The orderer's hop: a submitted op's contents are still the
+            # client's objects. The log keeps its own containers.
+            kept = dataclasses.replace(
+                value, contents=_copy_containers(value.contents),
+                data=_copy_containers(value.data))
+        self.log.append(BusMessage(offset, key, kept))
         return offset
 
     def trim(self, upto: int) -> int:
@@ -491,14 +679,15 @@ class _DurablePartition:
 
 
 class _DurableTopic(Topic):
-    def __init__(self, name: str, num_partitions: int, root: Path) -> None:
+    def __init__(self, name: str, num_partitions: int, root: Path,
+                 counters: _WriteCounters) -> None:
         self.name = name
         self.partitions = [
-            _DurablePartition(root / f"{name}-{p}.log")
+            _DurablePartition(root / f"{name}-{p}.log", counters)
             for p in range(num_partitions)]
 
 
-class DurableMessageBus(MessageBus):
+class DurableMessageBus(MessageBus, _CountedWrites):
     """MessageBus whose partitions and consumer offsets live on disk.
 
     Reopening the same directory restores every topic log and committed
@@ -513,6 +702,7 @@ class DurableMessageBus(MessageBus):
         super().__init__(retention_messages=retention_messages)
         self._root = Path(root)
         self._root.mkdir(parents=True, exist_ok=True)
+        self._counters = _WriteCounters()
         # Topic metadata journal: partition counts are fixed at creation
         # (the Kafka rule) — reopening always uses the recorded count, so a
         # caller passing a different num_partitions can never orphan logs
@@ -541,7 +731,8 @@ class DurableMessageBus(MessageBus):
                 self._meta_log.append(_dump([name, num_partitions]))
                 self._topic_partitions[name] = num_partitions
                 recorded = num_partitions
-            self._topics[name] = _DurableTopic(name, recorded, self._root)
+            self._topics[name] = _DurableTopic(name, recorded, self._root,
+                                               self._counters)
         return self._topics[name]
 
     def commit(self, topic: str, group: str, partition: int,
@@ -587,7 +778,7 @@ class DurableMessageBus(MessageBus):
 # -- durable state store ------------------------------------------------------
 
 
-class FileStateStore:
+class FileStateStore(_CountedWrites):
     """Journaled key→document store (same duck-typed surface as the
     in-memory StateStore). Every put/append is one journal record; open
     replays the journal into memory. ``compact()`` rewrites the journal as
@@ -605,6 +796,7 @@ class FileStateStore:
         self._path = self._root / "state.log"
         self._journal = OpLog(self._path)
         self._dirty = False  # appends since the last sync (group commit)
+        self._counters = _WriteCounters()
         self._data: dict[str, Any] = {}
         # Journal records per key, and those a later put superseded.
         self._key_records: dict[str, int] = {}
@@ -622,19 +814,19 @@ class FileStateStore:
         return self._data.get(key, default)
 
     def put(self, key: str, value: Any) -> None:
-        data = _dump(["put", key, value])
+        # Memory holds what a post-restart replay would (tuples become
+        # lists etc.): no dual-shape bugs between first run and recovery.
+        # A value already in that shape is held as given, as the in-memory
+        # StateStore holds it.
+        data, kept = self._counters.encode(["put", key], value)
         self._journal.append(data)
-        # Keep the codec-decoded copy so in-memory state is identical to a
-        # post-restart replay (tuples become lists etc.) — no dual-shape
-        # bugs between first run and recovery. One serialization pass: the
-        # journal bytes are the source.
-        self._data[key] = _load(data)[2]
+        self._data[key] = kept
         self._bump("put", key)
 
     def append(self, key: str, items: list) -> None:
-        data = _dump(["append", key, items])
+        data, kept = self._counters.encode(["append", key], items)
         self._journal.append(data)
-        self._data.setdefault(key, []).extend(_load(data)[2])
+        self._data.setdefault(key, []).extend(kept)
         self._bump("append", key)
 
     def _count(self, kind: str, key: str) -> None:

@@ -719,6 +719,9 @@ class RouterliciousService:
             # psum aggregation sees merge-host counters too).
             merge_host.metrics = self.metrics
         self.store = store if store is not None else StateStore()
+        for durable in (self.bus, self.store):
+            if hasattr(durable, "metrics"):  # the durable bus and store
+                durable.metrics = self.metrics
         self.snapshots = snapshots if snapshots is not None \
             else StoreSnapshotBackend(self.store)
         self.bus.create_topic(RAWDELTAS, num_partitions)

@@ -342,3 +342,237 @@ class TestGroupCommitLog:
         bus.commit("t", "g", 0, 8)
         assert not part.dirty  # the commit group-synced them
         bus.close()
+
+
+# -- the write path keeps values already in replay shape ----------------------
+
+
+def _same_tree(a, b):
+    """Equal, with the same type at every node (NaN equals NaN)."""
+    import dataclasses
+    import math
+
+    assert type(a) is type(b), (a, b)
+    if type(a) is float and math.isnan(a):
+        assert math.isnan(b)
+    elif type(a) is dict:
+        assert list(a) == list(b)
+        for k in a:
+            _same_tree(a[k], b[k])
+    elif type(a) in (list, tuple):
+        assert len(a) == len(b)
+        for x, y in zip(a, b):
+            _same_tree(x, y)
+    elif dataclasses.is_dataclass(a):
+        for f in dataclasses.fields(a):
+            _same_tree(getattr(a, f.name), getattr(b, f.name))
+    else:
+        assert a == b
+
+
+def _replay_cases():
+    from enum import IntEnum
+
+    from fluidframework_tpu.protocol.messages import (
+        ClientDetail, DocumentMessage, MessageType, NackErrorType,
+        NackMessage, SequencedDocumentMessage, Trace)
+    from fluidframework_tpu.server.sequencer import RawOperation
+
+    class Color(IntEnum):
+        RED = 1
+
+    traces = (Trace("alfred", "submit", 1.5), Trace("deli", "start", 2.0))
+
+    def raw(contents):
+        return RawOperation(client_id="c1", type=MessageType.OPERATION,
+                            client_seq=3, ref_seq=2, timestamp=7,
+                            contents=contents, traces=traces)
+
+    def seq(contents):
+        return SequencedDocumentMessage(
+            client_id="c1", sequence_number=9, minimum_sequence_number=4,
+            client_sequence_number=3, reference_sequence_number=2,
+            type=MessageType.OPERATION, contents=contents, traces=traces,
+            timestamp=7)
+
+    checkpoint = {
+        "sequence_number": 9, "minimum_sequence_number": 4,
+        "last_sent_msn": 4, "no_active_clients": False,
+        "clients": [{"client_id": "c1", "client_seq": 3, "ref_seq": 2,
+                     "last_update": 7, "can_evict": True,
+                     "can_summarize": True, "nack": False}],
+        "nack_future": False, "client_timeout_ms": 300000,
+        "log_offset": 12, "summary_responded": 0}
+    # (name, value, whether the write path must decode its bytes)
+    return [
+        ("plain_dict", {"a": 1, "b": [1.5, "x", None, True],
+                        "c": {"d": -0.0}}, False),
+        ("int_key", {1: "a", "b": 2}, True),
+        ("contents_tuple", raw({"pos": (1, 2)}), True),
+        ("contents_intenum", seq({"color": Color.RED}), True),
+        ("contents_tag_key", DocumentMessage(
+            client_sequence_number=1, reference_sequence_number=0,
+            type=MessageType.OPERATION,
+            contents={"_t": "trace", "service": "s", "action": "a",
+                      "timestamp": 1.0}), True),
+        ("contents_nan", raw({"value": float("nan")}), True),
+        ("raw_with_traces", raw({"address": "grid", "contents": {
+            "type": "setCell", "row": 1, "col": 2, "value": 3}}), False),
+        ("seq_with_traces", seq({"type": "setCell", "value": [1, 2]}),
+         False),
+        ("join", RawOperation(client_id=None, type=MessageType.CLIENT_JOIN,
+                              data=ClientDetail(client_id="c1",
+                                                scopes=("doc:read",)),
+                              timestamp=5), False),
+        ("nack", NackMessage(
+            operation=DocumentMessage(client_sequence_number=1,
+                                      reference_sequence_number=0,
+                                      type=MessageType.OPERATION,
+                                      contents={"k": "v"}),
+            sequence_number=9, code=400,
+            error_type=NackErrorType.BAD_REQUEST, message="nack:1"), False),
+        ("deli_checkpoint", checkpoint, False),
+        ("deli_checkpoint_tuple_clients",
+         {**checkpoint, "clients": [("c1", 3, 2)]}, True),
+    ]
+
+
+_CASES = _replay_cases()
+
+
+class TestReplayShape:
+    @pytest.mark.parametrize("path", ["produce", "put", "append"])
+    @pytest.mark.parametrize("name,value,decodes", _CASES,
+                             ids=[c[0] for c in _CASES])
+    def test_memory_matches_replay(self, tmp_path, path, name, value,
+                                   decodes):
+        """Through each write path: the in-memory copy equals what a
+        reopen replays, type for type; the bytes are ``_dump``'s; and the
+        decode runs exactly for values not already in replay shape."""
+        from fluidframework_tpu.server.durable_store import _dump
+
+        if path == "produce":
+            bus = DurableMessageBus(tmp_path)
+            bus.create_topic("t", 1)
+            bus.produce("t", "doc", value)
+            held = bus.topic("t").read(0, 0)[0].value
+            counters = bus.metrics.snapshot()
+            bus.close()
+            bus = DurableMessageBus(tmp_path)
+            bus.create_topic("t", 1)
+            replayed = bus.topic("t").read(0, 0)[0].value
+            bus.close()
+            log, record = OpLog(tmp_path / "t-0.log"), ["doc", value]
+        else:
+            store = FileStateStore(tmp_path)
+            if path == "put":
+                store.put("k", value)
+                held = store.get("k")
+                record = ["put", "k", value]
+            else:
+                store.append("k", [value])
+                held = store.get("k")[0]
+                record = ["append", "k", [value]]
+            counters = store.metrics.snapshot()
+            store.close()
+            store = FileStateStore(tmp_path)
+            replayed = store.get("k") if path == "put" \
+                else store.get("k")[0]
+            store.close()
+            log = OpLog(tmp_path / "state.log")
+        _same_tree(held, replayed)
+        assert len(log) == 1 and log.read(0) == _dump(record)
+        log.close()
+        assert counters["durable.records"] == 1
+        assert counters["durable.replay_decodes"] == int(decodes)
+        if not decodes and path == "put":
+            assert held is value  # kept as given, not rebuilt
+
+
+def _durable_service(tmp_path):
+    from fluidframework_tpu.server.routerlicious import RouterliciousService
+
+    return RouterliciousService(
+        bus=DurableMessageBus(tmp_path / "bus"),
+        store=FileStateStore(tmp_path / "state"), auto_pump=False)
+
+
+def _set_cell(cseq, contents_value):
+    from fluidframework_tpu.protocol.messages import (DocumentMessage,
+                                                      MessageType)
+
+    return DocumentMessage(
+        client_sequence_number=cseq, reference_sequence_number=1,
+        type=MessageType.OPERATION,
+        contents={"address": "grid", "contents": {
+            "type": "setCell", "row": cseq, "col": 2,
+            "value": contents_value}})
+
+
+class TestDurableWritePath:
+    def test_submitted_contents_stay_the_callers(self, tmp_path):
+        """The log's copy of a submitted op shares no container with the
+        caller's DocumentMessage: mutating it after submit changes
+        neither the RAWDELTAS message nor, after a pump, rawops/ or ops/."""
+        import copy
+
+        from fluidframework_tpu.server.orderer import RAWDELTAS
+
+        service = _durable_service(tmp_path)
+        conn = service.connect("doc", lambda ops: None)
+        service.pump()
+        message = _set_cell(1, [1, 2])
+        before = copy.deepcopy(message.contents)
+        conn.submit([message])
+        message.contents["contents"]["value"].append(3)
+        message.contents["contents"]["row"] = 99
+        message.contents["extra"] = True
+
+        topic = service.bus.topic(RAWDELTAS)
+        raws = [m.value for p in range(topic.num_partitions)
+                for m in topic.read(p, 0) if m.key == "doc"]
+        assert raws[-1].contents == before
+        service.pump()
+        assert service.store.get("rawops/doc")[-1].contents == before
+        assert service.store.get("ops/doc")[-1].contents == before
+        service.bus.close()
+        service.store.close()
+
+    def test_records_and_decodes_counted(self, tmp_path):
+        """Five records an op (clock, RAWDELTAS, DELTAS, rawops/, ops/)
+        plus the lambdas' checkpoints. Only the RAWDELTAS record of the op
+        whose contents hold a tuple decodes: every later record carries
+        the bus's decoded copy, in replay shape."""
+        from fluidframework_tpu.server.durable_store import _load
+
+        service = _durable_service(tmp_path)
+        conns = [service.connect(doc, lambda ops: None)
+                 for doc in ("doc-a", "doc-b")]
+        service.pump()
+        journal = service.store._journal
+        start = len(journal)
+        base = service.metrics.snapshot()
+        n = 0
+        for conn in conns:
+            for cseq in range(1, 7):
+                conn.submit([_set_cell(cseq, cseq * 10)])
+                n += 1
+        conns[0].submit([_set_cell(7, (1, 2))])  # a tuple: decodes
+        n += 1
+        service.pump()
+        snap = service.metrics.snapshot()
+        keys = [_load(journal.read(i))[1] for i in range(start, len(journal))]
+        per_op = [k for k in keys
+                  if k == "clock" or k.startswith(("ops/", "rawops/"))]
+        assert len(per_op) == 3 * n
+        checkpoints = len(keys) - len(per_op)
+        assert checkpoints > 0
+        records = snap["durable.records"] - base["durable.records"]
+        assert records == 5 * n + checkpoints
+        decodes = (snap["durable.replay_decodes"]
+                   - base.get("durable.replay_decodes", 0))
+        assert decodes == 1
+        assert service.store.get("ops/doc-a")[-1].contents[
+            "contents"]["value"] == [1, 2]
+        service.bus.close()
+        service.store.close()
