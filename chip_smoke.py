@@ -1,28 +1,24 @@
-"""Bring-up smoke on the chip: drive the served path once through its own
-entry points, check every result against the repo's scalar engines, and
-print one JSON line per phase, then the result line.
+"""On-chip smoke of the serving paths that no benchmark cell runs yet.
 
-    python chip_smoke.py               # one chip: map storm, text, all-family
-    python chip_smoke.py --multichip   # four chips: the docs-sharded tick only
+Each phase drives its path once through its own entry points, checks
+every result against the repo's scalar engines and prints one JSON line;
+the result line comes last.
 
-Phases (one process holds the chip; the storm load clients are plain
-scripts that never import JAX):
+    python chip_smoke.py               # one chip: text, all-family
 
-* ``map_storm`` — BASELINE config 3 (SharedMap op storm, 10k docs x 4
-  clients, set/delete flood) at the e2e storm shape: 10,240 docs, k=512
-  ops per doc per tick. Storm frames go over TCP to the C++ bridge front
-  door and the StormController with the group-commit WAL. Every frame is
-  acked, nothing is shed, the durable WAL covers the acked ops, and
-  sampled docs equal a scalar MapData replay of ``get_deltas``.
+Phases (one process holds the chip):
+
 * ``text`` — BASELINE config 2 width: 128 writers per doc on the
   block-structured table through KernelMergeHost (4,096 docs, k=32, 512
   slots), converged text equal to the scalar MergeEngine.
 * ``all_family`` — the fused map + text + matrix + tree tick
   (``storm._mixed_tick``) at 8,192 docs through ShardedServing, sampled
   rows of each family equal to its scalar engine.
-* ``multichip`` (``--multichip`` only) — ShardedServing's docs-sharded
-  tick over 4 devices at 4 x 10,240 docs: shards on 4 distinct devices,
-  sampled map rows equal to the scalar replay.
+
+The map storm through the front door and the docs-sharded tick over four
+chips are benchmark cells (``map_storm_10k.flood`` and
+``map_sharded_4chip.flood`` in ``BENCHMARK.json``), each checked against
+its own plain reference there.
 
 Any failed check or exception exits nonzero without the result line.
 """
@@ -31,9 +27,7 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import random
-import subprocess
 import sys
 import time
 from pathlib import Path
@@ -79,163 +73,7 @@ def _scalar_map(words) -> dict:
     return dict(data.items())
 
 
-# -- phase 1: the map op storm through the front door --------------------------
-
-
-def phase_map_storm(dev, seed: int, num_docs: int = 10_240,
-                    clients_per_doc: int = 4, k: int = 512, ticks: int = 6,
-                    n_conns: int = 4, num_slots: int = 32,
-                    sample: int = 64) -> None:
-    import struct
-    import tempfile
-
-    import numpy as np
-
-    from fluidframework_tpu.dds.map_data import MapData
-    from fluidframework_tpu.native.fanout import make_fanout
-    from fluidframework_tpu.ops import map_pallas as mp
-    from fluidframework_tpu.protocol.messages import MessageType
-    from fluidframework_tpu.server.bridge_host import BridgeFrontDoor
-    from fluidframework_tpu.server.kernel_host import KernelSequencerHost
-    from fluidframework_tpu.server.merge_host import KernelMergeHost
-    from fluidframework_tpu.server.routerlicious import RouterliciousService
-    from fluidframework_tpu.server.storm import StormController, _storm_tick
-
-    spill = tempfile.mkdtemp(prefix="chip-smoke-wal-")
-    seq_host = KernelSequencerHost(num_slots=clients_per_doc,
-                                   initial_capacity=num_docs)
-    merge_host = KernelMergeHost(map_slots=num_slots, row_capacity=num_docs,
-                                 flush_threshold=10**9)
-    service = RouterliciousService(merge_host=merge_host,
-                                   batched_deli_host=seq_host,
-                                   auto_pump=False, fanout=make_fanout())
-    storm = StormController(service, seq_host, merge_host,
-                            flush_threshold_docs=num_docs,
-                            max_key_slots=num_slots, spill_dir=spill,
-                            durability="group")
-    front = BridgeFrontDoor(service, 0)
-    procs: list[subprocess.Popen] = []
-    try:
-        t0 = time.perf_counter()
-        docs = [f"storm-doc-{i}" for i in range(num_docs)]
-        clients = {d: [service.connect(d, lambda msgs: None).client_id
-                       for _ in range(clients_per_doc)] for d in docs}
-        service.pump()
-        setup_s = time.perf_counter() - t0
-
-        # Warm-up: one full-shape tick in process (client 0 of every
-        # doc) compiles the fused deli + map-fold program.
-        rng = np.random.default_rng(seed)
-        words0 = (rng.integers(0, 1 << 20, (num_docs, k)).astype(np.uint32)
-                  << 12)
-        t0 = time.perf_counter()
-        storm.submit_frame(None, {"op": "storm", "docs": [
-            [d, clients[d][0], 1, 1, k] for d in docs]},
-            memoryview(words0.tobytes()))
-        storm.flush()
-        compile_s = time.perf_counter() - t0
-        _check(storm.stats["sequenced_ops"] == num_docs * k,
-               f"warm-up sequenced {storm.stats['sequenced_ops']}")
-        ticks_before = storm.stats["ticks"]
-
-        per_conn = num_docs // n_conns
-        client_py = REPO / "fluidframework_tpu" / "tools" / "storm_client.py"
-        for c in range(n_conns):
-            conn_docs = docs[c * per_conn:(c + 1) * per_conn]
-            proc = subprocess.Popen(
-                [sys.executable, str(client_py)], stdin=subprocess.PIPE,
-                stdout=subprocess.PIPE, text=True,
-                env={**os.environ, "JAX_PLATFORMS": "cpu"})
-            procs.append(proc)
-            proc.stdin.write(json.dumps({
-                "port": front.port, "k": k, "ticks": ticks,
-                "seed": seed * 100 + c, "num_slots": num_slots,
-                "kinds": [0, 0, 0, 1], "window": 2,
-                "docs": [[d, clients[d]] for d in conn_docs],
-                "cseq0": [[k + 1] + [1] * (clients_per_doc - 1)]
-                * len(conn_docs)}) + "\n")
-            proc.stdin.flush()
-        for proc in procs:
-            _check(proc.stdout.readline().strip() == "READY",
-                   "storm client did not start")
-        storm.ledger.clear()  # tick walls count from the first frame
-        t0 = time.perf_counter()
-        for proc in procs:
-            proc.stdin.write("GO\n")
-            proc.stdin.flush()
-        results = [json.loads(proc.stdout.readline()) for proc in procs]
-        elapsed = time.perf_counter() - t0
-        for proc in procs:
-            _check(proc.wait(timeout=60) == 0, "storm client failed")
-        storm.flush()
-
-        _check(not any(r["jax_imported"] for r in results),
-               "a storm client imported JAX")
-        acked = sum(len(r["ack_times"]) for r in results)
-        _check(acked == n_conns * ticks, f"acked {acked} frames")
-        _check(sum(r["nacked"] for r in results) == 0, "frames were nacked")
-        _check(storm.stats["shed_frames"] == 0, "frames were shed")
-        steady = storm.stats["ticks"] - ticks_before
-        _check(steady >= 4, f"only {steady} steady ticks")
-        acked_ops = num_docs * k * (1 + ticks)  # warm-up + acked frames
-        wal = storm._group_wal
-        _check(wal.durable_len == storm.stats["ticks"],
-               f"durable {wal.durable_len} of {storm.stats['ticks']} ticks")
-        durable_ops = 0
-        for i in range(wal.durable_len):
-            rec = wal.read(i)
-            hlen = struct.unpack_from("<I", rec)[0]
-            hdr = json.loads(rec[4:4 + hlen])
-            durable_ops += sum(entry[5] for entry in hdr["docs"])
-        _check(durable_ops >= acked_ops,
-               f"WAL holds {durable_ops} ops, {acked_ops} acked")
-        _check(storm.stats["sequenced_ops"] == acked_ops,
-               f"sequenced {storm.stats['sequenced_ops']} of {acked_ops}")
-
-        pick = random.Random(seed).sample(docs, sample)
-        for d in pick:
-            data = MapData()
-            for m in service.get_deltas(d, 0):
-                if m.type != MessageType.OPERATION:
-                    continue
-                inner = (m.contents or {}).get("contents",
-                                               {}).get("contents")
-                if inner:
-                    data.process(inner, False, None)
-            _check(merge_host.map_entries(d, "default", "root")
-                   == dict(data.items()), f"{d} differs from MapData")
-
-        _check(not mp.default_interpret(), "map fold in interpret mode")
-        b_seq, b_map = seq_host._capacity, merge_host._map_capacity
-        vec_seq = np.zeros(b_seq, np.int32)
-        vec_map = np.zeros(b_map, np.int32)
-        _check(_has_kernel(_storm_tick, seq_host._state, merge_host._xstate,
-                           vec_seq, vec_seq, vec_seq, vec_seq, vec_seq,
-                           vec_map, np.zeros((b_map, k), np.uint32),
-                           vec_map), "storm tick holds no Pallas kernel")
-        tick_ms = sorted(r["wall"] / 1e6 for r in storm.ledger.records())
-        _line("map_storm", dev, ok=True,
-              shapes={"docs": num_docs, "clients_per_doc": clients_per_doc,
-                      "k": k, "key_slots": num_slots, "conns": n_conns},
-              setup_s=setup_s, compile_s=compile_s, steady_ticks=steady,
-              steady_s=elapsed,
-              ops_per_s=num_docs * k * ticks / elapsed,
-              tick_ms_p50=tick_ms[len(tick_ms) // 2],
-              tick_ms_max=tick_ms[-1], durable_ticks=wal.durable_len,
-              sampled_docs=sample, kernel="pallas_map_fold")
-    finally:
-        for proc in procs:
-            if proc.poll() is None:
-                proc.kill()
-                proc.wait()
-        front.close()
-        if storm._group_wal is not None:
-            storm._group_wal.close()
-        import shutil
-        shutil.rmtree(spill, ignore_errors=True)
-
-
-# -- phase 2: 128 writers per text doc on the block table ----------------------
+# -- phase 1: text on the block merge-tree ---------------------------------------
 
 
 def _text_stream(rng: random.Random, n_ops: int, writers: int,
@@ -328,7 +166,7 @@ def phase_text(dev, seed: int, num_docs: int = 4096, writers: int = 128,
           device_ops=host.stats["device_ops"])
 
 
-# -- phase 3: the fused all-family tick ----------------------------------------
+# -- phase 2: the fused all-family tick ----------------------------------------
 
 
 def phase_all_family(dev, seed: int, num_docs: int = 8192, ticks: int = 4,
@@ -410,12 +248,10 @@ def phase_all_family(dev, seed: int, num_docs: int = 8192, ticks: int = 4,
             cseq[row] += n
         t0 = time.perf_counter()
         harvest = serving.tick()
-        harvest = serving.flush() or [harvest]
         tick_s.append(time.perf_counter() - t0)
         acks = {}
-        for per_host in harvest:
-            for rows in per_host.values():
-                acks.update(rows)
+        for rows in harvest.values():
+            acks.update(rows)
         for row in range(num_docs):
             n_ok, first, last = acks[row]
             fam = families[row % 4]
@@ -518,92 +354,8 @@ def phase_all_family(dev, seed: int, num_docs: int = 8192, ticks: int = 4,
           sampled_rows_per_family=sample)
 
 
-# -- four chips: the docs-sharded serving tick ---------------------------------
-
-
-def phase_multichip(dev, seed: int, per_device: int = 10_240, k: int = 512,
-                    ticks: int = 4, n_devices: int = 4,
-                    sample: int = 256) -> None:
-    import jax
-    import numpy as np
-
-    from fluidframework_tpu.ops import map_pallas as mp
-    from fluidframework_tpu.parallel.mesh import make_mesh
-    from fluidframework_tpu.parallel.serving import ShardedServing
-
-    devices = jax.devices()
-    _check(len(devices) >= n_devices, f"{len(devices)} devices")
-    num_docs = per_device * n_devices
-    serving = ShardedServing(make_mesh(devices[:n_devices]),
-                             num_docs=num_docs, k=k, num_hosts=n_devices,
-                             num_clients=2, map_slots=32)
-    serving.join_all()
-    rng = np.random.default_rng(seed)
-    pick = sorted(random.Random(seed).sample(range(num_docs), sample))
-    logs = {row: [] for row in pick}
-    cseq, ref = 1, 1
-    tick_s = []
-    for t in range(ticks):
-        words = ((rng.integers(0, 4, (num_docs, k)) % 3).astype(np.uint32)
-                 | rng.integers(0, 32, (num_docs, k)).astype(np.uint32) << 2
-                 | rng.integers(0, 1 << 20, (num_docs, k)).astype(
-                     np.uint32) << 12)
-        for row in range(num_docs):
-            serving.submit(row, words[row], cseq, ref)
-        for row in pick:
-            logs[row].append(words[row])
-        t0 = time.perf_counter()
-        harvest = serving.tick()
-        harvest = serving.flush() or [harvest]
-        tick_s.append(time.perf_counter() - t0)
-        acks = {}
-        for per_host in harvest:
-            for rows in per_host.values():
-                acks.update(rows)
-        _check(len(acks) == num_docs
-               and all(a[0] == k for a in acks.values()),
-               f"tick {t}: {len(acks)} rows acked")
-        cseq += k
-        ref = 1 + (t + 1) * k
-
-    leaf = serving.map_state.value
-    shard_devices = {s.device for s in leaf.addressable_shards}
-    _check(len(shard_devices) == n_devices,
-           f"map state on {len(shard_devices)} devices")
-    _check(not mp.default_interpret(), "map fold in interpret mode")
-    values = np.asarray(serving.map_state.value)
-    present = np.asarray(serving.map_state.present)
-    for row in pick:
-        want = _scalar_map(np.concatenate(logs[row]).tolist())
-        got = {f"k{s}": int(values[row, s])
-               for s in range(present.shape[1]) if present[row, s]}
-        _check(got == want, f"map row {row} differs from the scalar replay")
-    _line("multichip", dev, ok=True,
-          shapes={"docs": num_docs, "devices": n_devices, "k": k},
-          shard_devices=sorted(str(d) for d in shard_devices),
-          compile_s=tick_s[0], steady_ticks=len(tick_s) - 1,
-          steady_tick_ms=[1000.0 * s for s in tick_s[1:]],
-          sampled_docs=sample)
-
-
-def _require_native() -> None:
-    """The served path needs the C++ bridge, fan-out and oplog: no silent
-    fallback to the Python twins here."""
-    from fluidframework_tpu import native
-    from fluidframework_tpu.native import _loader, bridge, fanout
-
-    for name, load in (("bridge", bridge._load_library),
-                       ("fanout", fanout._load_library),
-                       ("oplog", native._load_library)):
-        if load() is None:
-            raise RuntimeError(f"native {name} did not build: "
-                               f"{_loader.build_errors.get(name)}")
-
-
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    parser.add_argument("--multichip", action="store_true",
-                        help="run only the 4-chip docs-sharded phase")
     parser.add_argument("--seed", type=int, default=0)
     args = parser.parse_args(argv)
 
@@ -620,13 +372,8 @@ def main(argv=None) -> int:
     cache = compile_cache.enable()
     _line("setup", dev, ok=True, compile_cache_dir=cache,
           jax=jax.__version__, device_count=len(jax.devices()))
-    if args.multichip:
-        phase_multichip(dev, args.seed)
-    else:
-        _require_native()
-        phase_map_storm(dev, args.seed)
-        phase_text(dev, args.seed)
-        phase_all_family(dev, args.seed)
+    phase_text(dev, args.seed)
+    phase_all_family(dev, args.seed)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}), flush=True)

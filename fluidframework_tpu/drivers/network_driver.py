@@ -479,9 +479,9 @@ class StormStream:
     Windowed flow control (round 14): with ``window=N`` at most N frames
     stay in flight (submitted, neither acked nor nacked) — :meth:`submit`
     blocks until the ack watermark frees a slot, so a sender can never
-    build the multi-second socket/ingress backlog BENCH_r10 measured in
-    front of the serving tick (4.0 s of "latency" that was client
-    queueing, not the server). Size the window at least
+    build a multi-second socket/ingress backlog in front of the serving
+    tick (latency that is client queueing, not the server). Size the
+    window at least
     ``server pipeline_depth + 1``: acks lag dispatch by up to ``depth``
     ticks, and a window smaller than that starves the cohort. A
     busy-nack (``retry_after_s``) frees its slot — the frame is dead

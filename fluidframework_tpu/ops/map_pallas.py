@@ -185,12 +185,3 @@ def apply_tick_words_pallas(state: MapState, words: jax.Array,
     zeros = jnp.zeros_like(counts)
     return fold_words(state, words, zeros, counts, base_seq,
                       block_docs=block_docs, interpret=interpret)
-
-
-def apply_tick_words_best(state: MapState, words, counts, base_seq
-                          ) -> MapState:
-    """Pallas VMEM fold on TPU, XLA dense-winner path elsewhere."""
-    from .map_kernel import apply_tick_words
-    if default_interpret():
-        return apply_tick_words(state, words, counts, base_seq)
-    return apply_tick_words_pallas(state, words, counts, base_seq)

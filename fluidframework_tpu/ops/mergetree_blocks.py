@@ -645,18 +645,18 @@ def rebalance(state: BlockMergeState, min_seq: jax.Array,
 # The from-scratch ``_rebalance_impl`` (compact → from_flat → summary
 # rebuild) is exact but pays two full log2(S) shift cascades over every
 # plane — and on head-concentrated streams the danger trigger fires
-# nearly every tick (BENCH_r06: the serving path LOSES to the flat
-# kernel at S=8192, 0.65×). The incremental form below restores the
-# per-block-headroom invariant (ADVICE item 4) by spilling ONLY overfull
-# blocks into their neighbors with LOCAL log-shift spreads (per-block
-# circular rolls — log2(Bk) stages instead of log2(S), one direction in
-# the common case), defers the tombstone zamboni off the hot tick behind
-# a ``blk_tomb`` pressure threshold, and updates summaries only for the
-# blocks the spill touched; cold blocks keep their planes BIT-identical
-# (the ADVICE item 3 exactness proof never re-derives). The decision and
-# the spill are functions of the state alone (plus the static tick
-# width), so a durable-log replay re-decides and re-lays-out
-# byte-identically.
+# nearly every tick (on such streams a full rebuild per tick can cost
+# the block table its lead over the flat kernel at S=8192). The
+# incremental form below restores the per-block-headroom invariant
+# (ADVICE item 4) by spilling ONLY overfull blocks into their neighbors
+# with LOCAL log-shift spreads (per-block circular rolls — log2(Bk)
+# stages instead of log2(S), one direction in the common case), defers
+# the tombstone zamboni off the hot tick behind a ``blk_tomb`` pressure
+# threshold, and updates summaries only for the blocks the spill
+# touched; cold blocks keep their planes BIT-identical (the ADVICE item
+# 3 exactness proof never re-derives). The decision and the spill are
+# functions of the state alone (plus the static tick width), so a
+# durable-log replay re-decides and re-lays-out byte-identically.
 #
 # One conveyor step moves each overfull block's excess one block over —
 # SIMULTANEOUSLY across all blocks, so a chain of at-cap blocks shifts

@@ -165,8 +165,7 @@ class ShardedServing:
                  text_locality: float = 0.0,
                  matrix_vec_slots: int = 0, matrix_handles: int = 0,
                  matrix_k: int = 0,
-                 tree_slots: int = 0, tree_k: int = 0,
-                 pipeline_depth: int = 0) -> None:
+                 tree_slots: int = 0, tree_k: int = 0) -> None:
         if num_docs % mesh.devices.size:
             raise ValueError("num_docs must divide over the mesh")
         self.mesh = mesh
@@ -268,13 +267,6 @@ class ShardedServing:
         #: row -> overflow count from the last tick's tree leg (rank
         #: space exhausted — the host must re-rank; tests size to avoid).
         self.last_tree_overflow: dict[int, int] = {}
-        # Depth-N harvest pipeline (the StormController lesson): a tick's
-        # readbacks start copying at enqueue and are harvested only after
-        # N later ticks are in flight, hiding the device→host round trip
-        # under compute. Depth 0 = synchronous (tick returns its own
-        # harvest — what the failover tests rely on).
-        self.pipeline_depth = max(0, pipeline_depth)
-        self._inflight: list[dict] = []
         # One stage-ledger record per harvested tick (ff.shard.stage.*
         # spans on the profiler's clock); ``wall`` is the harvest-to-
         # harvest cadence. The admit stage is held open from the first
@@ -585,9 +577,6 @@ class ShardedServing:
         # (at-least-once: an assembly failure above must keep them).
         for port in self.hosts:
             self._pending[port.host_id] = {}
-        # Pipeline: start this tick's device→host readback copies at
-        # enqueue; harvest only once ``pipeline_depth`` later ticks are
-        # in flight behind it (depth 0 = synchronous, the default).
         rec = dict(submitted=submitted, records=records,
                    out=(n_seq, first, last), tree_overflow=tree_overflow,
                    text_overflow=text_overflow, kstats=kstats, tick=tick,
@@ -600,19 +589,13 @@ class ShardedServing:
                 copy_async = getattr(arr, "copy_to_host_async", None)
                 if copy_async is not None:
                     copy_async()
-        self._inflight.append(rec)
-        if len(self._inflight) > self.pipeline_depth:
-            return self._harvest_rec(self._inflight.pop(0))
-        return {port.host_id: {} for port in self.hosts}
+        return self._harvest_rec(rec)
 
     def flush(self) -> list[dict[int, dict[int, tuple[int, int, int]]]]:
-        """Drain the harvest pipeline; one {host: {row: ack}} dict per
-        outstanding tick, oldest first (acks must not collapse across
-        ticks — a client matches each to its frame)."""
-        out = []
-        while self._inflight:
-            out.append(self._harvest_rec(self._inflight.pop(0)))
-        return out
+        """Harvests of ticks still outstanding, oldest first: always
+        ``[]``, since :meth:`tick` harvests its own tick and its durable
+        records before it returns."""
+        return []
 
     def _harvest_rec(self, rec: dict
                      ) -> dict[int, dict[int, tuple[int, int, int]]]:
@@ -648,7 +631,7 @@ class ShardedServing:
         with self.ledger.stage("readback", tick):
             self._check_overflow(rec)
         self.ledger.record(tick, 0, len(rec["submitted"]), rec["ops"],
-                           wall_ns=None, depth=self.pipeline_depth)
+                           wall_ns=None)
         return harvest
 
     def _check_overflow(self, rec: dict) -> None:
@@ -787,11 +770,8 @@ class ShardedServing:
         """Durable snapshot of one host's rows across EVERY family state
         (+ text pools + per-row durable-log offsets). The checkpoint/
         offset pair is consistent BY CONSTRUCTION when taken between
-        ticks (tick() is the only writer). Harvests of ticks that were
-        still in the pipeline are returned under ``"drained"`` — each
-        ack matches a client frame, so the caller must deliver them,
-        not drop them."""
-        drained = self.flush()  # durable log must cover in-flight ticks
+        ticks (tick() is the only writer, and it records its own tick
+        durably before it returns)."""
         port = self.hosts[host_id]
         states = {
             name: jax.tree.map(lambda a: _plane_rows(a, port), state)
@@ -800,7 +780,6 @@ class ShardedServing:
             "host_id": host_id,
             "start": port.start,
             "stop": port.stop,
-            "drained": drained,
             "states": states,
             # Back-compat field-dict views of the two always-on families.
             "seq": dict(states["seq"]._asdict()),
@@ -930,7 +909,6 @@ class ShardedServing:
                             rec["cseq0"], rec["ref"], rec["client"],
                             text=rec["text"], pool_base=rec["pool_base"])
             self.tick()
-        self.flush()
 
     # -- observability ---------------------------------------------------------
 
@@ -1233,8 +1211,6 @@ class ShardResidency:
             raise ValueError(
                 f"{doc_id!r} (row {row}) has a pending submission — "
                 "tick before evicting")
-        if s._inflight:
-            s.flush()  # the durable log must cover in-flight ticks
         port1 = HostPort(-1, row, row + 1)
         rec: dict[str, Any] = {
             "states": {
@@ -1423,7 +1399,6 @@ class MegaDocLanes:
         if any(self.serving._pending[p.host_id]
                for p in self.serving.hosts):
             self.serving.tick()  # lane batches still staged: run them
-        self.serving.flush()
         ms = self.serving.map_state
         present = np.asarray(ms.present)
         value = np.asarray(ms.value)

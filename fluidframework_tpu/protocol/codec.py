@@ -346,7 +346,7 @@ def encode_viewer_tick_body(doc_id: str, n_seq: int, first: int,
     window plus the tick's raw op words (``count`` u32 LE — the same
     wire layout storm frames carry in). Encoded ONCE per doc per tick;
     the returned :class:`RawBody` goes down every viewer transport
-    verbatim (the serialize-once invariant BENCH_r13 pins)."""
+    verbatim (the serialize-once invariant)."""
     header = {"op": VIEWER_TICK_OP, "doc": doc_id, "n": n_seq,
               "first": first, "last": last, "msn": msn, "count": count}
     return RawBody(encode_storm_body(header, words))

@@ -91,10 +91,10 @@ class MessageBus:
     log: once every registered consumer group has committed past a
     message AND the partition holds more than the horizon, the consumed
     prefix is trimmed (the Kafka ``log.retention`` analog — the service
-    tier's message history stops scaling with total history; BENCH_r12's
-    residual cold-doc RAM slope lived exactly here). Nothing uncommitted
-    is ever trimmed: one lagging group pins the log, exactly like a slow
-    Kafka consumer pins its segment."""
+    tier's message history stops scaling with total history; without
+    it, RAM grows with every cold doc's consumed messages). Nothing
+    uncommitted is ever trimmed: one lagging group pins the log, exactly
+    like a slow Kafka consumer pins its segment."""
 
     def __init__(self, retention_messages: int | None = None) -> None:
         self._topics: dict[str, Topic] = {}

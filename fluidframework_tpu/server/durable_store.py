@@ -381,17 +381,10 @@ class GroupCommitLog:
 
     def __init__(self, path: str | os.PathLike, max_queue: int = 256,
                  fsync: bool = True,
-                 breaker: CircuitBreaker | None = None,
-                 commit_latency_s: float = 0.0) -> None:
+                 breaker: CircuitBreaker | None = None) -> None:
         self._log = OpLog(path)
         self._path = Path(path)  # rewrite_records replace target
         self._fsync = fsync
-        # Modeled additional commit latency per fsync BATCH (writer
-        # thread only, after the real fsync): benches use it to put the
-        # WAL in the replicated-log regime (quorum append / networked
-        # disk) where a host's commit round trip — not its CPU — bounds
-        # its serving rate. 0 (default, production) = local disk only.
-        self._commit_latency_s = max(0.0, commit_latency_s)
         # Serializes ALL OpLog access: neither backend is thread-safe
         # (the Python one shares a single seek position between read and
         # append; the native one grows its index vector unsynchronized),
@@ -563,12 +556,6 @@ class GroupCommitLog:
                         with ProgramSpan("wal.fsync"):
                             self._log.sync()
                         self.fsync_ns += time.monotonic_ns() - t_sync
-                if self._fsync and self._commit_latency_s:
-                    # Modeled commit round trip OUTSIDE the io lock:
-                    # it delays the durable watermark (as a replicated
-                    # log's quorum ack would), never reads of records
-                    # already appended to the local file.
-                    time.sleep(self._commit_latency_s)
                 faults.crashpoint("wal.post_fsync")
                 ship = self.on_batch_durable
                 if ship is not None:

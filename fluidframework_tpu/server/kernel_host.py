@@ -168,10 +168,6 @@ class KernelSequencerHost:
         self._free_rows.append(row)
         return row
 
-    @property
-    def resident_docs(self) -> int:
-        return len(self._rows)
-
     def _grow_rows(self) -> None:
         old = self._capacity
         self._capacity = old * 2

@@ -274,8 +274,8 @@ class ScriptoriumDocumentLambda:
     per horizon's worth of appends). Catch-up reads older than the
     horizon become impossible (clients that far behind reload from a
     snapshot) — the same trade the storm tier's
-    ``doc_index_retention_ticks`` makes, and the rest of BENCH_r12's
-    service-plane RAM slope."""
+    ``doc_index_retention_ticks`` makes, and it bounds the rest of the
+    service plane's RAM growth with total history."""
 
     def __init__(self, doc_id: str, store: StateStore,
                  retention_ops: int | None = None) -> None:

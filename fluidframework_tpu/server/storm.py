@@ -365,34 +365,6 @@ STORM_WAL_VERSION = 3
 STORM_SNAPSHOT_VERSION = 3
 
 
-def choose_pipeline_depth(attribution: dict, current: int = 1) -> int:
-    """Pick the serving pipeline depth from OBSERVED stage attribution
-    (the round-15 adaptive-depth satellite). BENCH_r14's depth-scaling
-    rows are the evidence: at the 10k-doc shape the group fsync is
-    commensurate with the dispatch (wal_commit_wait 0.52 vs
-    device_dispatch 0.41 of the tick) and overlapping them wins 1.48x;
-    at the 2048-doc shape blobs are small, the fsync is cheap, and the
-    SERIAL tick wins (pipelining pays a staging generation + lagged acks
-    for nothing). So: commit-wait under a quarter of the dispatch time
-    -> depth 0 (serial); at least half -> depth >= 1 (overlap); the band
-    between is hysteresis (keep the current depth — flapping would
-    resize the staging ring every window). Needs >= 8 ticks of ledger
-    window to act; returns ``current`` until then."""
-    win = attribution.get("_window", {})
-    if win.get("ticks", 0) < 8:
-        return current
-    commit = attribution.get("wal_commit_wait", {}).get("total_ms", 0.0)
-    dispatch = attribution.get("device_dispatch", {}).get("total_ms", 0.0)
-    if dispatch <= 0.0:
-        return current
-    ratio = commit / dispatch
-    if ratio < 0.25:
-        return 0
-    if ratio >= 0.5:
-        return max(current, 1)
-    return current
-
-
 class StormController:
     """Buffers storm frames and runs the fused tick over the REAL hosts:
     the service's batched deli (KernelSequencerHost) and merge host
@@ -415,13 +387,18 @@ class StormController:
 
     #: Per-op count sanity bound (one doc's batch within one frame).
     MAX_COUNT = 1 << 16
+    #: Weighted-shed borrow threshold: a tenant past its weighted
+    #: pending share still buffers while the GLOBAL queue is below this
+    #: fraction of max_pending_docs (work conservation); past it, the
+    #: over-share tenant sheds first.
+    QOS_BORROW_FRACTION = 0.5
 
     def __init__(self, service, seq_host: KernelSequencerHost,
                  merge_host: KernelMergeHost, datastore: str = "default",
                  channel: str = "root",
                  flush_threshold_docs: int = 4096,
                  max_key_slots: int = 64,
-                 pipeline_depth: int | str = 1,
+                 pipeline_depth: int = 1,
                  spill_dir: str | None = None,
                  durability: str | None = None,
                  snapshots=None,
@@ -430,11 +407,9 @@ class StormController:
                  max_pending_docs: int | None = None,
                  busy_retry_s: float = 0.05,
                  doc_index_retention_ticks: int | None = None,
-                 wal_commit_latency_s: float = 0.0,
                  tenant_weights: dict[str, float] | None = None,
                  tenant_weight_source=None,
                  tick_slot_budget: int | None = None,
-                 qos_borrow_fraction: float = 0.5,
                  logger=None) -> None:
         self.service = service
         self.seq_host = seq_host
@@ -511,10 +486,7 @@ class StormController:
             path = root / "storm_tick_words.log"
             self._spill_path = path  # trim_tick_blobs rewrite target
             if durability == "group":
-                # commit_latency_s models a replicated durable log's
-                # quorum round trip (bench regime); 0 = local disk.
-                self._group_wal = GroupCommitLog(
-                    path, commit_latency_s=wal_commit_latency_s)
+                self._group_wal = GroupCommitLog(path)
                 self._blob_log = self._group_wal
             else:
                 self._blob_log = OpLog(path)
@@ -569,11 +541,6 @@ class StormController:
                                    weight_source=tenant_weight_source,
                                    registry=merge_host.metrics)
         self.tick_slot_budget = tick_slot_budget
-        # Weighted-shed borrow threshold: a tenant past its weighted
-        # pending share still buffers while the GLOBAL queue is below
-        # this fraction of max_pending_docs (work conservation); past
-        # it, the over-share tenant sheds first.
-        self.qos_borrow_fraction = qos_borrow_fraction
         # Quarantine plane: doc -> {"reason", "tick"}. A quarantined doc
         # is frozen out of cohorts (submits nack retryable) and serves
         # reads through the scalar record fold until readmit_doc().
@@ -655,26 +622,16 @@ class StormController:
         # BEFORE staging the next one, so tick N's WAL append (and its
         # group fsync, on the writer thread) starts as soon as N's
         # readback lands and then runs CONCURRENT with tick N+1's
-        # scatter + device dispatch — the two dominant stages of the
-        # durable tick (BENCH_r10: wal_commit_wait 0.52 + device_dispatch
-        # 0.41, formerly back-to-back). Acks stay withheld on the durable
-        # watermark exactly as before — they lag dispatch by ≤ depth
-        # ticks, which is why flow-controlled senders size their window
-        # ≥ depth + 1. Depth 1 is the default; depth 0 is the serial
-        # fallback (dispatch → readback → append → fsync barrier → ack,
-        # per round — the pre-pipelining shape, kept as the A/B twin and
-        # for request-response senders that gate on every ack).
-        # pipeline_depth="auto" (the round-15 adaptive-depth satellite):
-        # start overlapped and re-decide from the ledger's OBSERVED
-        # wal_commit_wait vs device_dispatch shares every adaptation
-        # window (choose_pipeline_depth) — the BENCH_r14 depth-scaling
-        # rows showed the serial tick wins exactly where the fsync is
-        # cheap, which no static constant can know up front.
-        self._auto_depth = pipeline_depth == "auto"
-        self._depth_adapted_at = 0
-        self.depth_adapt_every = 64  # ticks between adaptation checks
-        self.pipeline_depth = 1 if self._auto_depth \
-            else max(0, pipeline_depth)
+        # scatter + device dispatch — two of the durable tick's largest
+        # stages, which would otherwise run back-to-back. Acks stay
+        # withheld on the durable watermark exactly as before — they lag
+        # dispatch by ≤ depth ticks, which is why flow-controlled
+        # senders size their window ≥ depth + 1. Depth 1 is the default;
+        # depth 0 is the serial fallback (dispatch → readback → append →
+        # fsync barrier → ack, per round — the pre-pipelining shape, kept
+        # as the A/B twin and for request-response senders that gate on
+        # every ack).
+        self.pipeline_depth = max(0, pipeline_depth)
         self._inflight: list[dict] = []
         # Host staging generations (double/N-buffered): the scatter
         # arrays a dispatched tick's device transfer may still alias are
@@ -943,7 +900,7 @@ class StormController:
                 cap is not None
                 and self.qos.pending_docs.get(tenant_id, 0) + n > cap
                 and self._pending_docs + n > self.max_pending_docs
-                * self.qos_borrow_fraction)
+                * self.QOS_BORROW_FRACTION)
             if over_global or over_share:
                 self.qos.note_shed(tenant_id, n_ops)
                 return self._shed(
@@ -1042,8 +999,8 @@ class StormController:
                 >= self.snapshot_interval_ticks):
             self.checkpoint()
         # Maintenance cadence OFF the per-tick path: mega-doc auto
-        # promotion/demotion and the adaptive pipeline depth re-decide
-        # here (never inside a round), then the RSS arena trim.
+        # promotion/demotion re-decides here (never inside a round),
+        # then the RSS arena trim.
         if self.megadoc is not None and not self._replay:
             self.megadoc.maybe_adapt()
         if self.history is not None and not self._replay \
@@ -1051,28 +1008,11 @@ class StormController:
             # Summarization compaction cadence (server/history.py): roll
             # long WAL tails into fresh summaries + trim per retention.
             self.history.maybe_compact()
-        if self._auto_depth and not self._replay and (
-                self.stats["ticks"] - self._depth_adapted_at
-                >= self.depth_adapt_every):
-            self._depth_adapted_at = self.stats["ticks"]
-            self.set_pipeline_depth(choose_pipeline_depth(
-                self.ledger.attribution(), self.pipeline_depth))
         # RSS hygiene OFF the per-tick path: at most one arena trim per
         # flush, gated on tick count AND a wall-clock floor (the round-5
         # serving-loop stall suspect — see _TrimGate).
         if self._trim_gate.due(self.stats["ticks"]):
             _malloc_trim()
-
-    def set_pipeline_depth(self, depth: int) -> None:
-        """Change the serving pipeline depth between rounds: settle the
-        in-flight ticks first (a shrink must not orphan them), then the
-        staging-generation ring resizes lazily on the next round."""
-        depth = max(0, int(depth))
-        if depth == self.pipeline_depth:
-            return
-        self._harvest()
-        self.pipeline_depth = depth
-        self.merge_host.metrics.gauge("storm.pipeline.depth").set(depth)
 
     @property
     def wal_degraded(self) -> bool:
@@ -1324,8 +1264,8 @@ class StormController:
         # moment it matters and its WAL append reaches the writer thread
         # NOW — the group fsync then runs concurrent with this round's
         # scatter + device dispatch instead of queueing behind them
-        # (BENCH_r10 measured the two stages back-to-back at 0.52 + 0.41
-        # of every durable tick). This also frees the harvested tick's
+        # (run back-to-back, the two would add up to most of a durable
+        # tick). This also frees the harvested tick's
         # staging generation for reuse below, and it must precede the
         # mega cohort transform: the combiner may journal CONTROL
         # records, which have to land AFTER the due tick's WAL record so
@@ -1562,13 +1502,8 @@ class StormController:
         a frame scattered into generation B while generation A is in
         flight must never touch A's device feed. A geometry change
         (capacity growth, a different per-round K) reallocates just the
-        generation it lands on; a runtime pipeline_depth change resizes
-        the ring."""
-        n = self.pipeline_depth + 1
-        if len(self._staging) != n:
-            self._staging = [None] * n
-            self._staging_idx = 0
-        self._staging_idx = (self._staging_idx + 1) % n
+        generation it lands on."""
+        self._staging_idx = (self._staging_idx + 1) % len(self._staging)
         gen = self._staging[self._staging_idx]
         if gen is None or gen["shape"] != (b_seq, b_map, k):
             gen = {
@@ -1768,7 +1703,7 @@ class StormController:
             # frame (sequenced window + raw words) serialized ONCE per doc
             # and fanned out in one batched publish — encodes-per-tick ==
             # hot docs with viewers, independent of viewer count (the
-            # serialize-once invariant BENCH_r13 pins). Words resolve
+            # serialize-once invariant). Words resolve
             # straight from each frame's receive-buffer view (the same
             # positional layout the WAL appends); only frames CONTAINING a
             # viewer doc pay an offsets walk — a 10k-doc tick with one

@@ -4,7 +4,7 @@ The serving host's device programs recompile whenever a pool migrates to
 a new shape bucket (segment slots, op-batch k, prop planes, overlap
 words all double on demand). Within one process the in-memory jit cache
 dedups identical shapes; across restarts — a serving host rolling, a
-farm re-running, bench.py re-invoked — every bucket shape would pay its
+farm re-running, a benchmark re-invoked — every bucket shape would pay its
 full XLA compile again. The reference ships its lambdas warm for the
 same reason (a routerlicious pod restart does not re-JIT V8 code from
 scratch); here the equivalent is JAX's persistent compilation cache

@@ -1,9 +1,10 @@
 """Test config: run JAX on a virtual 8-device CPU mesh.
 
 Tests run on the CPU (``JAX_PLATFORMS=cpu``) and validate semantics and
-sharding on a host-platform device mesh; the chip is driven by
-``python chip_smoke.py`` through the chip tool, and
-tests/test_tpu_compile.py compiles the kernels for a described v5e.
+sharding on a host-platform device mesh. On the chip, the benchmark
+cells (``benchmark/run.py``) and ``python chip_smoke.py`` (the paths no
+cell runs yet) drive the serving paths, and tests/test_tpu_compile.py
+compiles the kernels for a described v5e.
 """
 
 import os

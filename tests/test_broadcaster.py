@@ -403,8 +403,8 @@ class TestViewerOverAlfred:
 
 class TestServicePlaneRetention:
     """Round-13 satellite: the in-process bus partitions + per-doc ops
-    store take an opt-in retention horizon (the BENCH_r12 residual
-    ~11 KB/cold-doc tier)."""
+    store take an opt-in retention horizon (without one, each cold doc
+    leaves its whole message history in RAM)."""
 
     def test_bus_partitions_trim_below_slowest_group(self):
         from fluidframework_tpu.server.bus import Consumer, MessageBus
@@ -477,9 +477,8 @@ class TestServicePlaneRetention:
         bus2.close()
 
     def test_bus_retention_keeps_service_plane_ram_bounded(self):
-        """The closing evidence for BENCH_r12's residual slope: with the
-        horizon on, a long op stream leaves O(horizon) messages in the
-        bus partitions instead of O(history)."""
+        """With the horizon on, a long op stream leaves O(horizon)
+        messages in the bus partitions instead of O(history)."""
         from fluidframework_tpu.server.bus import MessageBus
         bus = MessageBus(retention_messages=32)
         service = RouterliciousService(bus=bus, ops_retention=32)

@@ -203,8 +203,12 @@ class TestOverloadSmoke:
     violated invariant; the asserts here double-check the report shape."""
 
     def test_throttle_under_storm(self, tmp_path):
+        # Exact counts only: at 8 docs x 16 ops a tick is a few ms of
+        # fixed Python cost, so a wall-clock ratio here measures the
+        # machine's other load. The latency bar runs at the serving
+        # shape in test_overload_full_matrix.
         report = chaos.run_overload(str(tmp_path), num_docs=8, k=16,
-                                    rounds=6)
+                                    rounds=6, p99_factor=None)
         assert report["shed_rate"] == 0.5  # exactly the 2x overflow shed
         assert report["acked_frames"] == report["shed_frames"] == 48
 

@@ -34,7 +34,6 @@ from fluidframework_tpu.server.merge_host import KernelMergeHost
 from fluidframework_tpu.server.routerlicious import RouterliciousService
 from fluidframework_tpu.server.storm import (
     StormController,
-    choose_pipeline_depth,
     materialize_storm_records,
 )
 
@@ -324,76 +323,6 @@ def test_sharded_tier_runs_on_forced_multidevice_mesh(cpu_mesh_devices):
     assert t_mega == t_twin
     assert stats["megadoc_promotions"] == 1
     assert stats["megadoc_demotions"] == 1
-
-
-# -- adaptive pipeline depth (satellite) --------------------------------------
-
-
-def _attribution(commit_ms, dispatch_ms, ticks=16):
-    return {"_window": {"ticks": ticks},
-            "wal_commit_wait": {"total_ms": commit_ms},
-            "device_dispatch": {"total_ms": dispatch_ms}}
-
-
-def test_choose_pipeline_depth_pins_both_regimes():
-    """BENCH_r14's two regimes: commit-wait commensurate with dispatch
-    (the 10k shape: 0.52 vs 0.41 shares) -> overlap; fsync cheap (the
-    2048 shape) -> serial. The band between is hysteresis, and a short
-    ledger window never flips the depth."""
-    # 10k-doc regime: commit 0.52 / dispatch 0.41 of the tick.
-    assert choose_pipeline_depth(_attribution(520.0, 410.0), 0) == 1
-    assert choose_pipeline_depth(_attribution(520.0, 410.0), 2) == 2
-    # 2048-doc regime: fsync far below the dispatch -> serial wins.
-    assert choose_pipeline_depth(_attribution(20.0, 400.0), 1) == 0
-    assert choose_pipeline_depth(_attribution(20.0, 400.0), 0) == 0
-    # Hysteresis band: keep whatever is running.
-    assert choose_pipeline_depth(_attribution(150.0, 400.0), 0) == 0
-    assert choose_pipeline_depth(_attribution(150.0, 400.0), 1) == 1
-    # Too little evidence: no change.
-    assert choose_pipeline_depth(_attribution(520.0, 410.0, ticks=3),
-                                 0) == 0
-    assert choose_pipeline_depth({}, 1) == 1
-
-
-def test_auto_depth_adapts_from_observed_ledger(tmp_path):
-    """pipeline_depth="auto" re-decides from the REAL ledger at the
-    adaptation cadence: a run whose commit-wait stays trivial adapts
-    down to the serial tick."""
-    svc, storm, seq, mh, _ = build_stack(str(tmp_path),
-                                         pipeline_depth="auto")
-    assert storm.pipeline_depth == 1 and storm._auto_depth
-    storm.depth_adapt_every = 1
-    doc = "d"
-    client = svc.connect(doc, lambda m: None).client_id
-    svc.pump()
-    for r in range(12):
-        storm.submit_frame(None, {"rid": r,
-                                  "docs": [[doc, client, 1 + r * 4, 1, 4]]},
-                           memoryview(storm_words(3, r, 0, k=4).tobytes()))
-        storm.flush()
-    # Tiny ticks on tmpfs: the fsync is far below the dispatch, so the
-    # auto policy must have settled on the serial fallback.
-    assert storm.pipeline_depth == 0
-    att = storm.ledger.attribution()
-    assert att["_window"]["ticks"] >= 8
-    storm._group_wal.close()
-
-
-def test_set_pipeline_depth_settles_inflight(tmp_path):
-    svc, storm, seq, mh, _ = build_stack(str(tmp_path), pipeline_depth=2)
-    doc = "d"
-    client = svc.connect(doc, lambda m: None).client_id
-    svc.pump()
-    storm.submit_frame(None, {"rid": 0, "docs": [[doc, client, 1, 1, 4]]},
-                       memoryview(storm_words(4, 0, 0, k=4).tobytes()))
-    storm._flush_round()
-    assert storm._inflight
-    storm.set_pipeline_depth(0)
-    assert not storm._inflight
-    assert storm.pipeline_depth == 0
-    assert mh.metrics.gauge("storm.pipeline.depth").value == 0
-    storm.flush()
-    storm._group_wal.close()
 
 
 # -- auto promotion / demotion ------------------------------------------------

@@ -253,7 +253,7 @@ def test_rebalance_preserves_future_resolution(seed):
 
 def gen_head_stream(rng, n_ops):
     """Adversarial head-concentrated stream: every structural op lands
-    at the document head (the BENCH_r06 known-loss shape — the
+    at the document head (the block table's worst shape — the
     incremental-rebalance trigger fires at the maximum rate)."""
     ops, length, pool = [], 0, 0
     for seq in range(1, n_ops + 1):

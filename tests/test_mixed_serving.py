@@ -422,61 +422,6 @@ def test_matrix_handles_survive_failover(mesh):
     assert grid == [[77, None], [11, 33]], grid
 
 
-def test_pipelined_harvest_matches_sync(mesh):
-    """Depth-2 harvest pipeline: acks lag ≤ 2 ticks, flush() drains the
-    debt, and the device state + durable log match the synchronous
-    assembly bit-for-bit."""
-    def drive(depth):
-        serving = ShardedServing(mesh, num_docs=8, k=4, num_hosts=2,
-                                 num_clients=2, text_slots=32,
-                                 pipeline_depth=depth)
-        serving.join_all(slots=(0, 1))
-        acks = []
-        for t in range(5):
-            for row in range(8):
-                if row % 2:
-                    serving.submit_text(
-                        row, [dict(kind=mtk.MT_INSERT, pos=0,
-                                   text=f"t{t}")],
-                        first_cseq=t + 1, ref_seq=2 + t, client_slot=0)
-                else:
-                    words = np.array([(row << 2) | ((t + 1) << 12)],
-                                     np.uint32)
-                    serving.submit(row, words, first_cseq=t + 1,
-                                   ref_seq=2 + t)
-            acks.append(serving.tick())
-        tail = serving.flush()  # list of per-tick harvests, oldest first
-        return serving, acks, tail
-
-    sync, sync_acks, _ = drive(0)
-    piped, piped_acks, piped_tail = drive(2)
-    # Sync acks arrive same-tick; pipelined ones lag by exactly depth.
-    assert all(rows for h in sync_acks for rows in h.values())
-    assert not any(piped_acks[0][h] for h in (0, 1))
-    assert not any(piped_acks[1][h] for h in (0, 1))
-    assert any(piped_acks[2][h] for h in (0, 1))
-    # Every submitted tick is acked once the pipe drains.
-    got = {0: [], 1: []}
-    for h in piped_acks + piped_tail:
-        for host, rows in h.items():
-            for row, ack in rows.items():
-                got[host].append((row, ack))
-    want = {0: [], 1: []}
-    for h in sync_acks:
-        for host, rows in h.items():
-            for row, ack in rows.items():
-                want[host].append((row, ack))
-    assert sorted(got[0]) == sorted(want[0])
-    assert sorted(got[1]) == sorted(want[1])
-    for field in mtb.BlockMergeState._fields:
-        assert np.array_equal(
-            np.asarray(getattr(piped.merge_state, field)),
-            np.asarray(getattr(sync.merge_state, field))), field
-    assert np.array_equal(piped.map_rows(), sync.map_rows())
-    assert {r: len(v) for r, v in piped.durable.items()} == \
-        {r: len(v) for r, v in sync.durable.items()}
-
-
 def test_text_capacity_guard_and_compact(mesh):
     """Admission rejects a text batch whose worst-case slot growth would
     silently overflow the device table; compact_text() (the device

@@ -97,6 +97,45 @@ def test_kernel_dedup_across_sharded_ticks(mesh):
             assert second[port.host_id][row][0] == 0  # all duplicates
 
 
+@pytest.mark.parametrize("text_slots", [0, 32], ids=["map_tick",
+                                                     "mixed_tick"])
+def test_tick_acks_its_own_tick_and_flush_is_empty(mesh, text_slots):
+    """The synchronous contract: tick() returns the acks of the tick it
+    ran, its durable records already appended, and flush() has nothing
+    left to return. Covers both device programs (the map-only storm tick
+    and the fused mixed tick)."""
+    from fluidframework_tpu.ops import mergetree_kernel as mtk
+
+    num_docs, k = 8, 4
+    serving = ShardedServing(mesh, num_docs=num_docs, k=k, num_hosts=2,
+                             num_clients=2, text_slots=text_slots)
+    serving.join_all()
+    last = {row: 1 for row in range(num_docs)}  # the join's seq
+    for t in range(3):
+        for row in range(num_docs):
+            if text_slots and row % 2:
+                serving.submit_text(
+                    row, [dict(kind=mtk.MT_INSERT, pos=0, text="ab")] * k,
+                    first_cseq=1 + t * k, ref_seq=last[row])
+            else:
+                words = np.full(k, (row << 2) | ((t + 1) << 12), np.uint32)
+                serving.submit(row, words, first_cseq=1 + t * k,
+                               ref_seq=last[row])
+        harvest = serving.tick()
+        for port in serving.hosts:
+            rows = harvest[port.host_id]
+            assert set(rows) == set(range(port.start, port.stop))
+            for row, ack in rows.items():
+                assert ack == (k, last[row] + 1, last[row] + k), (t, row)
+                rec = serving.durable[row][-1]
+                assert (rec["n_seq"], rec["first"], rec["last"]) == ack
+                assert serving.durable_offset(row) == t + 1
+                last[row] = ack[2]
+        assert serving.flush() == []
+        assert serving.ledger.records()[-1]["depth"] == 0
+    assert serving.ledger.records()[-1]["tick"] == 2
+
+
 def test_global_metrics_psum(mesh):
     serving = ShardedServing(mesh, num_docs=16, k=4, num_hosts=4)
     serving.join_all()

@@ -30,14 +30,15 @@ from fluidframework_tpu.server.routerlicious import RouterliciousService
 from fluidframework_tpu.server.storm import StormController
 
 
-def make_service(num_docs=8, flush_threshold_docs=10**9):
+def make_service(num_docs=8, flush_threshold_docs=10**9, **storm_kw):
     seq_host = KernelSequencerHost(num_slots=2, initial_capacity=num_docs)
     merge_host = KernelMergeHost(flush_threshold=10**9)
     service = RouterliciousService(merge_host=merge_host,
                                    batched_deli_host=seq_host,
                                    auto_pump=False)
     storm = StormController(service, seq_host, merge_host,
-                            flush_threshold_docs=flush_threshold_docs)
+                            flush_threshold_docs=flush_threshold_docs,
+                            **storm_kw)
     return service, storm, merge_host
 
 
@@ -298,8 +299,8 @@ def test_pipeline_depth_streams_acks_behind_compute():
     """pipeline_depth > 1 with a STREAMING sender (not ack-gated): acks
     lag by <= depth ticks while in flight, every frame is eventually
     acked exactly once, and the map state equals the scalar replay."""
-    service, storm, merge_host = make_service(flush_threshold_docs=2)
-    storm.pipeline_depth = 3
+    service, storm, merge_host = make_service(flush_threshold_docs=2,
+                                              pipeline_depth=3)
     docs = ["a", "b"]
     clients = join_docs(service, docs)
     rng = np.random.default_rng(5)

@@ -436,10 +436,10 @@ class TestDeviceKstats:
 def test_tracing_overhead_guard(shape):
     """Overhead guard (satellite): tracing every frame must not visibly
     tax tick throughput — the per-frame cost is a couple of dict writes
-    and ns reads. The bench (BENCH_r10) measures the <2% bar at the
-    DEFAULT 1-in-64 sample on the full socket path; this smoke bounds
-    the in-process worst case (sample EVERY frame) loosely enough to
-    stay deterministic under CI noise."""
+    and ns reads. The <2% bar at the DEFAULT 1-in-64 sample on the full
+    socket path is not measured; this smoke bounds the in-process worst
+    case (sample EVERY frame) loosely enough to stay deterministic under
+    CI noise."""
     num_docs, k, ticks = shape
 
     def timed_run(tc_from):
@@ -454,6 +454,5 @@ def test_tracing_overhead_guard(shape):
 
     base = min(timed_run(None) for _ in range(2))
     traced = min(timed_run(10_000) for _ in range(2))
-    # Loose CI bound; the real <2% acceptance figure is measured by
-    # bench.py --e2e-r10 on the long socket run.
+    # Loose CI bound, not the <2% acceptance figure.
     assert traced <= base * 1.5, (traced, base)
