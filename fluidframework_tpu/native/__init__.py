@@ -14,10 +14,12 @@ logs are portable between the two.
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
 import os
 import struct
 import zlib
+from array import array
 from pathlib import Path
 
 from ._loader import build_and_load
@@ -40,6 +42,9 @@ def _load_library() -> ctypes.CDLL | None:
     lib.oplog_append.restype = ctypes.c_long
     lib.oplog_append.argtypes = [ctypes.c_void_p, ctypes.c_char_p,
                                  ctypes.c_uint32]
+    lib.oplog_append_many.restype = ctypes.c_long
+    lib.oplog_append_many.argtypes = [ctypes.c_void_p, ctypes.c_char_p,
+                                      ctypes.c_void_p, ctypes.c_uint32]
     lib.oplog_sync.restype = ctypes.c_int
     lib.oplog_sync.argtypes = [ctypes.c_void_p]
     lib.oplog_read_len.restype = ctypes.c_long
@@ -69,6 +74,19 @@ class _NativeOpLog:
         idx = self._lib.oplog_append(self._handle, data, len(data))
         if idx < 0:
             raise OSError("oplog_append failed")
+        return idx
+
+    def append_many(self, payloads: list[bytes]) -> int:
+        """Append ``payloads`` as ``append`` would, one by one, with one
+        write; returns the index of the first."""
+        if not payloads:
+            return len(self)
+        lens = array("I", map(len, payloads))
+        idx = self._lib.oplog_append_many(
+            self._handle, b"".join(payloads), lens.buffer_info()[0],
+            len(lens))
+        if idx < 0:
+            raise OSError("oplog_append_many failed")
         return idx
 
     def read(self, index: int) -> bytes:
@@ -125,13 +143,30 @@ class _PythonOpLog:
         return len(self._index)
 
     def append(self, data: bytes) -> int:
+        return self.append_many([data])
+
+    def append_many(self, payloads: list[bytes]) -> int:
+        first = len(self._index)
+        if not payloads:
+            return first
+        frames = b"".join(struct.pack("<II", len(p), zlib.crc32(p)) + p
+                          for p in payloads)
         self._fh.seek(self._end)
-        self._fh.write(struct.pack("<II", len(data), zlib.crc32(data)))
-        self._fh.write(data)
-        self._fh.flush()
-        self._index.append((self._end + 8, len(data)))
-        self._end += 8 + len(data)
-        return len(self._index) - 1
+        try:
+            self._fh.write(frames)
+            self._fh.flush()
+        except OSError:
+            # Append mode writes at the end of the file: cut a partial
+            # tail so that the next append lands at ``_end``.
+            with contextlib.suppress(OSError):
+                self._fh.truncate(self._end)
+            raise
+        pos = self._end
+        for p in payloads:
+            self._index.append((pos + 8, len(p)))
+            pos += 8 + len(p)
+        self._end = pos
+        return first
 
     def read(self, index: int) -> bytes:
         offset, length = self._index[index]

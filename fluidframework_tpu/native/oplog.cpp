@@ -95,6 +95,47 @@ long oplog_append(OpLog* log, const uint8_t* data, uint32_t len) {
     return (long)log->index.size() - 1;
 }
 
+// Frames `n` payloads (concatenated in `data`, their lengths in `lens`)
+// exactly as `n` oplog_append calls would, and writes them all with one
+// pwrite loop at `end`. Returns the index of the first, or -1 with `end`
+// and the index unchanged (a partial tail is cut back to `end`).
+long oplog_append_many(OpLog* log, const uint8_t* data,
+                       const uint32_t* lens, uint32_t n) {
+    if (!log || log->fd < 0) return -1;
+    size_t total = 0;
+    for (uint32_t i = 0; i < n; ++i) total += 8 + (size_t)lens[i];
+    std::vector<uint8_t> buf(total);
+    uint8_t* out = buf.data();
+    const uint8_t* in = data;
+    for (uint32_t i = 0; i < n; ++i) {
+        uint32_t len = lens[i];
+        uint32_t crc = crc32(0L, in, len);
+        memcpy(out, &len, 4);
+        memcpy(out + 4, &crc, 4);
+        memcpy(out + 8, in, len);
+        out += 8 + (size_t)len;
+        in += len;
+    }
+    size_t done = 0;
+    while (done < total) {
+        ssize_t w = pwrite(log->fd, buf.data() + done, total - done,
+                           log->end + (off_t)done);
+        if (w <= 0) {
+            if (ftruncate(log->fd, log->end) != 0) { /* open() cuts it */ }
+            return -1;
+        }
+        done += (size_t)w;
+    }
+    long first = (long)log->index.size();
+    off_t pos = log->end;
+    for (uint32_t i = 0; i < n; ++i) {
+        log->index.push_back({pos + 8, lens[i]});
+        pos += 8 + (off_t)lens[i];
+    }
+    log->end = pos;
+    return first;
+}
+
 int oplog_sync(OpLog* log) {
     if (!log || log->fd < 0) return -1;
     return fdatasync(log->fd);

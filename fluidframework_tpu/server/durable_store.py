@@ -185,20 +185,37 @@ def _wire(x: Any, bad: list) -> Any:
     return to_wire(x)
 
 
-class _WriteCounters:
-    """The write path's counters, looked up once per registry:
-    ``durable.records`` (records written) and ``durable.replay_decodes``
-    (those whose in-memory copy still took the decode)."""
+class _WriteStage:
+    """The write path of a durable bus and state store: its counters,
+    looked up once per registry (``durable.records``, records written;
+    ``durable.replay_decodes``, those whose in-memory copy still took the
+    decode; ``durable.writes``, file writes that put staged records on
+    their logs), and the records encoded but not yet on their files.
 
-    __slots__ = ("registry", "records", "decodes")
+    A record waits here until the next flush, which writes each log's
+    records with one ``append_many``. The bus flushes before every offset
+    record, and every fsync, compaction and close of a staged log flushes
+    first (a staged log is read only as it opens); so at each of those
+    points the files hold exactly the bytes that writing each record at
+    once would have left. A service's store adopts its bus's stage, so
+    that a flush covers both."""
+
+    #: Staged bytes past which a stage flushes on its own.
+    LIMIT_BYTES = 4 << 20
+
+    __slots__ = ("registry", "records", "decodes", "writes", "_held",
+                 "_bytes")
 
     def __init__(self, registry: MetricsRegistry | None = None) -> None:
         self.bind(registry if registry is not None else MetricsRegistry())
+        self._held: dict[Any, list[bytes]] = {}  # OpLog -> its records
+        self._bytes = 0
 
     def bind(self, registry: MetricsRegistry) -> None:
         self.registry = registry
         self.records = registry.counter("durable.records")
         self.decodes = registry.counter("durable.replay_decodes")
+        self.writes = registry.counter("durable.writes")
 
     def encode(self, head: list, value: Any) -> tuple[bytes, Any]:
         """The bytes ``_dump(head + [value])`` writes, and the in-memory
@@ -213,20 +230,49 @@ class _WriteCounters:
         self.decodes.inc()
         return data, _load(data)[-1]
 
+    def stage(self, log, data: bytes) -> None:
+        """Hold ``data`` for ``log`` until the next flush (first flushing
+        what is held past ``LIMIT_BYTES``, so that a failed write raises
+        before ``data`` is held)."""
+        if self._bytes > self.LIMIT_BYTES:
+            self.flush()
+        held = self._held.get(log)
+        if held is None:
+            self._held[log] = [data]
+        else:
+            held.append(data)
+        self._bytes += len(data)
+
+    def flush(self) -> None:
+        """Write every staged record, one ``append_many`` a log. A log
+        whose write fails keeps its records staged."""
+        held = self._held
+        while held:
+            log = next(iter(held))
+            log.append_many(held[log])
+            del held[log]
+            self.writes.inc()
+        self._bytes = 0
+
 
 class _CountedWrites:
     """``metrics``: the registry a durable log's write path counts into
-    (the service hands over its own)."""
+    (the service hands over its own); ``stage``: the write stage its
+    records wait in (a store adopts its service's bus's)."""
 
-    _counters: _WriteCounters
+    _stage: _WriteStage
 
     @property
     def metrics(self) -> MetricsRegistry:
-        return self._counters.registry
+        return self._stage.registry
 
     @metrics.setter
     def metrics(self, registry: MetricsRegistry) -> None:
-        self._counters.bind(registry)
+        self._stage.bind(registry)
+
+    @property
+    def stage(self) -> _WriteStage:
+        return self._stage
 
 
 def _copy_containers(value: Any) -> Any:
@@ -612,13 +658,13 @@ class GroupCommitLog:
 
 
 class _DurablePartition:
-    """In-memory view append-through to an op log file."""
+    """In-memory view of an op log file; appends reach the file at the
+    stage's next flush."""
 
     def __init__(self, path: Path,
-                 counters: _WriteCounters | None = None) -> None:
+                 stage: _WriteStage | None = None) -> None:
         self._oplog = OpLog(path)
-        self._counters = counters if counters is not None \
-            else _WriteCounters()
+        self._stage = stage if stage is not None else _WriteStage()
         self.log: list[BusMessage] = []
         #: Offset of ``log[0]`` (bus retention trims the consumed
         #: in-memory prefix; the on-disk oplog keeps full history).
@@ -634,8 +680,8 @@ class _DurablePartition:
 
     def append(self, key: str, value: Any) -> int:
         offset = self.base + len(self.log)
-        data, kept = self._counters.encode([key], value)
-        self._oplog.append(data)
+        data, kept = self._stage.encode([key], value)
+        self._stage.stage(self._oplog, data)
         self.dirty = True
         if kept is value and type(value) is RawOperation:
             # The orderer's hop: a submitted op's contents are still the
@@ -658,19 +704,21 @@ class _DurablePartition:
 
     def sync_if_dirty(self) -> None:
         if self.dirty:
+            self._stage.flush()
             self._oplog.sync()
             self.dirty = False
 
     def close(self) -> None:
+        self._stage.flush()
         self._oplog.close()
 
 
 class _DurableTopic(Topic):
     def __init__(self, name: str, num_partitions: int, root: Path,
-                 counters: _WriteCounters) -> None:
+                 stage: _WriteStage) -> None:
         self.name = name
         self.partitions = [
-            _DurablePartition(root / f"{name}-{p}.log", counters)
+            _DurablePartition(root / f"{name}-{p}.log", stage)
             for p in range(num_partitions)]
 
 
@@ -689,7 +737,7 @@ class DurableMessageBus(MessageBus, _CountedWrites):
         super().__init__(retention_messages=retention_messages)
         self._root = Path(root)
         self._root.mkdir(parents=True, exist_ok=True)
-        self._counters = _WriteCounters()
+        self._stage = _WriteStage()
         # Topic metadata journal: partition counts are fixed at creation
         # (the Kafka rule) — reopening always uses the recorded count, so a
         # caller passing a different num_partitions can never orphan logs
@@ -719,7 +767,7 @@ class DurableMessageBus(MessageBus, _CountedWrites):
                 self._topic_partitions[name] = num_partitions
                 recorded = num_partitions
             self._topics[name] = _DurableTopic(name, recorded, self._root,
-                                               self._counters)
+                                               self._stage)
         return self._topics[name]
 
     def commit(self, topic: str, group: str, partition: int,
@@ -730,7 +778,10 @@ class DurableMessageBus(MessageBus, _CountedWrites):
         # a claim that everything below it was consumed; if the partition
         # log lost those records to a crash, replay-from-offset would skip
         # ops no lambda ever saw. Group commit: the whole batch of appends
-        # since the last commit shares this one fsync.
+        # since the last commit shares this one fsync. Every staged record
+        # (of every partition, and of the store that shares the stage)
+        # reaches its file first: an offset never runs ahead of a record.
+        self._stage.flush()
         t = self._topics.get(topic)
         if t is not None and partition < len(t.partitions):
             t.partitions[partition].sync_if_dirty()
@@ -755,6 +806,7 @@ class DurableMessageBus(MessageBus, _CountedWrites):
         self._offset_records = len(self._offset_log)
 
     def close(self) -> None:
+        self._stage.flush()
         self._meta_log.close()
         self._offset_log.close()
         for topic in self._topics.values():
@@ -767,13 +819,14 @@ class DurableMessageBus(MessageBus, _CountedWrites):
 
 class FileStateStore(_CountedWrites):
     """Journaled key→document store (same duck-typed surface as the
-    in-memory StateStore). Every put/append is one journal record; open
-    replays the journal into memory. ``compact()`` rewrites the journal as
-    one snapshot record per key (the Mongo-compaction analog). It runs
-    when the records a later put superseded outnumber the live ones (and
-    ``COMPACT_THRESHOLD``), so the journal stays within about twice what
-    it holds and each rewrite is paid for by as many superseded records:
-    an append-only key (an op log) never forces one."""
+    in-memory StateStore). Every put/append is one journal record, on the
+    file from the write stage's next flush; open replays the journal into
+    memory. ``compact()`` rewrites the journal as one snapshot record per
+    key (the Mongo-compaction analog). It runs when the records a later
+    put superseded outnumber the live ones (and ``COMPACT_THRESHOLD``),
+    so the journal stays within about twice what it holds and each
+    rewrite is paid for by as many superseded records: an append-only key
+    (an op log) never forces one."""
 
     COMPACT_THRESHOLD = 8192
 
@@ -783,7 +836,7 @@ class FileStateStore(_CountedWrites):
         self._path = self._root / "state.log"
         self._journal = OpLog(self._path)
         self._dirty = False  # appends since the last sync (group commit)
-        self._counters = _WriteCounters()
+        self._stage = _WriteStage()
         self._data: dict[str, Any] = {}
         # Journal records per key, and those a later put superseded.
         self._key_records: dict[str, int] = {}
@@ -805,14 +858,14 @@ class FileStateStore(_CountedWrites):
         # lists etc.): no dual-shape bugs between first run and recovery.
         # A value already in that shape is held as given, as the in-memory
         # StateStore holds it.
-        data, kept = self._counters.encode(["put", key], value)
-        self._journal.append(data)
+        data, kept = self._stage.encode(["put", key], value)
+        self._stage.stage(self._journal, data)
         self._data[key] = kept
         self._bump("put", key)
 
     def append(self, key: str, items: list) -> None:
-        data, kept = self._counters.encode(["append", key], items)
-        self._journal.append(data)
+        data, kept = self._stage.encode(["append", key], items)
+        self._stage.stage(self._journal, data)
         self._data.setdefault(key, []).extend(kept)
         self._bump("append", key)
 
@@ -831,6 +884,13 @@ class FileStateStore(_CountedWrites):
                                self._records - self._garbage):
             self.compact()
 
+    @_CountedWrites.stage.setter
+    def stage(self, stage: _WriteStage) -> None:
+        # The service hands over its bus's stage, so that the bus's flush
+        # before an offset record puts this journal's records on file too.
+        self._stage.flush()
+        self._stage = stage
+
     def keys(self, prefix: str = "") -> list[str]:
         return sorted(k for k in self._data if k.startswith(prefix))
 
@@ -838,10 +898,12 @@ class FileStateStore(_CountedWrites):
         """Group commit: one fsync covers every record since the last
         (no-op when nothing was written — callers sync per checkpoint)."""
         if self._dirty:
+            self._stage.flush()
             self._journal.sync()
             self._dirty = False
 
     def compact(self) -> None:
+        self._stage.flush()
         self._journal.close()
         tmp = self._path.with_suffix(".compact")
         tmp.unlink(missing_ok=True)
@@ -858,6 +920,7 @@ class FileStateStore(_CountedWrites):
         self._dirty = False  # the compacted journal was synced pre-publish
 
     def close(self) -> None:
+        self._stage.flush()
         self._journal.close()
 
 

@@ -719,6 +719,10 @@ class RouterliciousService:
             # psum aggregation sees merge-host counters too).
             merge_host.metrics = self.metrics
         self.store = store if store is not None else StateStore()
+        if hasattr(self.bus, "stage") and hasattr(self.store, "stage"):
+            # One write stage: the bus's flush before an offset record
+            # puts the store's records on file too.
+            self.store.stage = self.bus.stage
         for durable in (self.bus, self.store):
             if hasattr(durable, "metrics"):  # the durable bus and store
                 durable.metrics = self.metrics
